@@ -18,9 +18,10 @@ Exit status is the CI gate: nonzero unless (a) the table is non-empty,
 n_batches x the solved schedule's modeled bytes — the engine may not
 drift from ``perfmodel``'s ShardedTraffic pricing.
 
-``--smoke`` serves CI-sized buckets (28/48/64 at width_mult 0.25) so the
-report runs in interpret mode in seconds; default buckets are the paper
-sizes (224/384/512).
+``--smoke`` serves CI-sized buckets (28/48/64, a 10-class B0 at
+width_mult 0.25) so the report runs in interpret mode in seconds; without
+it the engine serves the published EfficientNet-B0 (width 1.0, 1000
+classes) at the paper sizes (224/384/512).
 """
 
 from __future__ import annotations
@@ -31,7 +32,11 @@ import sys
 import jax
 import numpy as np
 
-from repro.configs.efficientnet_b0 import efficientnet_b0_smoke
+from repro.compile_cache import enable_compile_cache
+from repro.configs.efficientnet_b0 import (
+    efficientnet_b0,
+    efficientnet_b0_smoke,
+)
 from repro.core import telemetry
 from repro.models.mbconv import efficientnet_b0_def
 from repro.models.param import materialize
@@ -83,8 +88,10 @@ def main(argv=None) -> int:
     width = args.width_mult if args.width_mult is not None \
         else (0.25 if args.smoke else 1.0)
 
+    enable_compile_cache()
     telemetry.reset()
-    cfg = efficientnet_b0_smoke(width_mult=width, num_classes=10)
+    cfg = (efficientnet_b0_smoke(width_mult=width, num_classes=10)
+           if args.smoke else efficientnet_b0(width_mult=width))
     params = materialize(efficientnet_b0_def(cfg), jax.random.key(args.seed))
     eng = VisionEngine(params, cfg, VisionServeConfig(
         resolutions=resolutions, batch_size=args.batch_size,

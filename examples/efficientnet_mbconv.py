@@ -16,6 +16,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.configs.base import set_kernel_config
 from repro.configs.efficientnet_b0 import efficientnet_b0_smoke
 from repro.core.autotune import get_mbconv_schedule
@@ -54,7 +55,8 @@ def main():
                          "DW->HBM->SE->PW baseline instead of the two-pass "
                          "fused pipeline")
     args = ap.parse_args()
-    set_kernel_config(fused_mbconv=not args.staged, interpret=True)
+    enable_compile_cache()
+    set_kernel_config(fused_mbconv=not args.staged)
 
     schedule_table()
 

@@ -6,11 +6,14 @@
 import jax.numpy as jnp
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.core.schedule import make_schedule, is_exact_cover
 from repro.core.convdk import dwconv2d_convdk, dwconv2d_oracle
 from repro.core.tiling import DWLayer, plan_layer
 from repro.core.perfmodel import cost_ws_base, cost_ws_convdk, reduction
 from repro.kernels import convdk_depthwise2d, depthwise2d_ref
+
+enable_compile_cache()
 
 # 1. The number theory: the paper's worked example (k=3, s=2, N=30).
 sched = make_schedule(k=3, s=2, N=30)
@@ -39,11 +42,11 @@ base, ours = cost_ws_base(layer), cost_ws_convdk(layer)
 print(f"512x14x14: buffer traffic {base.buffer_words} -> {ours.buffer_words} "
       f"words ({reduction(base.buffer_words, ours.buffer_words):.1f}% less)")
 
-# 5. The TPU kernel (Pallas, interpret mode on CPU) — same dataflow idea:
+# 5. The TPU kernel (Pallas; interpreted on a CPU) — same dataflow idea:
 #    strip resident in VMEM, k shifted re-reads, channels on the lanes.
 xb = jnp.asarray(rng.normal(size=(2, 14, 14, 32)), jnp.float32)   # NHWC
 kb = jnp.asarray(rng.normal(size=(3, 3, 32)), jnp.float32)
-got = convdk_depthwise2d(xb, kb, stride=1, padding="SAME", interpret=True)
+got = convdk_depthwise2d(xb, kb, stride=1, padding="SAME")
 want = depthwise2d_ref(xb, kb, stride=1, padding="SAME")
 print(f"\nPallas ConvDK kernel == oracle: "
       f"{bool(jnp.allclose(got, want, atol=1e-4))}")

@@ -17,6 +17,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.configs.base import kernel_config, set_kernel_config
 from repro.models.common import separable_block, separable_def
 from repro.models.param import P, materialize
@@ -56,7 +57,8 @@ def main():
                     help="route separable blocks through the staged "
                          "two-kernel pipeline instead of the fused kernel")
     args = ap.parse_args()
-    set_kernel_config(fused_separable=not args.staged, interpret=True)
+    enable_compile_cache()
+    set_kernel_config(fused_separable=not args.staged)
 
     params = materialize(model_def(), jax.random.key(0))
 

@@ -1,58 +1,32 @@
-"""Version-compat shims over fast-moving JAX APIs.
+"""Thin wrappers over the JAX surface the repo builds on (JAX 0.9.0).
 
-The repo targets the installed JAX (CI pins a floor, not an exact version);
-the sharding surface in particular moved between 0.4.x and 0.5+:
-
-* ``jax.sharding.AxisType`` + ``jax.make_mesh(..., axis_types=...)`` —
-  absent before ~0.4.38; meshes there are implicitly "auto" everywhere.
-* ``jax.set_mesh`` — newer spelling of the mesh context; older releases use
-  the ``Mesh`` object's own context manager.
-
-Everything that builds or activates a mesh goes through this module so the
-suite collects and runs on any supported JAX.
+Everything that builds a mesh, enters ``shard_map`` or issues a Pallas
+DMA goes through this module, so those call shapes live in one
+place: meshes with explicit Auto axis types, ``shard_map`` without
+replication checking, and the strip-staging engine's async copies.
 """
 
 from __future__ import annotations
 
-import contextlib
 import os
+import sys
 from typing import Optional, Sequence, Tuple
 
 import jax
-
-try:  # jax >= ~0.4.38
-    from jax.sharding import AxisType  # type: ignore[attr-defined]
-except ImportError:  # pragma: no cover - exercised on old JAX in CI matrix
-    AxisType = None
+from jax.sharding import AxisType
 
 
 def make_mesh(shape: Sequence[int], axes: Tuple[str, ...]):
-    """``jax.make_mesh`` with Auto axis_types where the API supports them."""
-    if AxisType is not None:
-        try:
-            return jax.make_mesh(tuple(shape), tuple(axes),
-                                 axis_types=(AxisType.Auto,) * len(axes))
-        except TypeError:  # make_mesh predates the axis_types kwarg
-            pass
-    return jax.make_mesh(tuple(shape), tuple(axes))
+    """``jax.make_mesh`` with Auto axis types on every axis."""
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def shard_map_compat(f, mesh, in_specs, out_specs):
-    """``shard_map`` across its moves: ``jax.shard_map`` (newest),
-    ``jax.experimental.shard_map.shard_map`` (0.4.x).  Replication checking
-    is disabled — the fused conv wrappers psum explicitly, and the check's
-    kwarg itself was renamed (``check_rep`` -> ``check_vma``) between
-    releases."""
-    if hasattr(jax, "shard_map"):  # jax >= ~0.6
-        try:
-            return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                                 out_specs=out_specs, check_vma=False)
-        except TypeError:  # pragma: no cover - older spelling of the kwarg
-            return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                                 out_specs=out_specs, check_rep=False)
-    from jax.experimental.shard_map import shard_map
-    return shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                     check_rep=False)
+    """``jax.shard_map`` with replication checking off — the fused conv
+    wrappers psum explicitly."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 # ---------------------------------------------------------------------------
@@ -60,13 +34,10 @@ def shard_map_compat(f, mesh, in_specs, out_specs):
 #
 # The production rendering of the fused ConvDK kernels keeps the input in
 # the ANY/HBM memory space and DMAs each halo'd strip window into VMEM
-# scratch with ``pltpu.make_async_copy``.  Interpret mode (the CI backend)
-# executes the SAME DMA-structured code path — the interpreter implements
-# the copy/semaphore primitives — so parity tests genuinely exercise the
-# staging structure.  These shims pin the few symbols that moved between
-# pallas releases (memory-space spelling, semaphore types) and degrade to a
-# synchronous-copy object on builds without DMA tracing support, keeping
-# the kernel code itself version-free.
+# scratch with ``pltpu.make_async_copy``.  Interpret mode (the CPU test
+# backend) executes the SAME DMA-structured code path — the interpreter
+# implements the copy/semaphore primitives — so parity tests genuinely
+# exercise the staging structure.
 # ---------------------------------------------------------------------------
 
 def _pltpu():
@@ -74,75 +45,27 @@ def _pltpu():
     return pltpu
 
 
-def pallas_any_memory_space():
-    """The ANY (compiler-placed, HBM-capable) memory space marker."""
-    pltpu = _pltpu()
-    if hasattr(pltpu, "ANY"):
-        return pltpu.ANY
-    return pltpu.TPUMemorySpace.ANY  # pre-0.4.3x spelling
-
-
-def pallas_supports_dma() -> bool:
-    """True when this pallas build can trace async copies + DMA semaphores
-    (every supported JAX; the fallback exists so exotic builds still run the
-    staged structure, just with synchronous copies and no semaphores)."""
-    pltpu = _pltpu()
-    return hasattr(pltpu, "make_async_copy") \
-        and hasattr(pltpu, "SemaphoreType")
-
-
-def pallas_dma_semaphores(n: int):
-    """Scratch-shape entry for an ``n``-slot DMA semaphore array."""
-    return _pltpu().SemaphoreType.DMA((n,))
-
-
-class _SyncCopy:
-    """Degenerate async-copy object: copies on ``start``, no-op ``wait``.
-
-    Only used when ``pallas_supports_dma()`` is False — the staging engine
-    then runs the identical start/wait protocol without real semaphores.
-    """
-
-    def __init__(self, src, dst):
-        self.src, self.dst = src, dst
-
-    def start(self):
-        self.dst[...] = self.src[...]
-
-    def wait(self):
-        pass
-
-
 def pallas_async_copy(src, dst, sem, priority=None):
-    """``pltpu.make_async_copy`` across versions (sync-copy fallback).
+    """``pltpu.make_async_copy``, with an optional DMA stream priority.
 
     ``priority`` requests a DMA stream priority for the copy (prefetches
     want the low-priority background stream, ``priority=1``, so demand
-    fetches overtake them).  The installed pallas's ``make_async_copy``
-    only grew that parameter in later releases, so it is passed through
-    WHEN SUPPORTED and silently dropped otherwise —
+    fetches overtake them).  It is passed through only where
+    ``make_async_copy`` takes it and dropped otherwise —
     ``pallas_dma_priority_supported()`` reports which happened, and the
     bench records the knob as unsupported rather than pretending it was
     exercised."""
     pltpu = _pltpu()
-    if sem is not None and hasattr(pltpu, "make_async_copy"):
-        if priority is not None and pallas_dma_priority_supported():
-            return pltpu.make_async_copy(src, dst, sem, priority=priority)
-        return pltpu.make_async_copy(src, dst, sem)
-    return _SyncCopy(src, dst)
+    if priority is not None and pallas_dma_priority_supported():
+        return pltpu.make_async_copy(src, dst, sem, priority=priority)
+    return pltpu.make_async_copy(src, dst, sem)
 
 
 def pallas_dma_priority_supported() -> bool:
     """Whether ``make_async_copy`` accepts a ``priority`` argument here."""
-    pltpu = _pltpu()
-    fn = getattr(pltpu, "make_async_copy", None)
-    if fn is None:
-        return False
-    try:
-        import inspect
-        return "priority" in inspect.signature(fn).parameters
-    except (TypeError, ValueError):
-        return False
+    import inspect
+    return "priority" in inspect.signature(
+        _pltpu().make_async_copy).parameters
 
 
 # ---------------------------------------------------------------------------
@@ -161,10 +84,13 @@ def pallas_dma_priority_supported() -> bool:
 # sharded MBConv gradient once, at a tiny shape on a (2, 2) slice of the
 # local devices, and compares the ``w_dw`` cotangent against the reference
 # VJP it is defined to equal.  On fixed JAX builds the barrier therefore
-# auto-disables; where the probe cannot run (fewer than 4 devices, or any
-# probe failure) the barrier stays on — it is harmless when the bug is
-# absent.  ``CONVDK_RESIDUAL_BARRIER`` / ``set_residual_barrier`` force
-# the decision ("on" | "off" | "auto").
+# auto-disables; where the probe cannot run (fewer than 4 devices, or a
+# probe failure — reported on stderr) the barrier stays on — it is
+# harmless when the bug is absent.  The probe runs the kernels as the
+# process would (compiled on a TPU, interpreted on the CPU), so a verdict
+# on a four-chip host is about the compiled path.
+# ``CONVDK_RESIDUAL_BARRIER`` / ``set_residual_barrier`` force the
+# decision ("on" | "off" | "auto").
 # ---------------------------------------------------------------------------
 
 _BARRIER_ENV = "CONVDK_RESIDUAL_BARRIER"
@@ -204,8 +130,7 @@ def residual_forwarding_probe() -> Optional[bool]:
     it."""
     global _probe_result
     if _probe_result is None:
-        clean = getattr(jax.core, "trace_state_clean", lambda: True)
-        if not clean():
+        if not jax.core.trace_ctx.is_top_level():
             return None                # un-cached: retry when eager
         _probe_result = _run_forwarding_probe()
     return {"buggy": True, "fixed": False}.get(_probe_result)
@@ -219,11 +144,14 @@ def _run_forwarding_probe() -> str:
         import numpy as np
 
         # lazy import: convdk_sharded imports this module at load time
+        from .kernels.common import default_interpret
         from .kernels.convdk_sharded import (
             _mbconv_sharded_op,
             _sep_sharded_op,
         )
         from .kernels.ref import mbconv_ref, separable_ref
+
+        interpret = default_interpret()
 
         mesh = make_mesh((2, 2), ("data", "model"))
         b, hw, ci, co, k, cse = 2, 4, 8, 4, 3, 1
@@ -249,7 +177,7 @@ def _run_forwarding_probe() -> str:
         # output ((out**2) — a constant cotangent does not tickle the
         # forwarding rewrite).
         entry = jax.jit(lambda *arrays: _mbconv_sharded_op(
-            *arrays, mesh, 1, "SAME", 1, "retain", None, "silu", True,
+            *arrays, mesh, 1, "SAME", 1, "retain", None, "silu", interpret,
             "strip_dma_db", "ring_allreduce", "replicated"))
 
         def loss(wd):
@@ -275,7 +203,7 @@ def _run_forwarding_probe() -> str:
         # disables the barrier for BOTH
         w_pw = arr(7, ci, co)
         sep_entry = jax.jit(lambda *arrays: _sep_sharded_op(
-            *arrays, mesh, 1, "SAME", 1, None, None, True,
+            *arrays, mesh, 1, "SAME", 1, None, None, interpret,
             "strip_dma_db", "ring_allreduce", "replicated"))
 
         def sep_loss(wd):
@@ -292,7 +220,10 @@ def _run_forwarding_probe() -> str:
         exact = np.allclose(np.asarray(got_s), np.asarray(want_s),
                             rtol=1e-3, atol=1e-3)
         return "fixed" if exact else "buggy"
-    except Exception:                 # any probe failure: keep the barrier
+    except Exception as e:            # any probe failure: keep the barrier,
+        print(f"repro.compat: residual-forwarding probe failed, keeping "
+              f"the barrier on: {type(e).__name__}: {e}",
+              file=sys.stderr)        # and say why
         return "unprobed"
 
 
@@ -311,22 +242,7 @@ def residual_barrier_needed() -> bool:
 def residual_barrier(res):
     """Block jit's input->output forwarding on a custom_vjp residual tuple
     (section doc above) — unless the probe shows this build is fixed, in
-    which case the tuple passes through untouched.  On builds without the
-    ``optimization_barrier`` primitive this degrades to identity (those
-    builds predate the forwarding rewrite that miscounts)."""
-    barrier = getattr(jax.lax, "optimization_barrier", None)
-    if barrier is None or _probing or not residual_barrier_needed():
+    which case the tuple passes through untouched."""
+    if _probing or not residual_barrier_needed():
         return res
-    return barrier(res)
-
-
-@contextlib.contextmanager
-def activate_mesh(mesh):
-    """Enter a mesh context: ``jax.set_mesh`` when available, else the
-    legacy ``Mesh`` context manager."""
-    if hasattr(jax, "set_mesh"):
-        with jax.set_mesh(mesh):
-            yield mesh
-    else:
-        with mesh:
-            yield mesh
+    return jax.lax.optimization_barrier(res)

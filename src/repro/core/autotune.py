@@ -68,6 +68,7 @@ from .perfmodel import (
     fusedmb_shard,
     fusedmb_staging_bytes,
     get_perf_coefficients,
+    launch_width,
     layout_transition_words,
     mbconv_pass_us,
     mbconv_shard,
@@ -88,6 +89,7 @@ from .perfmodel import (
     validate_layout,
     validate_overlap,
     validate_residency,
+    vmem_tile_bytes,
 )
 from . import telemetry
 from .telemetry import measure
@@ -127,6 +129,19 @@ class TPUConfig:
     vmem_bytes: int = 16 * 1024 * 1024   # per-core VMEM budget
     c_block: int = 128                   # lane width
     tile_h_candidates: Tuple[int, ...] = (1, 2, 4, 8, 16, 32)
+
+
+class VMEMInfeasibleError(ValueError):
+    """No candidate schedule of a layer fits the VMEM budget.  The solver
+    raises it instead of handing the kernels a schedule Mosaic would
+    refuse (or, worse, a scoped-VMEM overflow at launch)."""
+
+
+def _infeasible(family: str, shape, tpu: "TPUConfig", **pins):
+    pinned = "".join(f", {k}={v}" for k, v in pins.items() if v is not None)
+    return VMEMInfeasibleError(
+        f"no {family} schedule fits {tpu.vmem_bytes} B of VMEM for "
+        f"{shape}{pinned}")
 
 
 class _ScheduleTraffic:
@@ -647,24 +662,37 @@ def _collective_set(shape: MBConvShape, eff: MeshShape,
 # separable (single-pass) schedules
 # ---------------------------------------------------------------------------
 
+def _f32_tile(*dims: int) -> int:
+    return vmem_tile_bytes(dims, 4)
+
+
 def vmem_footprint_bytes(shape: SeparableShape, tile_h: int,
                          tpu: TPUConfig,
                          residency: str = DEFAULT_RESIDENCY) -> int:
     """Modeled VMEM residency of one fused grid cell under one residency.
 
-    Counts the input staging (the strip-DMA slot buffer(s) — 2x for
-    double-buffering — or the full-height resident block), the f32 DW
-    accumulator, the f32 PW scratch accumulator and both weight blocks:
-    the budget the staging engine's rendering of the kernel must respect.
+    Every array is priced at its (sublane, lane) tile padding
+    (``perfmodel.vmem_tile_bytes``).  Counts the input staging (the
+    strip-DMA slot buffer(s) — 2x for double-buffering — or the
+    double-buffered full-height resident block), the double-buffered
+    BlockSpec operands (both weight blocks and the output block), the f32
+    PW scratch accumulator, and the tap loop's live f32 values (the DW
+    accumulator, one tap slice and the PW partial): the budget the
+    kernel's Mosaic rendering must respect.
     """
     ci = pick_channel_block(shape.c_in, tpu.c_block)
     co = _blocks(shape.c_out, tpu.c_block)
     tile_h = max(1, min(tile_h, shape.out_h))
+    out_w = launch_width(shape.out_w, shape.s, shape.k, shape.padded_w)[0]
+    db = shape.dtype_bytes
     x_win = separable_staging_bytes(shape, tile_h, residency, tpu.c_block)
-    dw_acc = tile_h * shape.out_w * ci * 4
-    pw_acc = tile_h * shape.out_w * co * 4
-    weights = (shape.k * shape.k * ci + ci * co) * shape.dtype_bytes
-    return x_win + dw_acc + pw_acc + weights
+    blocks = 2 * (vmem_tile_bytes((shape.k, shape.k, ci), db)
+                  + vmem_tile_bytes((ci, co), db)
+                  + vmem_tile_bytes((tile_h, out_w, co), db))
+    pw_acc = _f32_tile(tile_h, out_w, co)
+    temps = (2 * _f32_tile(tile_h, out_w, ci)
+             + _f32_tile(tile_h * out_w, co))
+    return x_win + blocks + pw_acc + temps
 
 
 def _residency_set(residency: Optional[str]) -> Tuple[str, ...]:
@@ -697,7 +725,9 @@ def candidate_schedules(
     feasible = [(th, res) for th in ths for res in _residency_set(residency)
                 if vmem_footprint_bytes(local, th, tpu, res)
                 <= tpu.vmem_bytes]
-    for th, res in feasible or [(1, residency or "strip_dma")]:
+    if not feasible:
+        raise _infeasible("separable", local, tpu, residency=residency)
+    for th, res in feasible:
         if (th, res) in seen:
             continue
         seen.add((th, res))
@@ -755,7 +785,9 @@ def _solve_residency_at(shape: SeparableShape, tile_h: int, tpu: TPUConfig,
     local, eff = separable_shard(shape, mesh_shape, in_layout)
     modes = [res for res in RESIDENCY_MODES
              if vmem_footprint_bytes(local, tile_h, tpu, res)
-             <= tpu.vmem_bytes] or ["strip_dma"]
+             <= tpu.vmem_bytes]
+    if not modes:
+        raise _infeasible("separable", local, tpu, tile_h=tile_h)
     return min(modes, key=lambda res: (
         sharded_separable_traffic(shape, tile_h, eff, tpu.c_block, res,
                                   in_layout).device.total_bytes,
@@ -807,72 +839,63 @@ def mbconv_vmem_footprint_bytes(shape: MBConvShape, tile_h: int,
                                 tpu: TPUConfig,
                                 residency: str = DEFAULT_RESIDENCY,
                                 mode: str = "retain") -> int:
-    """Modeled VMEM residency of one two-pass MBConv grid cell.
-
-    The dominant terms are the input staging (slot buffers or the resident
-    block; ``retain`` adds the pass-2 DW re-read stream) and the f32
-    expand accumulator over the staged strip window at ``cm_block`` lanes
-    (pass 1 and recompute pass 2 share it); pass 2 adds the f32 projection
-    accumulator.  Summing both passes' terms is deliberately conservative
-    — the launches are separate, but a schedule that only fits one of them
-    is not worth distinguishing."""
-    ci = pick_channel_block(shape.c_in, tpu.c_block)
-    cm = pick_channel_block(shape.c_mid, tpu.c_block)
-    co = _blocks(shape.c_out, tpu.c_block)
-    tile_h = max(1, min(tile_h, shape.out_h))
-    in_rows = (tile_h - 1) * shape.s + shape.k
-    w_need = (shape.out_w - 1) * shape.s + shape.k
-    staging = mbconv_staging_bytes(shape, tile_h, mode, residency,
-                                   tpu.c_block)
-    exp_acc = in_rows * w_need * cm * 4
-    dw_blk = tile_h * shape.out_w * cm * 4
-    proj_acc = tile_h * shape.out_w * co * 4
-    weights = (ci * cm + shape.k * shape.k * cm + cm * co) * shape.dtype_bytes
-    return staging + exp_acc + dw_blk + proj_acc + weights
+    """Modeled VMEM residency of one two-pass MBConv grid cell: the SUM of
+    both passes' footprints (``mbconv_pass_vmem_bytes``).  The launches
+    are separate, so the sum is deliberately conservative — a schedule
+    that only fits one of them is not worth distinguishing."""
+    return sum(mbconv_pass_vmem_bytes(shape, tile_h, tpu, residency, mode))
 
 
 def mbconv_pass_vmem_bytes(shape: MBConvShape, tile_h: int,
                            tpu: TPUConfig,
                            residency: str = DEFAULT_RESIDENCY,
                            mode: str = "retain") -> Tuple[int, int]:
-    """``mbconv_vmem_footprint_bytes`` split by pass: ``(pass1, pass2)``
-    bytes, summing EXACTLY to the whole-cell footprint (property-tested —
-    the conservative serial feasibility check is unchanged by the split).
+    """VMEM bytes of each pass's launch: ``(pass1, pass2)``, every array
+    at its tile padding (``perfmodel.vmem_tile_bytes``).
 
-    Pass 1 holds the input staging, the expand accumulator, the DW block
-    and the expand/DW weights; pass 2 holds the DW re-read stream (retain
-    — the staging split between the x window and the DW slots follows
-    ``mbconv_staging_bytes``) or the recompute re-run of pass 1's input
-    terms, plus the projection accumulator and weight.  Cross-block
-    pipelining co-resides block i's pass 2 with block i+1's pass 1, so
-    the overlap feasibility check is per-pass against HALF the budget
-    (``_OVERLAP_VMEM_DIV``), not the summed footprint against all of it.
+    The expand+DW front end (pass 1, and pass 2 again under
+    ``recompute``) holds the input staging, the f32 expand accumulator,
+    the expand contraction's loaded window and f32 partial, the tap
+    loop's live f32 values (accumulator, tap slice, masked pool copy) and
+    the double-buffered expand/DW weight blocks.  Pass 1 adds its
+    double-buffered outputs (the SE pool, the retained DW block); pass 2
+    holds the retained-DW stream (``retain``) or the whole front end
+    (``recompute``), plus the projection: f32 scratch and partial, the
+    double-buffered gate, projection weight and output blocks.
+    Cross-block pipelining co-resides block i's pass 2 with block i+1's
+    pass 1, so the overlap feasibility check is per-pass against HALF the
+    budget (``_OVERLAP_VMEM_DIV``).
     """
     ci = pick_channel_block(shape.c_in, tpu.c_block)
     cm = pick_channel_block(shape.c_mid, tpu.c_block)
     co = _blocks(shape.c_out, tpu.c_block)
     tile_h = max(1, min(tile_h, shape.out_h))
     in_rows = (tile_h - 1) * shape.s + shape.k
-    w_need = (shape.out_w - 1) * shape.s + shape.k
+    out_w, w_tot = launch_width(shape.out_w, shape.s, shape.k,
+                                shape.padded_w)
+    db = shape.dtype_bytes
     # x-window staging only (the recompute form of the staging model);
     # the retain total adds the pass-2 DW re-read slots on top
     x_stage = mbconv_staging_bytes(shape, tile_h, "recompute", residency,
                                    tpu.c_block)
     dw_stage = mbconv_staging_bytes(shape, tile_h, mode, residency,
                                     tpu.c_block) - x_stage
-    exp_acc = in_rows * w_need * cm * 4
-    dw_blk = tile_h * shape.out_w * cm * 4
-    proj_acc = tile_h * shape.out_w * co * 4
-    w_p1 = (ci * cm + shape.k * shape.k * cm) * shape.dtype_bytes
-    w_p2 = cm * co * shape.dtype_bytes
-    pass1 = x_stage + exp_acc + dw_blk + w_p1
+    gate = 2 * _f32_tile(1, 1, cm) if shape.has_se else 0
+    front = (x_stage + _f32_tile(in_rows, w_tot, cm)
+             + _f32_tile(in_rows, w_tot, ci) + _f32_tile(in_rows, w_tot, cm)
+             + 3 * _f32_tile(tile_h, out_w, cm)
+             + 2 * (vmem_tile_bytes((ci, cm), db)
+                    + vmem_tile_bytes((shape.k, shape.k, cm), db)))
+    dw_out = (2 * vmem_tile_bytes((tile_h, out_w, cm), db)
+              if mode == "retain" else 0)
+    pass1 = front + gate + dw_out
+    proj = (_f32_tile(tile_h, out_w, co) + _f32_tile(tile_h * out_w, co)
+            + 2 * (vmem_tile_bytes((cm, co), db)
+                   + vmem_tile_bytes((tile_h, out_w, co), db)) + gate)
     if mode == "retain":
-        pass2 = dw_stage + proj_acc + w_p2
+        pass2 = dw_stage + _f32_tile(tile_h, out_w, cm) + proj
     else:
-        # recompute pass 2 re-runs the expand+DW front end; it owns the
-        # whole-cell terms minus what pass 1 already counted (the sum
-        # must stay identical, so pass 2 carries only the projection side)
-        pass2 = proj_acc + w_p2
+        pass2 = front + proj
     return pass1, pass2
 
 
@@ -944,7 +967,8 @@ def candidate_mbconv_schedules(
               and (overlap == DEFAULT_OVERLAP
                    or _overlap_vmem_ok(local, th, tpu, res, md))]
     if not combos:
-        combos = [(1, md, residency or "strip_dma") for md in modes]
+        raise _infeasible("mbconv", local, tpu, residency=residency,
+                          mode=mode, overlap=overlap)
     staged_cache: dict = {}
     for th, md, res in combos:
         for coll in colls:
@@ -1020,7 +1044,9 @@ def _solve_mbconv_residency_at(shape: MBConvShape, tile_h: int, mode: str,
     local, eff = mbconv_shard(shape, mesh_shape, in_layout)
     modes = [res for res in RESIDENCY_MODES
              if mbconv_vmem_footprint_bytes(local, tile_h, tpu, res, mode)
-             <= tpu.vmem_bytes] or ["strip_dma"]
+             <= tpu.vmem_bytes]
+    if not modes:
+        raise _infeasible("mbconv", local, tpu, tile_h=tile_h, mode=mode)
     return min(modes, key=lambda res: (
         sharded_mbconv_traffic(shape, tile_h, mode, eff, tpu.c_block,
                                res, in_layout=in_layout).device.total_bytes,
@@ -1141,19 +1167,27 @@ def _fusedmb_key(shape: MBConvShape, tpu: TPUConfig,
 def fusedmb_vmem_footprint_bytes(shape: MBConvShape, tile_h: int,
                                  tpu: TPUConfig,
                                  residency: str = DEFAULT_RESIDENCY) -> int:
-    """Modeled VMEM residency of one single-pass Fused-MBConv grid cell:
-    the input staging, the f32 dense-conv accumulator and f32 projection
-    accumulator (both live the whole cell — the conv output feeds the
-    projection without leaving VMEM) and both weight blocks."""
+    """Modeled VMEM residency of one single-pass Fused-MBConv grid cell,
+    every array at its tile padding: the input staging, the f32 dense-conv
+    and projection scratch accumulators (both live the whole cell — the
+    conv output feeds the projection without leaving VMEM), the tap
+    loop's live f32 values (tap slice, running partial and its update,
+    the projection partial) and the double-buffered weight and output
+    blocks."""
     ci = pick_channel_block(shape.c_in, tpu.c_block)
     cm = pick_channel_block(shape.c_mid, tpu.c_block)
     co = _blocks(shape.c_out, tpu.c_block)
     tile_h = max(1, min(tile_h, shape.out_h))
+    out_w = launch_width(shape.out_w, shape.s, shape.k, shape.padded_w)[0]
+    db, k = shape.dtype_bytes, shape.k
     staging = fusedmb_staging_bytes(shape, tile_h, residency, tpu.c_block)
-    conv_acc = tile_h * shape.out_w * cm * 4
-    proj_acc = tile_h * shape.out_w * co * 4
-    weights = (shape.k * shape.k * ci * cm + cm * co) * shape.dtype_bytes
-    return staging + conv_acc + proj_acc + weights
+    scratch = _f32_tile(tile_h, out_w, cm) + _f32_tile(tile_h, out_w, co)
+    temps = (_f32_tile(tile_h, out_w, ci) + 2 * _f32_tile(tile_h * out_w, cm)
+             + _f32_tile(tile_h * out_w, co))
+    blocks = 2 * (vmem_tile_bytes((k, k, ci, cm), db)
+                  + vmem_tile_bytes((cm, co), db)
+                  + vmem_tile_bytes((tile_h, out_w, co), db))
+    return staging + scratch + temps + blocks
 
 
 def candidate_fusedmb_schedules(
@@ -1180,7 +1214,8 @@ def candidate_fusedmb_schedules(
                 if fusedmb_vmem_footprint_bytes(local, th, tpu, res)
                 <= budget]
     if not feasible:
-        feasible = [(1, residency or "strip_dma")]
+        raise _infeasible("fusedmb", local, tpu, residency=residency,
+                          overlap=overlap)
     staged_cache: dict = {}
     for th, res in feasible:
         for coll in colls:
@@ -1244,7 +1279,9 @@ def _solve_fusedmb_residency_at(shape: MBConvShape, tile_h: int,
     local, eff = fusedmb_shard(shape, mesh_shape)
     modes = [res for res in RESIDENCY_MODES
              if fusedmb_vmem_footprint_bytes(local, tile_h, tpu, res)
-             <= tpu.vmem_bytes] or ["strip_dma"]
+             <= tpu.vmem_bytes]
+    if not modes:
+        raise _infeasible("fusedmb", local, tpu, tile_h=tile_h)
     return min(modes, key=lambda res: (
         sharded_fusedmb_traffic(shape, tile_h, eff, tpu.c_block,
                                 res).device.total_bytes,
@@ -1673,18 +1710,22 @@ def _annotate_overlap(plan: NetworkPlan, tpu: TPUConfig,
                 local_prev, psch.tile_h, tpu, psch.residency, psch.mode)
         if p2_vmem > half:
             continue
-        if cur.family == "fusedmb":
-            # a single-pass CONSUMER can still stream behind a two-pass
-            # producer's pass 2 — its whole cell is the pass-1 footprint
-            # the halved budget must fit
-            resolved = select_fusedmb_schedule(
-                cur.shape, tpu, plan.mesh_shape,
-                collective=cur.schedule.collective, overlap="pipelined")
-        else:
-            resolved = select_mbconv_schedule(
-                cur.shape, tpu, plan.mesh_shape,
-                collective=cur.schedule.collective,
-                in_layout=cur.in_layout, overlap="pipelined")
+        try:
+            if cur.family == "fusedmb":
+                # a single-pass CONSUMER can still stream behind a
+                # two-pass producer's pass 2 — its whole cell is the
+                # pass-1 footprint the halved budget must fit
+                resolved = select_fusedmb_schedule(
+                    cur.shape, tpu, plan.mesh_shape,
+                    collective=cur.schedule.collective,
+                    overlap="pipelined")
+            else:
+                resolved = select_mbconv_schedule(
+                    cur.shape, tpu, plan.mesh_shape,
+                    collective=cur.schedule.collective,
+                    in_layout=cur.in_layout, overlap="pipelined")
+        except VMEMInfeasibleError:
+            continue      # nothing fits the halved budget: stays serial
         if (resolved.total_bytes != cur.schedule.total_bytes
                 or resolved.out_layout != cur.out_layout):
             continue

@@ -403,21 +403,56 @@ def staging_slots(residency: str) -> int:
     return {"resident": 0, "strip_dma": 1, "strip_dma_db": 2}[residency]
 
 
-def pick_channel_block(c: int, cap: int = 128) -> int:
-    """Channel block size minimizing zero-padding, then maximizing width.
+# TPU memory holds arrays in (sublane, lane) tiles: the last dim rounds up
+# to 128 lanes and the second-to-last to 8 sublanes of 32-bit words (16
+# for 2-byte, 32 for 1-byte dtypes).  Mosaic slices and DMAs only whole
+# tiles, so the kernels launch every array padded to them.
+LANES = 128
+SUBLANES = 8
+_SUBLANE_BYTES = SUBLANES * 4
 
-    ``min(cap, round_up(c, 8))`` pads e.g. 144 channels to 256 (+78 % HBM
-    words and MACs on real MobileNet-V2 widths).  Instead: among blocks
-    b <= cap (multiples of 8), pick the one whose padded channel count
-    ``round_up(c, b)`` is smallest, breaking ties toward the widest block
-    (fills the 128-lane axis).  For c divisible by 8 this always pads zero:
-    144 -> 72, 192/576 -> 96, 960 -> 120, 384 -> 128.
+
+def pick_channel_block(c: int, cap: int = 128) -> int:
+    """Channel block size the Mosaic TPU compiler accepts.
+
+    A block's lane (last) dim must be a multiple of 128 or the whole array
+    dim, and a strip DMA out of HBM needs the array's channel dim itself
+    to be a multiple of 128.  So the kernels pad channels to
+    ``round_up(c, 128)`` and block them in 128-lane multiples: the widest
+    multiple of 128 up to ``cap`` that divides the padded count.  The
+    padding costs no HBM storage — XLA's tiled layout already stores a
+    channel dim in whole 128-lane tiles — but it does cost the zero lanes'
+    MACs.  ``cap`` must be a multiple of 128.
     """
-    c8 = _round_up(max(c, 1), 8)
-    if c8 <= cap:
-        return c8
-    return min((b for b in range(8, cap + 1, 8)),
-               key=lambda b: (_round_up(c8, b), -b))
+    if cap % LANES:
+        raise ValueError(f"cap must be a multiple of {LANES}, got {cap}")
+    c_pad = _round_up(max(c, 1), LANES)
+    return max(b for b in range(LANES, cap + 1, LANES) if c_pad % b == 0)
+
+
+def launch_width(out_w: int, s: int, k: int, w_padded: int
+                 ) -> Tuple[int, int]:
+    """``(out_wk, w_tot)`` of a strip-tiled conv launch: the kernel's
+    output width in whole sublanes (a ``(rows, out_wk, C)`` tile reshapes
+    to a matrix only then), and the launched input width — the padded
+    width or the reach of ``out_wk`` taps, whichever is wider — in whole
+    sublanes (a strip DMA moves whole (8, 128) tiles)."""
+    out_wk = _round_up(out_w, SUBLANES)
+    return out_wk, _round_up(max(w_padded, (out_wk - 1) * s + k), SUBLANES)
+
+
+def _launch_w(shape) -> int:
+    """Input width of a shape's kernel launch (``launch_width``): what a
+    staged window or a resident block holds in VMEM per row."""
+    return launch_width(shape.out_w, shape.s, shape.k, shape.padded_w)[1]
+
+
+def vmem_tile_bytes(dims: Tuple[int, ...], dtype_bytes: int = 4) -> int:
+    """VMEM bytes one array of ``dims`` occupies, tile padding included."""
+    *lead, sub, lane = (1,) * max(0, 2 - len(dims)) + tuple(dims)
+    sublanes = _SUBLANE_BYTES // dtype_bytes
+    return (math.prod(lead) * _round_up(sub, sublanes)
+            * _round_up(lane, LANES) * dtype_bytes)
 
 
 @dataclass(frozen=True)
@@ -581,11 +616,13 @@ def separable_staging_bytes(
     _n_th, in_rows = _strip_counts(shape, tile_h)
     ci = pick_channel_block(shape.c_in, c_block)
     if residency == "resident":
-        # the launched (height-cover-padded) block, not just padded_h
-        return (_covered_rows(shape, tile_h) * shape.padded_w * ci
-                * shape.dtype_bytes)
-    return (staging_slots(residency) * in_rows * shape.padded_w * ci
-            * shape.dtype_bytes)
+        # the launched (height-cover-padded) block, not just padded_h,
+        # double-buffered by the BlockSpec pipeline
+        return 2 * vmem_tile_bytes(
+            (_covered_rows(shape, tile_h), _launch_w(shape), ci),
+            shape.dtype_bytes)
+    return staging_slots(residency) * vmem_tile_bytes(
+        (in_rows, _launch_w(shape), ci), shape.dtype_bytes)
 
 
 # ---------------------------------------------------------------------------
@@ -817,14 +854,18 @@ def mbconv_staging_bytes(
     ci = pick_channel_block(shape.c_in, c_block)
     cm = pick_channel_block(shape.c_mid, c_block)
     slots = staging_slots(residency)
-    dw_stream = tile_h_eff * shape.out_w * cm * shape.dtype_bytes
+    dw_stream = vmem_tile_bytes((tile_h_eff, shape.out_w, cm),
+                                shape.dtype_bytes)
     if residency == "resident":
-        # the launched (height-cover-padded) block, not just padded_h
-        x_bytes = (_covered_rows(shape, tile_h) * shape.padded_w * ci
-                   * shape.dtype_bytes)
-        dw_bytes = dw_stream                      # per-strip resident block
+        # the launched (height-cover-padded) block, not just padded_h; the
+        # BlockSpec pipeline double-buffers both resident streams
+        x_bytes = 2 * vmem_tile_bytes(
+            (_covered_rows(shape, tile_h), _launch_w(shape), ci),
+            shape.dtype_bytes)
+        dw_bytes = 2 * dw_stream                  # per-strip resident block
     else:
-        x_bytes = slots * in_rows * shape.padded_w * ci * shape.dtype_bytes
+        x_bytes = slots * vmem_tile_bytes((in_rows, _launch_w(shape), ci),
+                                          shape.dtype_bytes)
         dw_bytes = slots * dw_stream
     return x_bytes + (dw_bytes if mode == "retain" else 0)
 
@@ -996,11 +1037,12 @@ def fusedmb_staging_bytes(
     in_rows = (tile_h_eff - 1) * shape.s + shape.k
     ci = pick_channel_block(shape.c_in, c_block)
     if residency == "resident":
-        # the launched (height-cover-padded) block, not just padded_h
-        return (_covered_rows(shape, tile_h) * shape.padded_w * ci
-                * shape.dtype_bytes)
-    return (staging_slots(residency) * in_rows * shape.padded_w * ci
-            * shape.dtype_bytes)
+        # the launched (height-cover-padded) block, double-buffered
+        return 2 * vmem_tile_bytes(
+            (_covered_rows(shape, tile_h), _launch_w(shape), ci),
+            shape.dtype_bytes)
+    return staging_slots(residency) * vmem_tile_bytes(
+        (in_rows, _launch_w(shape), ci), shape.dtype_bytes)
 
 
 # ---------------------------------------------------------------------------

@@ -28,6 +28,9 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from .common import compiler_params
+from .staging import strided_read
+
 
 def _dw2d_kernel(x_ref, w_ref, o_ref, *, k_h: int, k_w: int, stride: int,
                  tile_h: int, out_w: int):
@@ -38,18 +41,14 @@ def _dw2d_kernel(x_ref, w_ref, o_ref, *, k_h: int, k_w: int, stride: int,
     o_ref: (1, 1, tile_h, out_w, CB)
     """
     s = stride
-    x = x_ref[0, 0]                      # (rows, W_pad, CB)
-    acc = jnp.zeros((tile_h, out_w, x.shape[-1]), jnp.float32)
+    acc = jnp.zeros((tile_h, out_w, x_ref.shape[-1]), jnp.float32)
     # l shift cycles x k_h row taps: every re-read of the resident strip is
     # one (a, j) pass of Algorithm 2; all N width-blocks update in parallel.
+    # A stride-2 tap is a strided VMEM load: Mosaic cannot stride-slice a
+    # loaded value.
     for j in range(k_h):
         for i in range(k_w):
-            xs = jax.lax.slice(
-                x,
-                (j, i, 0),
-                (j + s * (tile_h - 1) + 1, i + s * (out_w - 1) + 1, x.shape[-1]),
-                (s, s, 1),
-            )
+            xs = strided_read(x_ref, (0, 0), j, i, tile_h, out_w, s)
             acc = acc + xs.astype(jnp.float32) * w_ref[j, i].astype(jnp.float32)
     o_ref[0, 0] = acc.astype(o_ref.dtype)
 
@@ -94,5 +93,6 @@ def dw2d_pallas(
             lambda bi, ti, ci: (bi, ti, 0, 0, ci),
         ),
         out_shape=jax.ShapeDtypeStruct((b, n_th, tile_h, out_w, c), x_strips.dtype),
+        compiler_params=compiler_params(),
         interpret=interpret,
     )(x_strips, w)

@@ -51,7 +51,12 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from ..core.perfmodel import DEFAULT_RESIDENCY, pick_channel_block
-from .common import default_interpret, round_up as _round_up, spatial_pads
+from .common import (
+    compiler_params,
+    default_interpret,
+    launch_geometry,
+    round_up as _round_up,
+)
 from .ref import _act_ref, separable_ref
 from .staging import StripPlan, StripStream, strip_plan
 
@@ -74,22 +79,17 @@ def _fused_kernel(x_ref, wdw_ref, wpw_ref, o_ref, *scratch, plan: StripPlan,
     ci = pl.program_id(3)
     n_ci = pl.num_programs(3)
 
-    # The staged strip window: (in_rows, w_span, CI).  Under strip_dma_db
+    # The staged strip window: (in_rows, w_tot, CI).  Under strip_dma_db
     # this wait also kicks off the prefetch of the NEXT cell's window.
-    x = StripStream(plan, x_ref, stage_refs).get()
+    win = StripStream(plan, x_ref, stage_refs).get()
 
     # Algorithm-2 tap loop: l shift cycles x k_h row taps over the resident
-    # strip, all width blocks updated per tap (see convdk_dw._dw2d_kernel).
-    dw = jnp.zeros((tile_h, out_w, x.shape[-1]), jnp.float32)
+    # strip, all width blocks updated per tap; a stride-s tap is one
+    # strided VMEM load (see convdk_dw._dw2d_kernel).
+    dw = jnp.zeros((tile_h, out_w, wdw_ref.shape[-1]), jnp.float32)
     for j in range(k_h):
         for i in range(k_w):
-            xs = jax.lax.slice(
-                x,
-                (j, i, 0),
-                (j + s * (tile_h - 1) + 1, i + s * (out_w - 1) + 1,
-                 x.shape[-1]),
-                (s, s, 1),
-            )
+            xs = win.read(j, i, tile_h, out_w, s)
             dw = dw + xs.astype(jnp.float32) * wdw_ref[j, i].astype(jnp.float32)
 
     # Depthwise is per-channel, so this block's DW output is final: the
@@ -147,10 +147,9 @@ def fused_separable_pallas(
     grid = (b, n_th, c_out // co_block, c_in // ci_block)
 
     plan = strip_plan(
-        h_tot=h_tot, w_tot=w_pad,
-        w_span=min(w_pad, (out_w - 1) * stride + k_w),
-        c_block=ci_block, tile_h=tile_h, grid=grid, window_dims=(0, 1, 3),
-        stride=stride, k_h=k_h, residency=residency)
+        h_tot=h_tot, w_tot=w_pad, c_block=ci_block, tile_h=tile_h,
+        grid=grid, window_dims=(0, 1, 3), stride=stride, k_h=k_h,
+        residency=residency)
 
     kernel = functools.partial(
         _fused_kernel, plan=plan, k_h=k_h, k_w=k_w, stride=stride,
@@ -174,6 +173,7 @@ def fused_separable_pallas(
             (b, n_th * tile_h, out_w, c_out), x_pad.dtype),
         scratch_shapes=[pltpu.VMEM((tile_h, out_w, co_block), jnp.float32),
                         *plan.scratch_shapes(x_pad.dtype)],
+        compiler_params=compiler_params(),
         interpret=interpret,
     )(x_pad, w_dw, w_pw)
 
@@ -185,37 +185,26 @@ def _fused_impl(x, w_dw, w_pw, stride, padding, tile_h, dw_act, act,
     c_in_pw, c_out = w_pw.shape
     assert cw == c and c_in_pw == c, (cw, c_in_pw, c)
     s = stride
-    out_h, out_w, pads = spatial_pads(h, w_in, k_h, k_w, s, padding)
+    geo = launch_geometry(h, w_in, k_h, k_w, s, padding, tile_h)
 
-    # input channels: minimal-padding block (padding here costs real strip
-    # reads and MACs); output channels: plain 128-lane cap — padding c_out
-    # only spends zero-lane MACs and SHRINKS n_co (fewer input re-reads).
+    # input channels: 128-lane blocks of the lane-padded width (what Mosaic
+    # tiles and the strip DMA can address); output channels: plain
+    # 128-lane cap — padding c_out only spends zero-lane MACs and SHRINKS
+    # n_co (fewer input re-reads).
     ci_block = pick_channel_block(c)
     ci_pad = _round_up(c, ci_block)
     co_block = min(128, _round_up(c_out, 8))
     co_pad = _round_up(c_out, co_block)
-    xp = jnp.pad(x, ((0, 0), pads[0], pads[1], (0, ci_pad - c)))
+    xp = jnp.pad(x, (*geo.pads, (0, ci_pad - c)))
     wdp = jnp.pad(w_dw, ((0, 0), (0, 0), (0, ci_pad - c)))
     wpp = jnp.pad(w_pw, ((0, ci_pad - c), (0, co_pad - c_out)))
 
-    # width cover for the i + s*(out_w-1) + 1 tap slice
-    need_w = (out_w - 1) * s + k_w
-    if need_w > xp.shape[2]:
-        xp = jnp.pad(xp, ((0, 0), (0, 0), (0, need_w - xp.shape[2]), (0, 0)))
-
-    tile_h = max(1, min(tile_h, out_h))
-    n_th = -(-out_h // tile_h)
-    # height cover so the last strip's window stays in bounds
-    need_h = (n_th - 1) * tile_h * s + (tile_h - 1) * s + k_h
-    if need_h > xp.shape[1]:
-        xp = jnp.pad(xp, ((0, 0), (0, need_h - xp.shape[1]), (0, 0), (0, 0)))
-
     out = fused_separable_pallas(
-        xp, wdp, wpp, stride=s, out_w=out_w, tile_h=tile_h, n_th=n_th,
-        ci_block=ci_block, co_block=co_block, dw_act=dw_act, act=act,
-        interpret=interpret, residency=residency,
+        xp, wdp, wpp, stride=s, out_w=geo.out_wk, tile_h=geo.tile_h,
+        n_th=geo.n_th, ci_block=ci_block, co_block=co_block, dw_act=dw_act,
+        act=act, interpret=interpret, residency=residency,
     )
-    return out[:, :out_h, :, :c_out]
+    return out[:, :geo.out_h, :geo.out_w, :c_out]
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8, 9))

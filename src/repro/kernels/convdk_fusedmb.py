@@ -51,7 +51,12 @@ from ..core.perfmodel import (
     pick_channel_block,
     validate_collective,
 )
-from .common import default_interpret, round_up as _round_up, spatial_pads
+from .common import (
+    compiler_params,
+    default_interpret,
+    launch_geometry,
+    round_up as _round_up,
+)
 from .ref import _act_ref, fusedmb_ref
 from .staging import StripPlan, StripStream, strip_plan
 
@@ -85,13 +90,7 @@ def _fusedmb_kernel(x_ref, wconv_ref, wproj_ref, o_ref, *scratch,
     part = jnp.zeros((tile_h, out_w, wconv_ref.shape[-1]), jnp.float32)
     for j in range(k_h):
         for i in range(k_w):
-            xs = jax.lax.slice(
-                win,
-                (j, i, 0),
-                (j + s * (tile_h - 1) + 1, i + s * (out_w - 1) + 1,
-                 win.shape[-1]),
-                (s, s, 1),
-            )
+            xs = win.read(j, i, tile_h, out_w, s)
             part = part + jax.lax.dot_general(
                 xs.reshape(tile_h * out_w, xs.shape[-1]).astype(jnp.float32),
                 wconv_ref[j, i].astype(jnp.float32),
@@ -145,13 +144,11 @@ def fusedmb_pallas(x_pad, w_conv, w_proj, *, stride, out_w, tile_h, n_th,
     co_pad = w_proj.shape[1]
     grid = (b, co_pad // co_block, n_th, cm_pad // cm_block,
             ci_pad // ci_block)
-    in_rows = (tile_h - 1) * stride + k_h
-    w_need = (out_w - 1) * stride + k_w
 
     plan = strip_plan(
-        h_tot=h_tot, w_tot=w_pad, w_span=w_need, c_block=ci_block,
-        tile_h=tile_h, grid=grid, window_dims=(0, 2, 4), stride=stride,
-        k_h=k_h, residency=residency)
+        h_tot=h_tot, w_tot=w_pad, c_block=ci_block, tile_h=tile_h,
+        grid=grid, window_dims=(0, 2, 4), stride=stride, k_h=k_h,
+        residency=residency)
     kernel = functools.partial(
         _fusedmb_kernel, plan=plan, k_h=k_h, k_w=k_w, stride=stride,
         tile_h=tile_h, out_w=out_w, act=act)
@@ -175,6 +172,7 @@ def fusedmb_pallas(x_pad, w_conv, w_proj, *, stride, out_w, tile_h, n_th,
             pltpu.VMEM((tile_h, out_w, co_block), jnp.float32),
             *plan.scratch_shapes(x_pad.dtype),
         ],
+        compiler_params=compiler_params(),
         interpret=interpret,
     )(x_pad, w_conv, w_proj)
 
@@ -201,8 +199,8 @@ def _fusedmb_impl(x, w_conv, w_proj, stride, padding, tile_h, act, interpret,
     c_out = w_proj.shape[1]
     assert w_proj.shape[0] == c_mid, (w_proj.shape, c_mid)
     s = stride
-
-    out_h, out_w, pads = spatial_pads(h, w_in, k_h, k_w, s, padding)
+    geo = launch_geometry(h, w_in, k_h, k_w, s, padding, tile_h)
+    out_h, out_w = geo.out_h, geo.out_w
 
     ci_block = pick_channel_block(c_in)
     ci_pad = _round_up(c_in, ci_block)
@@ -211,40 +209,28 @@ def _fusedmb_impl(x, w_conv, w_proj, stride, padding, tile_h, act, interpret,
     co_block = min(128, _round_up(c_out, 8))
     co_pad = _round_up(c_out, co_block)
 
-    xp = jnp.pad(x, ((0, 0), pads[0], pads[1], (0, ci_pad - c_in)))
+    xp = jnp.pad(x, (*geo.pads, (0, ci_pad - c_in)))
     wconv_p = jnp.pad(w_conv, ((0, 0), (0, 0), (0, ci_pad - c_in),
                                (0, cm_pad - c_mid)))
     wproj_p = jnp.pad(w_proj, ((0, cm_pad - c_mid), (0, co_pad - c_out)))
 
-    # width cover for the i + s*(out_w-1) + 1 tap slice
-    need_w = (out_w - 1) * s + k_w
-    if need_w > xp.shape[2]:
-        xp = jnp.pad(xp, ((0, 0), (0, 0), (0, need_w - xp.shape[2]), (0, 0)))
-
-    tile_h = max(1, min(tile_h, out_h))
-    n_th = -(-out_h // tile_h)
-    # height cover so the last strip's window stays in bounds
-    need_h = (n_th - 1) * tile_h * s + (tile_h - 1) * s + k_h
-    if need_h > xp.shape[1]:
-        xp = jnp.pad(xp, ((0, 0), (0, need_h - xp.shape[1]), (0, 0), (0, 0)))
-
     out = fusedmb_pallas(
-        xp, wconv_p, wproj_p, stride=s, out_w=out_w, tile_h=tile_h,
-        n_th=n_th, ci_block=ci_block, cm_block=cm_block, co_block=co_block,
-        act=act, interpret=interpret, residency=residency)
+        xp, wconv_p, wproj_p, stride=s, out_w=geo.out_wk, tile_h=geo.tile_h,
+        n_th=geo.n_th, ci_block=ci_block, cm_block=cm_block,
+        co_block=co_block, act=act, interpret=interpret, residency=residency)
     if axis_name is not None and collective == "psum_scatter":
         # layout-aware exit, same contract as MBConv pass 2: zero w_proj
         # columns pad a non-dividing c_out to ``scatter_width`` (their
         # partials are exactly zero), the wrapper slices them back.
         cw = scatter_width if scatter_width else c_out
-        out = out[:, :out_h, :, :min(cw, out.shape[-1])]
+        out = out[:, :out_h, :out_w, :min(cw, out.shape[-1])]
         if out.shape[-1] < cw:
             out = jnp.pad(
                 out, ((0, 0), (0, 0), (0, 0), (0, cw - out.shape[-1])))
         out = jax.lax.psum_scatter(out, axis_name,
                                    scatter_dimension=3, tiled=True)
     else:
-        out = out[:, :out_h, :, :c_out]
+        out = out[:, :out_h, :out_w, :c_out]
         if axis_name is not None:
             # projection partials: each shard contracted only its c_mid
             # slice

@@ -66,45 +66,49 @@ from ..core.perfmodel import (
     pick_channel_block,
     validate_collective,
 )
-from .common import default_interpret, round_up as _round_up, spatial_pads
+from .common import (
+    compiler_params,
+    default_interpret,
+    launch_geometry,
+    round_up as _round_up,
+)
 from .ref import _act_ref, mbconv_ref
-from .staging import StripPlan, StripStream, strip_plan
+from .staging import StripPlan, StripStream, strided_read, strip_plan
 
 
-def _dw_taps(e, w_dw_ref, *, k_h, k_w, stride, tile_h, out_w):
-    """Algorithm-2 tap loop over an expanded strip resident in VMEM.
+def _expanded_taps(acc_ref, w_dw_ref, *, exp_act, dw_act, k_h, k_w, stride,
+                   tile_h, out_w):
+    """Algorithm-2 tap loop over the finished expand accumulator.
 
-    e: (in_rows, w_need, CM) f32 -> (tile_h, out_w, CM) f32.
+    acc_ref: (in_rows, w_tot, CM) f32 -> (tile_h, out_w, CM) f32.  Each tap
+    is one (strided, for s = 2) load from the ref — Mosaic cannot
+    stride-slice a loaded value — so the expand activation is applied to
+    the ref IN PLACE first.
     """
-    s = stride
-    dw = jnp.zeros((tile_h, out_w, e.shape[-1]), jnp.float32)
+    if exp_act is not None:
+        acc_ref[...] = _act_ref(acc_ref[...], exp_act)
+    dw = jnp.zeros((tile_h, out_w, acc_ref.shape[-1]), jnp.float32)
     for j in range(k_h):
         for i in range(k_w):
-            xs = jax.lax.slice(
-                e,
-                (j, i, 0),
-                (j + s * (tile_h - 1) + 1, i + s * (out_w - 1) + 1,
-                 e.shape[-1]),
-                (s, s, 1),
-            )
+            xs = strided_read(acc_ref, (), j, i, tile_h, out_w, stride)
             dw = dw + xs * w_dw_ref[j, i].astype(jnp.float32)
-    return dw
+    return _act_ref(dw, dw_act)
 
 
 def _expand_accumulate(win, wexp_ref, acc_ref, *, ci):
     """One c_in-block partial of the expand PW over the staged strip window.
 
-    ``win`` is the engine-staged ``(in_rows, w_need, CI)`` window; the
+    ``win`` is the engine-staged ``(in_rows, w_tot, CI)`` window; the
     contraction with the (CI, CM) expand block accumulates across the
     innermost c_in grid dimension.
     """
-    in_rows, w_need = win.shape[0], win.shape[1]
+    in_rows, w_tot = win.shape[0], win.shape[1]
     partial = jax.lax.dot_general(
-        win.reshape(in_rows * w_need, win.shape[-1]).astype(jnp.float32),
+        win.reshape(in_rows * w_tot, win.shape[-1]).astype(jnp.float32),
         wexp_ref[:, :].astype(jnp.float32),
         dimension_numbers=(((1,), (0,)), ((), ())),
         preferred_element_type=jnp.float32,
-    ).reshape(in_rows, w_need, -1)
+    ).reshape(in_rows, w_tot, -1)
 
     @pl.when(ci == 0)
     def _init():
@@ -117,7 +121,7 @@ def _expand_accumulate(win, wexp_ref, acc_ref, *, ci):
 
 def _mbconv_pass1_kernel(x_ref, wexp_ref, wdw_ref, *rest,
                          plan: StripPlan, k_h, k_w, stride, tile_h, out_w,
-                         out_h, exp_act: Optional[str],
+                         out_h, valid_w, exp_act: Optional[str],
                          dw_act: Optional[str], se: bool, retain: bool):
     """One (batch, c_mid-block, row-strip, c_in-block) grid cell of pass 1.
 
@@ -139,19 +143,21 @@ def _mbconv_pass1_kernel(x_ref, wexp_ref, wdw_ref, *rest,
     ci = pl.program_id(3)
     n_ci = pl.num_programs(3)
     win = StripStream(plan, x_ref, stage_refs).get()
-    _expand_accumulate(win, wexp_ref, acc_ref, ci=ci)
+    _expand_accumulate(win.read(), wexp_ref, acc_ref, ci=ci)
 
     @pl.when(ci == n_ci - 1)
     def _finish_strip():
-        e = _act_ref(acc_ref[...], exp_act)
-        dw = _dw_taps(e, wdw_ref, k_h=k_h, k_w=k_w, stride=stride,
-                      tile_h=tile_h, out_w=out_w)
-        dw = _act_ref(dw, dw_act)
+        dw = _expanded_taps(acc_ref, wdw_ref, exp_act=exp_act, dw_act=dw_act,
+                            k_h=k_h, k_w=k_w, stride=stride, tile_h=tile_h,
+                            out_w=out_w)
         if se:
-            # mask strip rows past out_h so they never enter the pool
-            rows = jax.lax.broadcasted_iota(jnp.int32, (tile_h, out_w), 0) \
+            # mask strip rows past out_h and the sublane-cover columns past
+            # valid_w so they never enter the pool (full-rank iotas: Mosaic
+            # cannot broadcast a 2-D mask up)
+            rows = jax.lax.broadcasted_iota(jnp.int32, dw.shape, 0) \
                 + ti * tile_h
-            masked = jnp.where((rows < out_h)[..., None], dw, 0.0)
+            cols = jax.lax.broadcasted_iota(jnp.int32, dw.shape, 1)
+            masked = jnp.where((rows < out_h) & (cols < valid_w), dw, 0.0)
             sums = jnp.sum(masked, axis=(0, 1), keepdims=True)  # (1, 1, CM)
 
             @pl.when(ti == 0)
@@ -187,14 +193,13 @@ def _mbconv_pass2_recompute_kernel(x_ref, wexp_ref, wdw_ref, *rest,
     n_cm = pl.num_programs(3)
     n_ci = pl.num_programs(4)
     win = StripStream(plan, x_ref, stage_refs).get()
-    _expand_accumulate(win, wexp_ref, acc_ref, ci=ci)
+    _expand_accumulate(win.read(), wexp_ref, acc_ref, ci=ci)
 
     @pl.when(ci == n_ci - 1)
     def _project():
-        e = _act_ref(acc_ref[...], exp_act)
-        dw = _dw_taps(e, wdw_ref, k_h=k_h, k_w=k_w, stride=stride,
-                      tile_h=tile_h, out_w=out_w)
-        dw = _act_ref(dw, dw_act)
+        dw = _expanded_taps(acc_ref, wdw_ref, exp_act=exp_act, dw_act=dw_act,
+                            k_h=k_h, k_w=k_w, stride=stride, tile_h=tile_h,
+                            out_w=out_w)
         if se:
             dw = dw * scale_ref[0, 0].astype(jnp.float32)
         partial = jax.lax.dot_general(
@@ -231,8 +236,8 @@ def _mbconv_pass2_retain_kernel(dw_ref, *rest, plan: StripPlan, tile_h,
     stage_refs, (proj_ref,) = plan.take_scratch(tuple(scratch))
     cm = pl.program_id(3)
     n_cm = pl.num_programs(3)
-    dw_win = StripStream(plan, dw_ref, stage_refs).get()
-    dw = dw_win.astype(jnp.float32)
+    dw = StripStream(plan, dw_ref, stage_refs).get().read()
+    dw = dw.astype(jnp.float32)
     if se:
         dw = dw * scale_ref[0, 0].astype(jnp.float32)
     partial = jax.lax.dot_general(
@@ -255,10 +260,14 @@ def _mbconv_pass2_retain_kernel(dw_ref, *rest, plan: StripPlan, tile_h,
         o_ref[0] = proj_ref[...].astype(o_ref.dtype)
 
 
-def mbconv_pass1_pallas(x_pad, w_exp, w_dw, *, stride, out_w, out_h, tile_h,
-                        n_th, ci_block, cm_block, exp_act, dw_act, retain,
-                        interpret, se=True, residency=DEFAULT_RESIDENCY):
+def mbconv_pass1_pallas(x_pad, w_exp, w_dw, *, stride, out_w, out_h, valid_w,
+                        tile_h, n_th, ci_block, cm_block, exp_act, dw_act,
+                        retain, interpret, se=True,
+                        residency=DEFAULT_RESIDENCY):
     """Raw pass-1 launch: (pool_sums-or-None, dw_retained-or-None).
+
+    ``out_w`` is the launched (sublane-covered) output width; the pool
+    counts only the first ``valid_w`` columns and ``out_h`` rows.
 
     ``se=False`` drops the pool output (and its VMEM accumulator) from the
     launch entirely — an se=off retain pass writes only the DW tensor.
@@ -267,16 +276,15 @@ def mbconv_pass1_pallas(x_pad, w_exp, w_dw, *, stride, out_w, out_h, tile_h,
     b, h_tot, w_pad, ci_pad = x_pad.shape
     k_h, k_w, cm_pad = w_dw.shape
     grid = (b, cm_pad // cm_block, n_th, ci_pad // ci_block)
-    in_rows = (tile_h - 1) * stride + k_h
-    w_need = (out_w - 1) * stride + k_w
 
     plan = strip_plan(
-        h_tot=h_tot, w_tot=w_pad, w_span=w_need, c_block=ci_block,
-        tile_h=tile_h, grid=grid, window_dims=(0, 2, 3), stride=stride,
-        k_h=k_h, residency=residency)
+        h_tot=h_tot, w_tot=w_pad, c_block=ci_block, tile_h=tile_h,
+        grid=grid, window_dims=(0, 2, 3), stride=stride, k_h=k_h,
+        residency=residency)
     kernel = functools.partial(
         _mbconv_pass1_kernel, plan=plan, k_h=k_h, k_w=k_w, stride=stride,
-        tile_h=tile_h, out_w=out_w, out_h=out_h, exp_act=exp_act,
+        tile_h=tile_h, out_w=out_w, out_h=out_h, valid_w=valid_w,
+        exp_act=exp_act,
         dw_act=dw_act, se=se, retain=retain)
     out_shape = []
     out_specs = []
@@ -302,8 +310,10 @@ def mbconv_pass1_pallas(x_pad, w_exp, w_dw, *, stride, out_w, out_h, tile_h,
         ],
         out_specs=out_specs,
         out_shape=out_shape,
-        scratch_shapes=[pltpu.VMEM((in_rows, w_need, cm_block), jnp.float32),
-                        *plan.scratch_shapes(x_pad.dtype)],
+        scratch_shapes=[
+            pltpu.VMEM((plan.in_rows, w_pad, cm_block), jnp.float32),
+            *plan.scratch_shapes(x_pad.dtype)],
+        compiler_params=compiler_params(),
         interpret=interpret,
     )(x_pad, w_exp, w_dw)
     outs = list(outs)
@@ -324,13 +334,11 @@ def mbconv_pass2_recompute_pallas(x_pad, w_exp, w_dw, scale, w_proj, *,
     co_pad = w_proj.shape[1]
     grid = (b, co_pad // co_block, n_th, cm_pad // cm_block,
             ci_pad // ci_block)
-    in_rows = (tile_h - 1) * stride + k_h
-    w_need = (out_w - 1) * stride + k_w
 
     plan = strip_plan(
-        h_tot=h_tot, w_tot=w_pad, w_span=w_need, c_block=ci_block,
-        tile_h=tile_h, grid=grid, window_dims=(0, 2, 4), stride=stride,
-        k_h=k_h, residency=residency)
+        h_tot=h_tot, w_tot=w_pad, c_block=ci_block, tile_h=tile_h,
+        grid=grid, window_dims=(0, 2, 4), stride=stride, k_h=k_h,
+        residency=residency)
     kernel = functools.partial(
         _mbconv_pass2_recompute_kernel, plan=plan, k_h=k_h, k_w=k_w,
         stride=stride, tile_h=tile_h, out_w=out_w, exp_act=exp_act,
@@ -360,10 +368,11 @@ def mbconv_pass2_recompute_pallas(x_pad, w_exp, w_dw, scale, w_proj, *,
         out_shape=jax.ShapeDtypeStruct(
             (b, n_th * tile_h, out_w, co_pad), x_pad.dtype),
         scratch_shapes=[
-            pltpu.VMEM((in_rows, w_need, cm_block), jnp.float32),
+            pltpu.VMEM((plan.in_rows, w_pad, cm_block), jnp.float32),
             pltpu.VMEM((tile_h, out_w, co_block), jnp.float32),
             *plan.scratch_shapes(x_pad.dtype),
         ],
+        compiler_params=compiler_params(),
         interpret=interpret,
     )(*operands)
 
@@ -380,9 +389,8 @@ def mbconv_pass2_retain_pallas(dw_ret, scale, w_proj, *, out_w, tile_h,
     # stride=1 geometry) — the double-buffered DMA stream of the tentpole.
     se = scale is not None
     plan = strip_plan(
-        h_tot=dw_ret.shape[1], w_tot=dw_ret.shape[2], w_span=out_w,
-        c_block=cm_block, tile_h=tile_h, grid=grid, window_dims=(0, 2, 3),
-        residency=residency)
+        h_tot=dw_ret.shape[1], w_tot=out_w, c_block=cm_block, tile_h=tile_h,
+        grid=grid, window_dims=(0, 2, 3), residency=residency)
     kernel = functools.partial(_mbconv_pass2_retain_kernel, plan=plan,
                                tile_h=tile_h, out_w=out_w, se=se)
     in_specs = [plan.in_spec(lambda bi, co, ti, cm: (bi, ti, 0, cm))]
@@ -405,6 +413,7 @@ def mbconv_pass2_retain_pallas(dw_ret, scale, w_proj, *, out_w, tile_h,
             (b, n_th * tile_h, out_w, co_pad), dw_ret.dtype),
         scratch_shapes=[pltpu.VMEM((tile_h, out_w, co_block), jnp.float32),
                         *plan.scratch_shapes(dw_ret.dtype)],
+        compiler_params=compiler_params(),
         interpret=interpret,
     )(*operands)
 
@@ -454,7 +463,8 @@ def _mbconv_impl(x, w_exp, w_dw, w_se1, b_se1, w_se2, b_se2, w_proj, stride,
     assert mode in ("retain", "recompute"), mode
     s = stride
 
-    out_h, out_w, pads = spatial_pads(h, w_in, k_h, k_w, s, padding)
+    geo = launch_geometry(h, w_in, k_h, k_w, s, padding, tile_h)
+    out_h, out_w, tile_h, n_th = geo.out_h, geo.out_w, geo.tile_h, geo.n_th
 
     ci_block = pick_channel_block(c_in)
     ci_pad = _round_up(c_in, ci_block)
@@ -463,29 +473,18 @@ def _mbconv_impl(x, w_exp, w_dw, w_se1, b_se1, w_se2, b_se2, w_proj, stride,
     co_block = min(128, _round_up(c_out, 8))
     co_pad = _round_up(c_out, co_block)
 
-    xp = jnp.pad(x, ((0, 0), pads[0], pads[1], (0, ci_pad - c_in)))
+    xp = jnp.pad(x, (*geo.pads, (0, ci_pad - c_in)))
     wexp_p = jnp.pad(w_exp, ((0, ci_pad - c_in), (0, cm_pad - c_mid)))
     wdw_p = jnp.pad(w_dw, ((0, 0), (0, 0), (0, cm_pad - c_mid)))
     wproj_p = jnp.pad(w_proj, ((0, cm_pad - c_mid), (0, co_pad - c_out)))
 
-    # width cover for the i + s*(out_w-1) + 1 tap slice
-    need_w = (out_w - 1) * s + k_w
-    if need_w > xp.shape[2]:
-        xp = jnp.pad(xp, ((0, 0), (0, 0), (0, need_w - xp.shape[2]), (0, 0)))
-
-    tile_h = max(1, min(tile_h, out_h))
-    n_th = -(-out_h // tile_h)
-    # height cover so the last strip's window stays in bounds
-    need_h = (n_th - 1) * tile_h * s + (tile_h - 1) * s + k_h
-    if need_h > xp.shape[1]:
-        xp = jnp.pad(xp, ((0, 0), (0, need_h - xp.shape[1]), (0, 0), (0, 0)))
-
     if se or mode == "retain":
         pool, dw_ret = mbconv_pass1_pallas(
-            xp, wexp_p, wdw_p, stride=s, out_w=out_w, out_h=out_h,
-            tile_h=tile_h, n_th=n_th, ci_block=ci_block, cm_block=cm_block,
-            exp_act=exp_act, dw_act=dw_act, retain=(mode == "retain"),
-            interpret=interpret, se=se, residency=residency)
+            xp, wexp_p, wdw_p, stride=s, out_w=geo.out_wk, out_h=out_h,
+            valid_w=out_w, tile_h=tile_h, n_th=n_th, ci_block=ci_block,
+            cm_block=cm_block, exp_act=exp_act, dw_act=dw_act,
+            retain=(mode == "retain"), interpret=interpret, se=se,
+            residency=residency)
     else:
         # se=off + recompute: pass 1 would produce nothing — skip it.
         pool, dw_ret = None, None
@@ -508,12 +507,12 @@ def _mbconv_impl(x, w_exp, w_dw, w_se1, b_se1, w_se2, b_se2, w_proj, stride,
 
     if mode == "retain":
         out = mbconv_pass2_retain_pallas(
-            dw_ret, scale, wproj_p, out_w=out_w, tile_h=tile_h, n_th=n_th,
-            cm_block=cm_block, co_block=co_block, interpret=interpret,
-            residency=residency)
+            dw_ret, scale, wproj_p, out_w=geo.out_wk, tile_h=tile_h,
+            n_th=n_th, cm_block=cm_block, co_block=co_block,
+            interpret=interpret, residency=residency)
     else:
         out = mbconv_pass2_recompute_pallas(
-            xp, wexp_p, wdw_p, scale, wproj_p, stride=s, out_w=out_w,
+            xp, wexp_p, wdw_p, scale, wproj_p, stride=s, out_w=geo.out_wk,
             tile_h=tile_h, n_th=n_th, ci_block=ci_block, cm_block=cm_block,
             co_block=co_block, exp_act=exp_act, dw_act=dw_act,
             interpret=interpret, residency=residency)
@@ -526,14 +525,14 @@ def _mbconv_impl(x, w_exp, w_dw, w_se1, b_se1, w_se2, b_se2, w_proj, stride,
         # columns, so their partials are exactly zero and the wrapper
         # slices them back off the gathered-global view.
         cw = scatter_width if scatter_width else c_out
-        out = out[:, :out_h, :, :min(cw, out.shape[-1])]
+        out = out[:, :out_h, :out_w, :min(cw, out.shape[-1])]
         if out.shape[-1] < cw:
             out = jnp.pad(
                 out, ((0, 0), (0, 0), (0, 0), (0, cw - out.shape[-1])))
         out = jax.lax.psum_scatter(out, axis_name,
                                    scatter_dimension=3, tiled=True)
     else:
-        out = out[:, :out_h, :, :c_out]
+        out = out[:, :out_h, :out_w, :c_out]
         if axis_name is not None:
             # projection partials: each shard contracted only its c_mid
             # slice

@@ -102,7 +102,7 @@ def _dw2d_impl(x, w, stride, padding, tile_h, interpret):
     s = stride
     out_h, out_w, pads = spatial_pads(h, w_in, k_h, k_w, s, padding)
 
-    # channel blocking: minimal-padding block along the 128-lane axis
+    # channel blocking: 128-lane blocks of the lane-padded width
     c_block = pick_channel_block(c)
     c_pad = _round_up(c, c_block)
     xp = jnp.pad(x, ((0, 0), pads[0], pads[1], (0, c_pad - c)))

@@ -37,9 +37,8 @@ order IS the DMA stream order, whatever dims (c_out blocks, c_mid
 reduction, ...) interleave between strips.
 
 Everything here runs identically under interpret mode: the pallas
-interpreter implements the copy/semaphore primitives (shimmed through
-``repro.compat`` for version drift), so CPU parity tests execute the same
-DMA-structured code path as a real TPU launch.
+interpreter implements the copy/semaphore primitives, so CPU parity tests
+execute the same DMA-structured code path as a real TPU launch.
 """
 
 from __future__ import annotations
@@ -51,12 +50,7 @@ from typing import Optional, Tuple
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ..compat import (
-    pallas_any_memory_space,
-    pallas_async_copy,
-    pallas_dma_semaphores,
-    pallas_supports_dma,
-)
+from ..compat import pallas_async_copy
 from ..core import telemetry
 from ..core.perfmodel import (
     DEFAULT_RESIDENCY,
@@ -78,14 +72,14 @@ __all__ = [
 class StripPlan:
     """Static description of one staged input stream of a fused kernel.
 
-    Geometry (one window is ``(in_rows, w_span, c_block)``):
+    Geometry (one window is ``(in_rows, w_tot, c_block)``):
 
     * ``h_tot`` / ``w_tot`` — full (padded) rows / width of the source
-      tensor, as launched: bounds for the last window's slice.
-    * ``w_span`` — staged words per row, ``(out_w - 1) * stride + k_w``
-      for conv streams (= the whole tap reach), ``out_w`` for
-      non-overlapping re-read streams.
-    * ``c_block`` — channel lanes per window.
+      tensor, as launched: bounds for the last window's slice.  A window
+      spans the whole launched width: Mosaic slices an HBM array only in
+      whole (8, 128) tiles, and the launch pads the width to the tap
+      reach rounded up to whole sublanes (``common.launch_geometry``).
+    * ``c_block`` — channel lanes per window (a multiple of 128).
     * ``tile_h`` / ``stride`` / ``k_h`` — strip geometry; ``k_h == 1,
       stride == 1`` describes a non-overlapping row-block stream (the
       retained-DW re-read), anything else a halo'd conv stream.
@@ -100,7 +94,6 @@ class StripPlan:
 
     h_tot: int
     w_tot: int
-    w_span: int
     c_block: int
     tile_h: int
     grid: Tuple[int, ...]
@@ -114,7 +107,6 @@ class StripPlan:
 
     def __post_init__(self):
         validate_residency(self.residency)
-        assert self.w_span <= self.w_tot, (self.w_span, self.w_tot)
         assert len(self.window_dims) == 3 and all(
             0 <= d < len(self.grid) for d in self.window_dims), self
 
@@ -151,25 +143,23 @@ class StripPlan:
         un-blocked in the ANY space and the engine carves windows itself.
         """
         if self.is_dma:
-            return pl.BlockSpec(memory_space=pallas_any_memory_space())
+            return pl.BlockSpec(memory_space=pl.ANY)
         rows = self.h_tot if self.halo else self.tile_h
         return pl.BlockSpec((1, rows, self.w_tot, self.c_block), index_map)
 
     def scratch_shapes(self, dtype) -> tuple:
         """Engine scratch to append to the kernel's ``scratch_shapes``:
-        the slot buffer plus (when the build traces real DMAs) the per-slot
-        semaphore array.  Empty for ``resident``."""
+        the slot buffer plus the per-slot DMA semaphore array.  Empty for
+        ``resident``."""
         if not self.is_dma:
             return ()
-        shapes = [pltpu.VMEM(
-            (self.n_slots, self.in_rows, self.w_span, self.c_block), dtype)]
-        if pallas_supports_dma():
-            shapes.append(pallas_dma_semaphores(self.n_slots))
-        return tuple(shapes)
+        return (pltpu.VMEM((self.n_slots, self.in_rows, self.w_tot,
+                            self.c_block), dtype),
+                pltpu.SemaphoreType.DMA((self.n_slots,)))
 
     def take_scratch(self, scratch: tuple) -> tuple:
         """Split a kernel's trailing scratch refs: (engine_refs, rest)."""
-        n = (2 if pallas_supports_dma() else 1) if self.is_dma else 0
+        n = 2 if self.is_dma else 0
         return (scratch[len(scratch) - n:] if n else (),
                 scratch[:len(scratch) - n])
 
@@ -178,7 +168,6 @@ def strip_plan(
     *,
     h_tot: int,
     w_tot: int,
-    w_span: int,
     c_block: int,
     tile_h: int,
     grid: Tuple[int, ...],
@@ -195,7 +184,7 @@ def strip_plan(
     stream geometry fully determines its issue count and staged words, so
     counting at construction is both cheap and exact."""
     plan = StripPlan(
-        h_tot=h_tot, w_tot=w_tot, w_span=w_span, c_block=c_block,
+        h_tot=h_tot, w_tot=w_tot, c_block=c_block,
         tile_h=tile_h, grid=tuple(grid), window_dims=tuple(window_dims),
         stride=stride, k_h=k_h,
         residency=DEFAULT_RESIDENCY if residency is None else residency,
@@ -206,7 +195,7 @@ def strip_plan(
         telemetry.counter("staging.dma_issues", plan.n_steps)
         telemetry.counter(
             "staging.window_words",
-            plan.n_steps * plan.in_rows * plan.w_span * plan.c_block)
+            plan.n_steps * plan.in_rows * plan.w_tot * plan.c_block)
     return plan
 
 
@@ -215,7 +204,7 @@ class StripStream:
 
     Construct inside the kernel body from the plan, the input ref and the
     engine's scratch refs, then call :meth:`get` once to obtain the
-    ``(in_rows, w_span, c_block)`` window of this cell — staged per the
+    ``(in_rows, w_tot, c_block)`` window of this cell — staged per the
     plan's residency (slice, blocking DMA, or double-buffered DMA with
     next-window prefetch).
     """
@@ -224,8 +213,7 @@ class StripStream:
         self.plan = plan
         self.x_ref = x_ref
         if plan.is_dma:
-            self.buf = stage_refs[0]
-            self.sem = stage_refs[1] if len(stage_refs) > 1 else None
+            self.buf, self.sem = stage_refs
         else:
             assert not stage_refs, stage_refs
             self.buf = self.sem = None
@@ -266,19 +254,19 @@ class StripStream:
         # copy, so the priority rides the descriptor uniformly
         prio = p.prefetch_priority if p.residency == "strip_dma_db" else None
         return pallas_async_copy(
-            self.x_ref.at[bi, pl.ds(row0, p.in_rows), pl.ds(0, p.w_span),
+            self.x_ref.at[bi, pl.ds(row0, p.in_rows), :,
                           pl.ds(ci * p.c_block, p.c_block)],
             self.buf.at[slot],
-            self.sem.at[slot] if self.sem is not None else None,
+            self.sem.at[slot],
             priority=prio,
         )
 
     # -- the one public op ---------------------------------------------------
 
-    def get(self):
-        """The current cell's staged window, ``(in_rows, w_span, c_block)``.
+    def get(self) -> "StripWindow":
+        """The current cell's staged window, ``(in_rows, w_tot, c_block)``.
 
-        * resident — a ``pl.ds`` slice of the VMEM-resident block,
+        * resident — a view into the VMEM-resident block,
         * strip_dma — start + wait one async copy into slot 0,
         * strip_dma_db — wait the copy a previous cell prefetched (cell 0
           bootstraps its own), after starting the NEXT cell's prefetch so
@@ -287,10 +275,10 @@ class StripStream:
         p = self.plan
         if not p.is_dma:
             if not p.halo:
-                return self.x_ref[0][:, :p.w_span]       # per-strip block
+                return StripWindow(self.x_ref, (0,), 0, p.tile_h, p.w_tot)
             _, ti, _ = self._window_here()
-            win = self.x_ref[0, pl.ds(ti * p.tile_h * p.stride, p.in_rows)]
-            return win[:, :p.w_span]
+            return StripWindow(self.x_ref, (0,), ti * p.tile_h * p.stride,
+                               p.in_rows, p.w_tot)
 
         step = self._step()
         here = self._window_here()
@@ -298,7 +286,7 @@ class StripStream:
             dma = self._dma(here, 0)
             dma.start()
             dma.wait()
-            return self.buf[0]
+            return StripWindow(self.buf, (0,), 0, p.in_rows, p.w_tot)
 
         # strip_dma_db: the scratch slots revolve across grid cells — the
         # first cell warms the stream, every cell prefetches its successor.
@@ -311,5 +299,38 @@ class StripStream:
             self._dma(self._window_at(step + 1),
                       (step + 1) % p.n_slots).start()
 
-        self._dma(here, step % p.n_slots).wait()
-        return self.buf[step % p.n_slots]
+        slot = step % p.n_slots
+        self._dma(here, slot).wait()
+        return StripWindow(self.buf, (slot,), 0, p.in_rows, p.w_tot)
+
+
+class StripWindow:
+    """A staged window as a VMEM ref view: ``ref[lead..., row0 + r, c, :]``.
+
+    Kernels read it through :meth:`read`, which loads straight from the
+    ref.  A stride-2 conv tap is a STRIDED REF LOAD here — Mosaic cannot
+    take a strided slice of a loaded value, but loads every s-th row and
+    column of a VMEM ref directly.
+    """
+
+    def __init__(self, ref, lead: tuple, row0, rows: int, cols: int):
+        self.ref, self.lead, self.row0 = ref, lead, row0
+        self.rows, self.cols = rows, cols
+
+    def read(self, row: int = 0, col: int = 0, rows: Optional[int] = None,
+             cols: Optional[int] = None, stride: int = 1):
+        """Rows ``row, row+stride, ...`` (``rows`` of them) by columns
+        ``col, col+stride, ...`` (``cols`` of them), all channels; the
+        defaults read the whole window."""
+        return strided_read(self.ref, self.lead, self.row0 + row, col,
+                            self.rows if rows is None else rows,
+                            self.cols if cols is None else cols, stride)
+
+
+def strided_read(ref, lead: tuple, row, col: int, rows: int, cols: int,
+                 stride: int = 1):
+    """``ref[*lead, row::stride][:rows], [col::stride][:cols], :]`` as one
+    (strided when ``stride > 1``) VMEM load."""
+    st = stride if stride > 1 else None
+    return ref[(*lead, pl.ds(row, rows, stride=st),
+                pl.ds(col, cols, stride=st), slice(None))]

@@ -128,9 +128,9 @@ def test_cache_key_includes_full_tpu_config(cache_dir):
     tmp_path, _cache = cache_dir
     base = TPUConfig()
     get_fused_schedule(1, 56, 56, 144, 24, 3, 1, tpu=base)
-    narrow = TPUConfig(c_block=64)
-    sch = get_fused_schedule(1, 56, 56, 144, 24, 3, 1, tpu=narrow)
-    assert sch.co_block <= 64                    # solved, not cache-echoed
+    wide = TPUConfig(c_block=256)
+    sch = get_fused_schedule(1, 56, 56, 144, 24, 3, 1, tpu=wide)
+    assert sch.ci_block == 256                   # solved, not cache-echoed
     coarse = TPUConfig(tile_h_candidates=(2,))
     sch2 = get_fused_schedule(1, 56, 56, 144, 24, 3, 1, tpu=coarse)
     assert sch2.tile_h == 2

@@ -22,7 +22,7 @@ _SCRIPT = textwrap.dedent("""
     from repro.models.model import forward, model_def
     from repro.models.param import materialize, logical_axes
     from repro.sharding import tree_shardings, spec_for
-    from repro.compat import activate_mesh, make_mesh
+    from repro.compat import make_mesh
     from jax.sharding import NamedSharding, PartitionSpec as P
 
     assert len(jax.devices()) == 8, jax.devices()
@@ -43,7 +43,7 @@ _SCRIPT = textwrap.dedent("""
     ref = forward(params, {"tokens": toks}, cfg)
 
     mesh = make_mesh((2, 4), ("data", "model"))
-    with activate_mesh(mesh):
+    with jax.set_mesh(mesh):
         p_sh = tree_shardings(logical_axes(pdefs), params, mesh)
         params_s = jax.device_put(params, p_sh)
         toks_s = jax.device_put(

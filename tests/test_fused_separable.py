@@ -1,6 +1,8 @@
 """Fused depthwise-separable ConvDK kernel vs the XLA oracle, the autotune
 schedule layer, and the fused-vs-staged HBM traffic accounting."""
 
+import dataclasses
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -169,28 +171,56 @@ def test_fused_traffic_below_staged_any_tile_h():
 
 
 def test_pick_channel_block_minimizes_padding():
-    """Channel blocking must not inflate real MobileNet widths: every c
-    divisible by 8 gets a zero-padding block; ties go to the widest."""
+    """Channel blocks are what Mosaic accepts: whole 128-lane multiples of
+    the 128-padded width, the widest such block up to the cap — so the
+    padding is the lane cover alone, never more."""
     from repro.core.perfmodel import pick_channel_block
-    for c, want in [(144, 72), (192, 96), (576, 96), (960, 120),
-                    (384, 128), (128, 128), (32, 32), (8, 8)]:
+    for c, want in [(144, 128), (192, 128), (576, 128), (960, 128),
+                    (384, 128), (128, 128), (32, 128), (8, 128)]:
         assert pick_channel_block(c) == want, (c, want)
+    assert pick_channel_block(384, cap=256) == 128      # 256 leaves a tail
+    assert pick_channel_block(512, cap=256) == 256
     for c in range(1, 300):
         b = pick_channel_block(c)
-        assert b % 8 == 0 and 8 <= b <= 128
-        # never worse than the naive min(128, round_up(c, 8)) cap
-        naive = min(128, -(-c // 8) * 8)
-        pad_b = -(-c // b) * b - c
-        pad_naive = -(-c // naive) * naive - c
-        assert pad_b <= pad_naive, (c, b, naive)
+        assert b % 128 == 0 and 128 <= b <= 128
+        # padded to the lane cover, not beyond
+        assert -(-c // b) * b == -(-c // 128) * 128, (c, b)
+    with pytest.raises(ValueError):
+        pick_channel_block(144, cap=64)
 
 
 def test_autotune_respects_vmem_budget():
-    tpu = TPUConfig(vmem_bytes=256 * 1024)
+    tpu = TPUConfig(vmem_bytes=2 * 1024 * 1024)
     shape = SeparableShape(b=1, h=112, w=112, c_in=96, c_out=24, k=3, s=1)
     for cand in candidate_schedules(shape, tpu):
         assert vmem_footprint_bytes(shape, cand.tile_h, tpu,
                                     cand.residency) <= tpu.vmem_bytes
+
+
+@pytest.mark.parametrize("family", ["separable", "mbconv", "fusedmb"])
+def test_autotune_raises_when_nothing_fits_vmem(family):
+    """Where no candidate fits the budget, the solver refuses instead of
+    handing the kernels a schedule the compiler would reject."""
+    from repro.core.autotune import (
+        VMEMInfeasibleError,
+        select_fusedmb_schedule,
+        select_mbconv_schedule,
+    )
+    from repro.core.perfmodel import MBConvShape
+
+    tpu = TPUConfig(vmem_bytes=64 * 1024)
+    mb = MBConvShape(b=1, h=56, w=56, c_in=24, c_mid=144, c_out=40, k=3,
+                     s=2)
+    solve = {
+        "separable": lambda: select_fused_schedule(
+            SeparableShape(b=1, h=56, w=56, c_in=144, c_out=24, k=3, s=1),
+            tpu),
+        "mbconv": lambda: select_mbconv_schedule(mb, tpu),
+        "fusedmb": lambda: select_fusedmb_schedule(
+            dataclasses.replace(mb, se_ratio=0.0), tpu),
+    }[family]
+    with pytest.raises(VMEMInfeasibleError, match="VMEM"):
+        solve()
 
 
 def test_autotune_selects_minimum_traffic():

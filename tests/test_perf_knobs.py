@@ -66,7 +66,7 @@ _SCRIPT = textwrap.dedent("""
     from repro.models.model import forward, model_def
     from repro.models.param import materialize, logical_axes
     from repro.sharding import tree_shardings, spec_for
-    from repro.compat import activate_mesh, make_mesh
+    from repro.compat import make_mesh
     from jax.sharding import NamedSharding
 
     cfg = get_arch("qwen1.5-4b").smoke
@@ -82,7 +82,7 @@ _SCRIPT = textwrap.dedent("""
     ref = forward(params, {"tokens": toks}, cfg)   # no mesh: knobs dormant
 
     mesh = make_mesh((2, 4), ("data", "model"))
-    with activate_mesh(mesh):
+    with jax.set_mesh(mesh):
         p_sh = tree_shardings(logical_axes(pdefs), params, mesh)
         params_s = jax.device_put(params, p_sh)
         toks_s = jax.device_put(toks, NamedSharding(

@@ -362,7 +362,7 @@ def test_pinned_mbconv_mode_solves_under_that_mode():
     from repro.core.autotune import get_mbconv_schedule
 
     set_schedule_cache_dir(None)
-    tpu = TPUConfig(vmem_bytes=640 * 1024)
+    tpu = TPUConfig(vmem_bytes=3 * 1024 * 1024)
     kwargs = dict(b=1, h=56, w=56, c_in=24, c_mid=144, c_out=40, k=5, s=2,
                   tpu=tpu)
     free = get_mbconv_schedule(**kwargs)

@@ -167,7 +167,7 @@ def test_staging_plan_hooks_count_issues_and_words():
     t = telemetry.get_telemetry()
     base = {k: t.get(k) for k in ("staging.plans", "staging.dma_issues",
                                   "staging.window_words")}
-    plan = strip_plan(h_tot=18, w_tot=16, w_span=16, c_block=8, tile_h=4,
+    plan = strip_plan(h_tot=18, w_tot=16, c_block=8, tile_h=4,
                       grid=(1, 4, 2), window_dims=(0, 1, 2), stride=1,
                       k_h=3, residency="strip_dma_db")
     assert t.get("staging.plans") == base["staging.plans"] + 1
@@ -175,7 +175,7 @@ def test_staging_plan_hooks_count_issues_and_words():
     assert t.get("staging.window_words") == (
         base["staging.window_words"] + 8 * plan.in_rows * 16 * 8)
     # resident plans issue no DMA
-    strip_plan(h_tot=18, w_tot=16, w_span=16, c_block=8, tile_h=4,
+    strip_plan(h_tot=18, w_tot=16, c_block=8, tile_h=4,
                grid=(1, 4, 2), window_dims=(0, 1, 2), stride=1, k_h=3,
                residency="resident")
     assert t.get("staging.dma_issues") == base["staging.dma_issues"] + 8
