@@ -44,7 +44,7 @@ import json
 import os
 import time
 from dataclasses import dataclass, replace
-from functools import lru_cache
+from functools import lru_cache, wraps
 from pathlib import Path
 from typing import Dict, Optional, Sequence, Tuple
 
@@ -108,6 +108,16 @@ DEFAULT_ACT = "silu"
 # Families a network CHAIN element may take (separable blocks are solved
 # per-layer via ``get_fused_schedule`` and never enter the chain DP)
 CHAIN_FAMILIES: Tuple[str, ...] = ("mbconv", "fusedmb")
+
+
+def _plan_span(fn):
+    """Times every call of a public solver entry, cache hit or miss, as
+    the span ``autotune.plan`` (nested entries count once)."""
+    @wraps(fn)
+    def timed(*args, **kwargs):
+        with telemetry.span("autotune.plan"):
+            return fn(*args, **kwargs)
+    return timed
 
 
 def validate_act(act: str) -> str:
@@ -794,6 +804,7 @@ def _solve_residency_at(shape: SeparableShape, tile_h: int, tpu: TPUConfig,
         _RESIDENCY_RANK[res]))
 
 
+@_plan_span
 def get_fused_schedule(
     b: int, h: int, w: int, c_in: int, c_out: int, k: int, s: int,
     dtype_bytes: int = 4, tpu: TPUConfig = TPUConfig(),
@@ -824,8 +835,6 @@ def get_fused_schedule(
                             in_layout, collective)
     sched = select_fused_schedule(shape, tpu, mesh_shape, residency,
                                   in_layout, collective)
-    telemetry.counter("autotune.solve.separable")
-    telemetry.counter(f"autotune.pick.residency.{sched.residency}")
     cache.put(key, {"tile_h": sched.tile_h, "residency": sched.residency,
                     "source": "model", "recorded_at": time.time()})
     return sched
@@ -1067,6 +1076,7 @@ def _solve_mbconv_collective_at(shape: MBConvShape, tile_h: int, mode: str,
         _COLLECTIVE_RANK[coll]))
 
 
+@_plan_span
 def get_mbconv_schedule(
     b: int, h: int, w: int, c_in: int, c_mid: int, c_out: int, k: int,
     s: int, se_ratio: float = 0.25, dtype_bytes: int = 4,
@@ -1119,10 +1129,6 @@ def get_mbconv_schedule(
                                    overlap)
     sched = select_mbconv_schedule(shape, tpu, mesh_shape, residency, mode,
                                    collective, in_layout, overlap)
-    telemetry.counter("autotune.solve.mbconv")
-    telemetry.counter(f"autotune.pick.residency.{sched.residency}")
-    telemetry.counter(f"autotune.pick.mode.{sched.mode}")
-    telemetry.counter(f"autotune.pick.collective.{sched.collective}")
     cache.put(key, {"tile_h": sched.tile_h, "mode": sched.mode,
                     "residency": sched.residency,
                     "collective": sched.collective,
@@ -1300,6 +1306,7 @@ def _solve_fusedmb_collective_at(shape: MBConvShape, tile_h: int,
         _COLLECTIVE_RANK[coll]))
 
 
+@_plan_span
 def get_fusedmb_schedule(
     b: int, h: int, w: int, c_in: int, c_mid: int, c_out: int, k: int,
     s: int, dtype_bytes: int = 4, tpu: TPUConfig = TPUConfig(),
@@ -1329,9 +1336,6 @@ def get_fusedmb_schedule(
                                     coll, overlap)
     sched = select_fusedmb_schedule(shape, tpu, mesh_shape, residency,
                                     collective, overlap)
-    telemetry.counter("autotune.solve.fusedmb")
-    telemetry.counter(f"autotune.pick.residency.{sched.residency}")
-    telemetry.counter(f"autotune.pick.collective.{sched.collective}")
     cache.put(key, {"tile_h": sched.tile_h, "residency": sched.residency,
                     "collective": sched.collective, "source": "model",
                     "recorded_at": time.time()})
@@ -1894,6 +1898,7 @@ def _network_plan_cached(rows: tuple, b: int, mesh_shape: MeshShape,
                                   se_ratio)
 
 
+@_plan_span
 def get_network_plan(
     rows: Sequence[Tuple[int, ...]], b: int,
     mesh_shape: MeshShape = (1, 1), dtype_bytes: int = 4,

@@ -10,10 +10,14 @@ counters that let a run explain what it actually did:
   decisions).  Incrementing is a dict update behind a lock: cheap enough
   to leave permanently on.
 * **Spans** — named wall-time aggregates (count / total / min / max) via
-  the ``span(name)`` context manager.
+  the ``span(name)`` context manager.  Each span also opens a
+  ``jax.profiler.TraceAnnotation`` of its name, so under a profiler
+  trace it lands on the ``/host:CPU`` plane, on the device ops' clock.
+  A span re-entered under itself on one thread (the plan solvers nest)
+  is timed and annotated once, at its outermost entry.
 * **Series** — bounded sample recorders (``record(name, value)``) for
   distributions the aggregates cannot answer: request latencies, queue
-  depths.  A series keeps the most recent ``SERIES_CAP`` samples and
+  waits.  A series keeps the most recent ``SERIES_CAP`` samples and
   summarizes as count / last / max / nearest-rank percentiles
   (``percentiles()``) — the serving layer's p50/p90/p99 live here.
 * **``measure()``** — THE timing loop for real kernel executions: warmup
@@ -121,6 +125,7 @@ class Telemetry:
         self._counters: Dict[str, float] = {}
         self._spans: Dict[str, SpanStat] = {}
         self._series: Dict[str, deque] = {}
+        self._local = threading.local()     # per-thread span depths
 
     # -- counters ------------------------------------------------------------
 
@@ -137,12 +142,26 @@ class Telemetry:
 
     @contextmanager
     def span(self, name: str):
-        """Time a ``with`` block into the span aggregate ``name``."""
+        """Time a ``with`` block into the span aggregate ``name`` and mark
+        it on the profiler's host timeline.  Only the outermost entry of
+        ``name`` on this thread counts."""
+        depth = self._local.__dict__.setdefault("depth", {})
+        outer = depth.get(name, 0)
+        depth[name] = outer + 1
+        if outer:
+            try:
+                yield self
+            finally:
+                depth[name] = outer
+            return
+        from jax.profiler import TraceAnnotation
         t0 = time.perf_counter()
         try:
-            yield self
+            with TraceAnnotation(name):
+                yield self
         finally:
             dt = time.perf_counter() - t0
+            depth[name] = outer
             with self._lock:
                 self._spans.setdefault(name, SpanStat()).add(dt)
 

@@ -79,4 +79,5 @@ def conv1d_pallas(
         ),
         out_shape=jax.ShapeDtypeStruct((b, n_tl, tile_l, d), x_strips.dtype),
         interpret=interpret,
+        name="conv1d",
     )(x_strips, w, bias[None, :])

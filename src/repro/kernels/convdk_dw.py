@@ -95,4 +95,5 @@ def dw2d_pallas(
         out_shape=jax.ShapeDtypeStruct((b, n_th, tile_h, out_w, c), x_strips.dtype),
         compiler_params=compiler_params(),
         interpret=interpret,
+        name="dw2d",
     )(x_strips, w)

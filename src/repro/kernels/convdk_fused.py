@@ -175,6 +175,7 @@ def fused_separable_pallas(
                         *plan.scratch_shapes(x_pad.dtype)],
         compiler_params=compiler_params(),
         interpret=interpret,
+        name="fused_separable",
     )(x_pad, w_dw, w_pw)
 
 

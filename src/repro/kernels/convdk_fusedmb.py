@@ -174,6 +174,7 @@ def fusedmb_pallas(x_pad, w_conv, w_proj, *, stride, out_w, tile_h, n_th,
         ],
         compiler_params=compiler_params(),
         interpret=interpret,
+        name="fusedmb",
     )(x_pad, w_conv, w_proj)
 
 

@@ -315,6 +315,7 @@ def mbconv_pass1_pallas(x_pad, w_exp, w_dw, *, stride, out_w, out_h, valid_w,
             *plan.scratch_shapes(x_pad.dtype)],
         compiler_params=compiler_params(),
         interpret=interpret,
+        name="mbconv_pass1",
     )(x_pad, w_exp, w_dw)
     outs = list(outs)
     pool = outs.pop(0) if se else None
@@ -374,6 +375,7 @@ def mbconv_pass2_recompute_pallas(x_pad, w_exp, w_dw, scale, w_proj, *,
         ],
         compiler_params=compiler_params(),
         interpret=interpret,
+        name="mbconv_pass2_recompute",
     )(*operands)
 
 
@@ -415,6 +417,7 @@ def mbconv_pass2_retain_pallas(dw_ret, scale, w_proj, *, out_w, tile_h,
                         *plan.scratch_shapes(dw_ret.dtype)],
         compiler_params=compiler_params(),
         interpret=interpret,
+        name="mbconv_pass2_retain",
     )(*operands)
 
 

@@ -190,7 +190,6 @@ def strip_plan(
         residency=DEFAULT_RESIDENCY if residency is None else residency,
         prefetch_priority=prefetch_priority)
     telemetry.counter("staging.plans")
-    telemetry.counter(f"staging.residency.{plan.residency}")
     if plan.is_dma:
         telemetry.counter("staging.dma_issues", plan.n_steps)
         telemetry.counter(

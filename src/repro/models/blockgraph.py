@@ -38,6 +38,8 @@ from __future__ import annotations
 import dataclasses
 from typing import Callable, FrozenSet, Optional, Tuple
 
+import jax
+
 from ..core.perfmodel import DEFAULT_OVERLAP, validate_overlap
 
 
@@ -234,17 +236,17 @@ class BlockGraph:
     def lower(self, x):
         """Execute the chain: thread ``x`` through every node's apply
         closure in node order — operation-for-operation identical to the
-        sequential loop, so forward and grad are bit-exact with it."""
-        from ..core import telemetry
-        telemetry.counter("blockgraph.lower")
-        telemetry.counter("blockgraph.pipelined_boundaries",
-                          len(self.pipelined_boundaries))
+        sequential loop, so forward and grad are bit-exact with it.  Each
+        node runs under ``jax.named_scope(node.name)``, so every op of a
+        block (its pads, slices, residual add, SE ops and kernels) carries
+        the block's name in its HLO ``op_name``."""
         for node in self.nodes:
             if node.apply is None:
                 raise GraphValidationError(
                     f"{node.name}: no apply closure bound; build the "
                     "graph through build_mbconv_graph to lower it")
-            x = node.apply(x)
+            with jax.named_scope(node.name):
+                x = node.apply(x)
         return x
 
 

@@ -572,10 +572,11 @@ def efficientnet_b0_apply(params: dict, images: jax.Array,
     former sequential loop."""
     specs = effnet_block_specs(cfg)
     dt = jnp.dtype(cfg.dtype)
-    x = jax.lax.conv_general_dilated(
-        images.astype(dt), params["stem"].astype(dt), (2, 2), "SAME",
-        dimension_numbers=("NHWC", "HWIO", "NHWC"))
-    x = jax.nn.silu(x)
+    with jax.named_scope("stem"):
+        x = jax.lax.conv_general_dilated(
+            images.astype(dt), params["stem"].astype(dt), (2, 2), "SAME",
+            dimension_numbers=("NHWC", "HWIO", "NHWC"))
+        x = jax.nn.silu(x)
 
     if kcfg is None:
         from ..configs.base import kernel_config
@@ -597,9 +598,10 @@ def efficientnet_b0_apply(params: dict, images: jax.Array,
             from jax.sharding import NamedSharding
             from jax.sharding import PartitionSpec as _P
             from ..kernels.convdk_sharded import MODEL_AXIS, _batch_axes
-            x = jax.lax.with_sharding_constraint(
-                x, NamedSharding(mesh, _P(_batch_axes(mesh), None, None,
-                                          MODEL_AXIS)))
+            with jax.named_scope("stem"):
+                x = jax.lax.with_sharding_constraint(
+                    x, NamedSharding(mesh, _P(_batch_axes(mesh), None,
+                                              None, MODEL_AXIS)))
 
     # the 16-block chain lowers through its dataflow-graph form: each
     # block is a BlockNode with explicit per-pass read/write buffer
@@ -613,10 +615,12 @@ def efficientnet_b0_apply(params: dict, images: jax.Array,
                                plan=plan)
     graph.validate()
     x = graph.lower(x)
-    x = jax.nn.silu(jnp.einsum("bhwc,cd->bhwd", x,
-                               params["head"].astype(x.dtype)))
-    x = x.mean(axis=(1, 2))
-    return x @ params["cls_w"].astype(x.dtype) + params["cls_b"].astype(x.dtype)
+    with jax.named_scope("head"):
+        x = jax.nn.silu(jnp.einsum("bhwc,cd->bhwd", x,
+                                   params["head"].astype(x.dtype)))
+        x = x.mean(axis=(1, 2))
+        return (x @ params["cls_w"].astype(x.dtype)
+                + params["cls_b"].astype(x.dtype))
 
 
 # ---------------------------------------------------------------------------
@@ -718,10 +722,11 @@ def mobilenet_v3_apply(params: dict, images: jax.Array,
     act/SE axes."""
     specs = mobilenet_v3_specs(cfg)
     dt = jnp.dtype(cfg.dtype)
-    x = jax.lax.conv_general_dilated(
-        images.astype(dt), params["stem"].astype(dt), (2, 2), "SAME",
-        dimension_numbers=("NHWC", "HWIO", "NHWC"))
-    x = jax.nn.hard_swish(x)
+    with jax.named_scope("stem"):
+        x = jax.lax.conv_general_dilated(
+            images.astype(dt), params["stem"].astype(dt), (2, 2), "SAME",
+            dimension_numbers=("NHWC", "HWIO", "NHWC"))
+        x = jax.nn.hard_swish(x)
 
     if kcfg is None:
         from ..configs.base import kernel_config
@@ -740,20 +745,23 @@ def mobilenet_v3_apply(params: dict, images: jax.Array,
             from jax.sharding import NamedSharding
             from jax.sharding import PartitionSpec as _P
             from ..kernels.convdk_sharded import MODEL_AXIS, _batch_axes
-            x = jax.lax.with_sharding_constraint(
-                x, NamedSharding(mesh, _P(_batch_axes(mesh), None, None,
-                                          MODEL_AXIS)))
+            with jax.named_scope("stem"):
+                x = jax.lax.with_sharding_constraint(
+                    x, NamedSharding(mesh, _P(_batch_axes(mesh), None,
+                                              None, MODEL_AXIS)))
 
     from .blockgraph import build_block_graph
     graph = build_block_graph(specs, params, kcfg=kcfg, mesh=mesh,
                               plan=plan)
     graph.validate()
     x = graph.lower(x)
-    x = jax.nn.hard_swish(jnp.einsum("bhwc,cd->bhwd", x,
-                                     params["head"].astype(x.dtype)))
-    x = x.mean(axis=(1, 2))
-    x = jax.nn.hard_swish(x @ params["fc"].astype(x.dtype))
-    return x @ params["cls_w"].astype(x.dtype) + params["cls_b"].astype(x.dtype)
+    with jax.named_scope("head"):
+        x = jax.nn.hard_swish(jnp.einsum("bhwc,cd->bhwd", x,
+                                         params["head"].astype(x.dtype)))
+        x = x.mean(axis=(1, 2))
+        x = jax.nn.hard_swish(x @ params["fc"].astype(x.dtype))
+        return (x @ params["cls_w"].astype(x.dtype)
+                + params["cls_b"].astype(x.dtype))
 
 
 # ---------------------------------------------------------------------------
@@ -843,10 +851,11 @@ def efficientnet_v2_s_apply(params: dict, images: jax.Array,
     replicated, the DP prices the boundary regathers accordingly)."""
     specs = effnet_v2_block_specs(cfg)
     dt = jnp.dtype(cfg.dtype)
-    x = jax.lax.conv_general_dilated(
-        images.astype(dt), params["stem"].astype(dt), (2, 2), "SAME",
-        dimension_numbers=("NHWC", "HWIO", "NHWC"))
-    x = jax.nn.silu(x)
+    with jax.named_scope("stem"):
+        x = jax.lax.conv_general_dilated(
+            images.astype(dt), params["stem"].astype(dt), (2, 2), "SAME",
+            dimension_numbers=("NHWC", "HWIO", "NHWC"))
+        x = jax.nn.silu(x)
 
     if kcfg is None:
         from ..configs.base import kernel_config
@@ -865,16 +874,19 @@ def efficientnet_v2_s_apply(params: dict, images: jax.Array,
             from jax.sharding import NamedSharding
             from jax.sharding import PartitionSpec as _P
             from ..kernels.convdk_sharded import MODEL_AXIS, _batch_axes
-            x = jax.lax.with_sharding_constraint(
-                x, NamedSharding(mesh, _P(_batch_axes(mesh), None, None,
-                                          MODEL_AXIS)))
+            with jax.named_scope("stem"):
+                x = jax.lax.with_sharding_constraint(
+                    x, NamedSharding(mesh, _P(_batch_axes(mesh), None,
+                                              None, MODEL_AXIS)))
 
     from .blockgraph import build_block_graph
     graph = build_block_graph(specs, params, kcfg=kcfg, mesh=mesh,
                               plan=plan)
     graph.validate()
     x = graph.lower(x)
-    x = jax.nn.silu(jnp.einsum("bhwc,cd->bhwd", x,
-                               params["head"].astype(x.dtype)))
-    x = x.mean(axis=(1, 2))
-    return x @ params["cls_w"].astype(x.dtype) + params["cls_b"].astype(x.dtype)
+    with jax.named_scope("head"):
+        x = jax.nn.silu(jnp.einsum("bhwc,cd->bhwd", x,
+                                   params["head"].astype(x.dtype)))
+        x = x.mean(axis=(1, 2))
+        return (x @ params["cls_w"].astype(x.dtype)
+                + params["cls_b"].astype(x.dtype))

@@ -23,7 +23,7 @@ here:
   bottleneck report and gates the reconciliation.
 * **Latency percentiles** — per-request latencies from blocked timings
   (``jax.block_until_ready``, the ``telemetry.measure`` discipline)
-  recorded as telemetry series alongside queue depth and wait times.
+  recorded as telemetry series alongside queue wait times.
 
 Admission is a TWO-LEVEL FIFO: ``submit(image, priority=1)`` places a
 request in the priority lane, which ``step`` drains ahead of the normal
@@ -50,7 +50,12 @@ Counter naming (shape-class first, then layer):
     serve.trace.r<res>               trace-time: retrace counter
     serve.pipelined_boundaries.r<res>  plan-time: solved overlap count
 
-Series: ``serve.queue_depth``, ``serve.queue_wait_s``, ``serve.latency_s``.
+Series: ``serve.queue_wait_s``, ``serve.latency_s``.
+
+Spans: ``serve.batch.r<res>`` around each launch, with the children
+``serve.pack`` (host batch build), ``serve.transfer`` (host to device),
+``serve.compute`` (the blocked call) and ``serve.copy_back`` (logits to
+the host); under a profiler trace they name the device's idle gaps.
 """
 
 from __future__ import annotations
@@ -211,7 +216,6 @@ class VisionEngine:
         telemetry.counter("serve.admitted")
         if priority > 0:
             telemetry.counter("serve.admitted.priority")
-        telemetry.record("serve.queue_depth", self.pending())
         return rid
 
     def pending(self) -> int:
@@ -314,16 +318,22 @@ class VisionEngine:
     def _launch(self, res: int, reqs: List[VisionRequest]
                 ) -> List[VisionResult]:
         plan = self.plan_for(res)
-        batch = np.zeros((self.scfg.batch_size, res, res, 3), np.float32)
-        for row, rq in enumerate(reqs):
-            h, w = rq.image.shape[:2]
-            batch[row, :h, :w, :] = rq.image
         fn = self._apply_for(res)
-        t_launch = time.perf_counter()
         with telemetry.span(f"serve.batch.r{res}"):
-            logits = jax.block_until_ready(
-                fn(self.params, jnp.asarray(batch)))
-        t_done = time.perf_counter()
+            with telemetry.span("serve.pack"):
+                batch = np.zeros((self.scfg.batch_size, res, res, 3),
+                                 np.float32)
+                for row, rq in enumerate(reqs):
+                    h, w = rq.image.shape[:2]
+                    batch[row, :h, :w, :] = rq.image
+            t_launch = time.perf_counter()
+            with telemetry.span("serve.transfer"):
+                x = jnp.asarray(batch)
+            with telemetry.span("serve.compute"):
+                logits = jax.block_until_ready(fn(self.params, x))
+            t_done = time.perf_counter()
+            with telemetry.span("serve.copy_back"):
+                arr = np.asarray(logits)
 
         telemetry.counter(f"serve.batches.r{res}")
         telemetry.counter(f"serve.requests.r{res}", len(reqs))
@@ -334,7 +344,6 @@ class VisionEngine:
             telemetry.counter(f"serve.collective.r{res}.{layer}", coll)
 
         share = plan.total_bytes / max(1, len(reqs))
-        arr = np.asarray(logits)
         results = []
         for row, rq in enumerate(reqs):
             latency = t_done - rq.t_submit

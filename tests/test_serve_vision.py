@@ -235,6 +235,26 @@ def test_priority_does_not_bypass_shedding(engine_parts):
     assert eng.pending() == 2
 
 
+def test_launch_spans_split_the_batch(engine_parts):
+    """Each launch is the span ``serve.batch.r<res>`` with one child span
+    per host phase: pack, transfer, compute, copy back."""
+    telemetry.reset()
+    eng = _engine(engine_parts, resolutions=(16,))
+    rng = np.random.default_rng(7)
+    for _ in range(4):
+        eng.submit(_img(rng, 16))
+    eng.drain()
+    spans = telemetry.snapshot()["spans"]
+    children = ("serve.pack", "serve.transfer", "serve.compute",
+                "serve.copy_back")
+    assert spans["serve.batch.r16"]["count"] == 2
+    for name in children:
+        assert spans[name]["count"] == 2
+    assert spans["serve.batch.r16"]["total_s"] >= sum(
+        spans[name]["total_s"] for name in children)
+    assert "serve.queue_depth" not in telemetry.snapshot()["series"]
+
+
 def test_pipelined_boundaries_counter(engine_parts):
     """Solving a bucket's plan records the solved overlap count — 0 on
     the degenerate (1,1) mesh is fine; what matters is the counter fires

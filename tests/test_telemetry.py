@@ -58,6 +58,58 @@ def test_span_records_on_exception():
     assert t.span_stat("boom").count == 1
 
 
+def test_nested_same_name_span_counts_outermost_only():
+    t = Telemetry()
+    with t.span("plan"):
+        with t.span("plan"):
+            with t.span("other"):
+                pass
+    assert t.span_stat("plan").count == 1
+    assert t.span_stat("other").count == 1
+    with t.span("plan"):                      # depth restored after exit
+        pass
+    assert t.span_stat("plan").count == 2
+
+
+def test_span_depth_is_per_thread():
+    import threading
+    t = Telemetry()
+    inner = threading.Event()
+    done = threading.Event()
+
+    def worker():
+        inner.wait()
+        with t.span("plan"):
+            pass
+        done.set()
+
+    th = threading.Thread(target=worker)
+    th.start()
+    with t.span("plan"):
+        inner.set()
+        assert done.wait(10)
+    th.join()
+    assert t.span_stat("plan").count == 2
+
+
+def test_span_writes_profiler_host_event(tmp_path):
+    """Under a profiler trace a span is an event of its name on the
+    ``/host:CPU`` plane, the plane the benchmark reads host spans from."""
+    import glob
+    from jax.profiler import ProfileData
+    t = Telemetry()
+    with jax.profiler.trace(str(tmp_path)):
+        with t.span("telemetry.probe_span"):
+            jax.block_until_ready(jnp.ones(4) + 1)
+    found = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    assert found
+    data = ProfileData.from_file(found[0])
+    names = {ev.name for plane in data.planes if plane.name == "/host:CPU"
+             for line in plane.lines for ev in line.events}
+    assert "telemetry.probe_span" in names
+    assert t.span_stat("telemetry.probe_span").count == 1
+
+
 def test_snapshot_is_json_ready_and_reset_clears():
     import json
 
