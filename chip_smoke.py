@@ -96,9 +96,8 @@ def reference_logits(params, images, cfg):
         p = params[f"block{i}"]
         expands = "exp" in p
         y = mbconv_ref(
-            x, p["exp"] if expands else jnp.eye(sp.c_mid, dtype=x.dtype),
-            p["dw"], p["se_w1"], p["se_b1"], p["se_w2"], p["se_b2"],
-            p["proj"], stride=sp.s, padding="SAME",
+            x, p.get("exp"), p["dw"], p["se_w1"], p["se_b1"], p["se_w2"],
+            p["se_b2"], p["proj"], stride=sp.s, padding="SAME",
             exp_act="silu" if expands else None, dw_act="silu",
             se_act="silu", gate_act="sigmoid")
         x = y + x if sp.has_residual else y

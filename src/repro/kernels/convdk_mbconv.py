@@ -46,8 +46,12 @@ crossover is priced per layer shape by ``core.perfmodel.mbconv_fused_traffic``
 and chosen by ``core.autotune.select_mbconv_schedule`` (MIREDO-style: the
 schedule is solved per block topology, not per op).
 
-Blocks with expansion ratio 1 (EfficientNet's MBConv1) pass the identity as
-``w_exp`` with ``exp_act=None`` — the kernel math is unchanged and exact.
+Blocks with expansion ratio 1 (EfficientNet's MBConv1, MobileNet-V3's first
+bottleneck) pass ``w_exp=None`` with ``exp_act=None``: there is no expand
+stage at all.  Their launches drop the c_in grid dimension, the expand
+weight and the expand accumulator; the staged window's channel block
+follows the c_mid grid dimension and the depthwise taps read the window
+itself.  A square ``w_exp`` a caller does pass still runs as a real expand.
 """
 
 from __future__ import annotations
@@ -60,6 +64,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from ..core import telemetry
 from ..core.perfmodel import (
     DEFAULT_COLLECTIVE,
     DEFAULT_RESIDENCY,
@@ -73,25 +78,20 @@ from .common import (
     round_up as _round_up,
 )
 from .ref import _act_ref, mbconv_ref
-from .staging import StripPlan, StripStream, strided_read, strip_plan
+from .staging import StripPlan, StripStream, StripWindow, strip_plan
 
 
-def _expanded_taps(acc_ref, w_dw_ref, *, exp_act, dw_act, k_h, k_w, stride,
-                   tile_h, out_w):
-    """Algorithm-2 tap loop over the finished expand accumulator.
-
-    acc_ref: (in_rows, w_tot, CM) f32 -> (tile_h, out_w, CM) f32.  Each tap
-    is one (strided, for s = 2) load from the ref — Mosaic cannot
-    stride-slice a loaded value — so the expand activation is applied to
-    the ref IN PLACE first.
-    """
-    if exp_act is not None:
-        acc_ref[...] = _act_ref(acc_ref[...], exp_act)
-    dw = jnp.zeros((tile_h, out_w, acc_ref.shape[-1]), jnp.float32)
+def _dw_taps(win: StripWindow, w_dw_ref, *, dw_act, k_h, k_w, stride, tile_h,
+             out_w):
+    """Algorithm-2 tap loop over a (in_rows, w_tot, CM) window ->
+    (tile_h, out_w, CM) f32.  Each tap is one (strided, for s = 2) load
+    from the window's ref — Mosaic cannot stride-slice a loaded value."""
+    dw = jnp.zeros((tile_h, out_w, w_dw_ref.shape[-1]), jnp.float32)
     for j in range(k_h):
         for i in range(k_w):
-            xs = strided_read(acc_ref, (), j, i, tile_h, out_w, stride)
-            dw = dw + xs * w_dw_ref[j, i].astype(jnp.float32)
+            xs = win.read(j, i, tile_h, out_w, stride)
+            dw = dw + xs.astype(jnp.float32) * w_dw_ref[j, i].astype(
+                jnp.float32)
     return _act_ref(dw, dw_act)
 
 
@@ -119,37 +119,60 @@ def _expand_accumulate(win, wexp_ref, acc_ref, *, ci):
         acc_ref[...] = acc_ref[...] + partial
 
 
-def _mbconv_pass1_kernel(x_ref, wexp_ref, wdw_ref, *rest,
-                         plan: StripPlan, k_h, k_w, stride, tile_h, out_w,
-                         out_h, valid_w, exp_act: Optional[str],
-                         dw_act: Optional[str], se: bool, retain: bool):
-    """One (batch, c_mid-block, row-strip, c_in-block) grid cell of pass 1.
+def _strip_dw(win: StripWindow, wexp_ref, wdw_ref, acc_refs, *, ci_dim,
+              exp_act, on_dw, **taps):
+    """Call ``on_dw(dw)`` with this cell's finished DW strip.
+
+    With an expand (``wexp_ref`` given), the window's c_in block
+    accumulates into ``acc_refs[0]`` across grid dim ``ci_dim``; at the
+    last c_in block the expand activation is applied to the accumulator IN
+    PLACE (the taps load from the ref) and the taps run over it.  Without
+    one (expansion ratio 1) the taps read the staged window itself.
+    """
+    if wexp_ref is None:
+        on_dw(_dw_taps(win, wdw_ref, **taps))
+        return
+    (acc_ref,) = acc_refs
+    ci = pl.program_id(ci_dim)
+    _expand_accumulate(win.read(), wexp_ref, acc_ref, ci=ci)
+
+    @pl.when(ci == pl.num_programs(ci_dim) - 1)
+    def _finish_strip():
+        if exp_act is not None:
+            acc_ref[...] = _act_ref(acc_ref[...], exp_act)
+        acc = StripWindow(acc_ref, (), 0, *acc_ref.shape[:2])
+        on_dw(_dw_taps(acc, wdw_ref, **taps))
+
+
+def _mbconv_pass1_kernel(x_ref, *refs, plan: StripPlan, expand: bool, k_h,
+                         k_w, stride, tile_h, out_w, out_h, valid_w,
+                         exp_act: Optional[str], dw_act: Optional[str],
+                         se: bool, retain: bool):
+    """One (batch, c_mid-block, row-strip[, c_in-block]) grid cell of
+    pass 1 (the c_in dim only when ``expand``).
 
     x_ref    : unstaged input (engine-staged per ``plan``)
-    wexp_ref : (CI, CM)               expand-PW block
+    wexp_ref : (CI, CM)               expand-PW block, only when ``expand``
     wdw_ref  : (k_h, k_w, CM)         depthwise taps
-    rest     : (pool_ref,) if se — the (1, 1, CM) on-chip SE pool
+    then     : (pool_ref,) if se — the (1, 1, CM) on-chip SE pool
                accumulator (sums) — then (dw_out_ref,) if retain, then
-               acc_ref + staging refs.  An se=off launch carries NO pool
-               output at all: the no-SE block pays zero pool VMEM/HBM.
+               acc_ref (only when ``expand``) + staging refs.  An se=off
+               launch carries NO pool output at all: the no-SE block pays
+               zero pool VMEM/HBM.
     """
-    rest = tuple(rest)
+    if expand:
+        wexp_ref, wdw_ref, *rest = refs
+    else:
+        wexp_ref, (wdw_ref, *rest) = None, refs
     if se:
         pool_ref, *rest = rest
     if retain:
         dwo_ref, *rest = rest
-    stage_refs, (acc_ref,) = plan.take_scratch(tuple(rest))
+    stage_refs, acc_refs = plan.take_scratch(tuple(rest))
     ti = pl.program_id(2)
-    ci = pl.program_id(3)
-    n_ci = pl.num_programs(3)
     win = StripStream(plan, x_ref, stage_refs).get()
-    _expand_accumulate(win.read(), wexp_ref, acc_ref, ci=ci)
 
-    @pl.when(ci == n_ci - 1)
-    def _finish_strip():
-        dw = _expanded_taps(acc_ref, wdw_ref, exp_act=exp_act, dw_act=dw_act,
-                            k_h=k_h, k_w=k_w, stride=stride, tile_h=tile_h,
-                            out_w=out_w)
+    def finish(dw):
         if se:
             # mask strip rows past out_h and the sublane-cover columns past
             # valid_w so they never enter the pool (full-rank iotas: Mosaic
@@ -171,35 +194,36 @@ def _mbconv_pass1_kernel(x_ref, wexp_ref, wdw_ref, *rest,
         if retain:
             dwo_ref[0] = dw.astype(dwo_ref.dtype)
 
+    _strip_dw(win, wexp_ref, wdw_ref, acc_refs, ci_dim=3, exp_act=exp_act,
+              on_dw=finish, dw_act=dw_act, k_h=k_h, k_w=k_w, stride=stride,
+              tile_h=tile_h, out_w=out_w)
 
-def _mbconv_pass2_recompute_kernel(x_ref, wexp_ref, wdw_ref, *rest,
-                                   plan: StripPlan, k_h, k_w, stride,
-                                   tile_h, out_w, exp_act: Optional[str],
+
+def _mbconv_pass2_recompute_kernel(x_ref, *refs, plan: StripPlan,
+                                   expand: bool, k_h, k_w, stride, tile_h,
+                                   out_w, exp_act: Optional[str],
                                    dw_act: Optional[str], se: bool):
-    """One (batch, c_out-block, row-strip, c_mid-block, c_in-block) cell.
+    """One (batch, c_out-block, row-strip, c_mid-block[, c_in-block]) cell
+    (the c_in dim only when ``expand``).
 
     Recomputes expand+DW exactly as pass 1 (the DW tensor never existed in
     HBM), multiplies by the SE gate (when ``se`` — an se=off launch carries
     no scale input at all) and contracts with the projection block —
     partial projection sums carried across the c_mid grid dimension.
     """
-    rest = tuple(rest)
+    if expand:
+        wexp_ref, wdw_ref, *rest = refs
+    else:
+        wexp_ref, (wdw_ref, *rest) = None, refs
     if se:
         scale_ref, *rest = rest
     wproj_ref, o_ref, *scratch = rest
-    stage_refs, (acc_ref, proj_ref) = plan.take_scratch(tuple(scratch))
+    stage_refs, (*acc_refs, proj_ref) = plan.take_scratch(tuple(scratch))
     cm = pl.program_id(3)
-    ci = pl.program_id(4)
     n_cm = pl.num_programs(3)
-    n_ci = pl.num_programs(4)
     win = StripStream(plan, x_ref, stage_refs).get()
-    _expand_accumulate(win.read(), wexp_ref, acc_ref, ci=ci)
 
-    @pl.when(ci == n_ci - 1)
-    def _project():
-        dw = _expanded_taps(acc_ref, wdw_ref, exp_act=exp_act, dw_act=dw_act,
-                            k_h=k_h, k_w=k_w, stride=stride, tile_h=tile_h,
-                            out_w=out_w)
+    def project(dw):
         if se:
             dw = dw * scale_ref[0, 0].astype(jnp.float32)
         partial = jax.lax.dot_general(
@@ -220,6 +244,10 @@ def _mbconv_pass2_recompute_kernel(x_ref, wexp_ref, wdw_ref, *rest,
         @pl.when(cm == n_cm - 1)
         def _finalize():
             o_ref[0] = proj_ref[...].astype(o_ref.dtype)
+
+    _strip_dw(win, wexp_ref, wdw_ref, acc_refs, ci_dim=4, exp_act=exp_act,
+              on_dw=project, dw_act=dw_act, k_h=k_h, k_w=k_w, stride=stride,
+              tile_h=tile_h, out_w=out_w)
 
 
 def _mbconv_pass2_retain_kernel(dw_ref, *rest, plan: StripPlan, tile_h,
@@ -260,6 +288,48 @@ def _mbconv_pass2_retain_kernel(dw_ref, *rest, plan: StripPlan, tile_h,
         o_ref[0] = proj_ref[...].astype(o_ref.dtype)
 
 
+def _input_stream(x_pad, w_exp, w_dw, head, cm_dim, *, ci_block, cm_block,
+                  tile_h, stride, residency):
+    """Grid, staged-input plan, and the (x, [w_exp,] w_dw) in_specs and
+    operands that pass 1 and recompute pass 2 share.
+
+    ``head`` is the kernel's grid without the c_in dim and ``cm_dim`` its
+    c_mid dim.  A real expand appends the c_in reduction dim, which picks
+    the window's channel block.  ``w_exp=None`` (expansion ratio 1) adds no
+    dim: the window's channel block is the c_mid block the taps read.
+    """
+    b, h_tot, w_pad, ci_pad = x_pad.shape
+    k_h, k_w, cm_pad = w_dw.shape
+    if w_exp is not None:
+        grid, chan_dim = (*head, ci_pad // ci_block), len(head)
+    else:
+        assert (ci_pad, ci_block) == (cm_pad, cm_block), (
+            ci_pad, ci_block, cm_pad, cm_block)
+        telemetry.counter("kernels.mbconv.identity_expand")
+        grid, chan_dim = tuple(head), cm_dim
+    plan = strip_plan(
+        h_tot=h_tot, w_tot=w_pad, c_block=ci_block, tile_h=tile_h,
+        grid=grid, window_dims=(0, 2, chan_dim), stride=stride, k_h=k_h,
+        residency=residency)
+    in_specs = [plan.in_spec(lambda *g: (g[0], 0, 0, g[chan_dim]))]
+    operands = [x_pad]
+    if w_exp is not None:
+        in_specs.append(pl.BlockSpec((ci_block, cm_block),
+                                     lambda *g: (g[chan_dim], g[cm_dim])))
+        operands.append(w_exp)
+    in_specs.append(pl.BlockSpec((k_h, k_w, cm_block),
+                                 lambda *g: (0, 0, g[cm_dim])))
+    operands.append(w_dw)
+    return grid, plan, in_specs, operands
+
+
+def _expand_scratch(w_exp, plan: StripPlan, w_pad, cm_block) -> list:
+    """The f32 expand accumulator — none without an expand."""
+    if w_exp is None:
+        return []
+    return [pltpu.VMEM((plan.in_rows, w_pad, cm_block), jnp.float32)]
+
+
 def mbconv_pass1_pallas(x_pad, w_exp, w_dw, *, stride, out_w, out_h, valid_w,
                         tile_h, n_th, ci_block, cm_block, exp_act, dw_act,
                         retain, interpret, se=True,
@@ -271,52 +341,45 @@ def mbconv_pass1_pallas(x_pad, w_exp, w_dw, *, stride, out_w, out_h, valid_w,
 
     ``se=False`` drops the pool output (and its VMEM accumulator) from the
     launch entirely — an se=off retain pass writes only the DW tensor.
+    ``w_exp=None`` launches the block with no expand stage.
     """
     assert se or retain, "se=off + recompute has no pass 1 at all"
-    b, h_tot, w_pad, ci_pad = x_pad.shape
-    k_h, k_w, cm_pad = w_dw.shape
-    grid = (b, cm_pad // cm_block, n_th, ci_pad // ci_block)
-
-    plan = strip_plan(
-        h_tot=h_tot, w_tot=w_pad, c_block=ci_block, tile_h=tile_h,
-        grid=grid, window_dims=(0, 2, 3), stride=stride, k_h=k_h,
+    b, _, w_pad, _ = x_pad.shape
+    cm_pad = w_dw.shape[-1]
+    grid, plan, in_specs, operands = _input_stream(
+        x_pad, w_exp, w_dw, (b, cm_pad // cm_block, n_th), 1,
+        ci_block=ci_block, cm_block=cm_block, tile_h=tile_h, stride=stride,
         residency=residency)
     kernel = functools.partial(
-        _mbconv_pass1_kernel, plan=plan, k_h=k_h, k_w=k_w, stride=stride,
-        tile_h=tile_h, out_w=out_w, out_h=out_h, valid_w=valid_w,
-        exp_act=exp_act,
+        _mbconv_pass1_kernel, plan=plan, expand=w_exp is not None,
+        k_h=w_dw.shape[0], k_w=w_dw.shape[1], stride=stride, tile_h=tile_h,
+        out_w=out_w, out_h=out_h, valid_w=valid_w, exp_act=exp_act,
         dw_act=dw_act, se=se, retain=retain)
     out_shape = []
     out_specs = []
     if se:
         out_shape.append(jax.ShapeDtypeStruct((b, 1, cm_pad), jnp.float32))
         out_specs.append(pl.BlockSpec((1, 1, cm_block),
-                                      lambda bi, cm, ti, ci: (bi, 0, cm)))
+                                      lambda bi, cm, ti, *_: (bi, 0, cm)))
     if retain:
         out_shape.append(jax.ShapeDtypeStruct(
             (b, n_th * tile_h, out_w, cm_pad), x_pad.dtype))
         out_specs.append(pl.BlockSpec(
             (1, tile_h, out_w, cm_block),
-            lambda bi, cm, ti, ci: (bi, ti, 0, cm)))
+            lambda bi, cm, ti, *_: (bi, ti, 0, cm)))
     outs = pl.pallas_call(
         kernel,
         grid=grid,
-        in_specs=[
-            plan.in_spec(lambda bi, cm, ti, ci: (bi, 0, 0, ci)),
-            pl.BlockSpec((ci_block, cm_block),
-                         lambda bi, cm, ti, ci: (ci, cm)),
-            pl.BlockSpec((k_h, k_w, cm_block),
-                         lambda bi, cm, ti, ci: (0, 0, cm)),
-        ],
+        in_specs=in_specs,
         out_specs=out_specs,
         out_shape=out_shape,
         scratch_shapes=[
-            pltpu.VMEM((plan.in_rows, w_pad, cm_block), jnp.float32),
+            *_expand_scratch(w_exp, plan, w_pad, cm_block),
             *plan.scratch_shapes(x_pad.dtype)],
         compiler_params=compiler_params(),
         interpret=interpret,
         name="mbconv_pass1",
-    )(x_pad, w_exp, w_dw)
+    )(*operands)
     outs = list(outs)
     pool = outs.pop(0) if se else None
     dw_ret = outs.pop(0) if retain else None
@@ -328,36 +391,27 @@ def mbconv_pass2_recompute_pallas(x_pad, w_exp, w_dw, scale, w_proj, *,
                                   cm_block, co_block, exp_act, dw_act,
                                   interpret, residency=DEFAULT_RESIDENCY):
     """``scale=None`` launches the se=off variant: no gate input, no gate
-    multiply — the no-SE block pays zero scale bytes."""
+    multiply — the no-SE block pays zero scale bytes.  ``w_exp=None``
+    launches the block with no expand stage."""
     se = scale is not None
-    b, h_tot, w_pad, ci_pad = x_pad.shape
-    k_h, k_w, cm_pad = w_dw.shape
+    b, _, w_pad, _ = x_pad.shape
+    cm_pad = w_dw.shape[-1]
     co_pad = w_proj.shape[1]
-    grid = (b, co_pad // co_block, n_th, cm_pad // cm_block,
-            ci_pad // ci_block)
-
-    plan = strip_plan(
-        h_tot=h_tot, w_tot=w_pad, c_block=ci_block, tile_h=tile_h,
-        grid=grid, window_dims=(0, 2, 4), stride=stride, k_h=k_h,
+    grid, plan, in_specs, operands = _input_stream(
+        x_pad, w_exp, w_dw,
+        (b, co_pad // co_block, n_th, cm_pad // cm_block), 3,
+        ci_block=ci_block, cm_block=cm_block, tile_h=tile_h, stride=stride,
         residency=residency)
     kernel = functools.partial(
-        _mbconv_pass2_recompute_kernel, plan=plan, k_h=k_h, k_w=k_w,
-        stride=stride, tile_h=tile_h, out_w=out_w, exp_act=exp_act,
-        dw_act=dw_act, se=se)
-    in_specs = [
-        plan.in_spec(lambda bi, co, ti, cm, ci: (bi, 0, 0, ci)),
-        pl.BlockSpec((ci_block, cm_block),
-                     lambda bi, co, ti, cm, ci: (ci, cm)),
-        pl.BlockSpec((k_h, k_w, cm_block),
-                     lambda bi, co, ti, cm, ci: (0, 0, cm)),
-    ]
-    operands = [x_pad, w_exp, w_dw]
+        _mbconv_pass2_recompute_kernel, plan=plan, expand=w_exp is not None,
+        k_h=w_dw.shape[0], k_w=w_dw.shape[1], stride=stride, tile_h=tile_h,
+        out_w=out_w, exp_act=exp_act, dw_act=dw_act, se=se)
     if se:
         in_specs.append(pl.BlockSpec((1, 1, cm_block),
-                                     lambda bi, co, ti, cm, ci: (bi, 0, cm)))
+                                     lambda bi, co, ti, cm, *_: (bi, 0, cm)))
         operands.append(scale)
     in_specs.append(pl.BlockSpec((cm_block, co_block),
-                                 lambda bi, co, ti, cm, ci: (cm, co)))
+                                 lambda bi, co, ti, cm, *_: (cm, co)))
     operands.append(w_proj)
     return pl.pallas_call(
         kernel,
@@ -365,11 +419,11 @@ def mbconv_pass2_recompute_pallas(x_pad, w_exp, w_dw, scale, w_proj, *,
         in_specs=in_specs,
         out_specs=pl.BlockSpec(
             (1, tile_h, out_w, co_block),
-            lambda bi, co, ti, cm, ci: (bi, ti, 0, co)),
+            lambda bi, co, ti, *_: (bi, ti, 0, co)),
         out_shape=jax.ShapeDtypeStruct(
             (b, n_th * tile_h, out_w, co_pad), x_pad.dtype),
         scratch_shapes=[
-            pltpu.VMEM((plan.in_rows, w_pad, cm_block), jnp.float32),
+            *_expand_scratch(w_exp, plan, w_pad, cm_block),
             pltpu.VMEM((tile_h, out_w, co_block), jnp.float32),
             *plan.scratch_shapes(x_pad.dtype),
         ],
@@ -455,12 +509,18 @@ def _mbconv_impl(x, w_exp, w_dw, w_se1, b_se1, w_se2, b_se2, w_proj, stride,
     scale input all disappear — and under ``mode="recompute"`` pass 1 is
     skipped ENTIRELY (it would produce nothing).  ``se_act``/``gate_act``
     parameterize the SE MLP's nonlinearities (V3 uses relu/hard_sigmoid).
+
+    ``w_exp is None`` (with ``exp_act=None``) is a block with no expand
+    convolution: the kernels' taps read the staged input window directly.
     """
     validate_collective(collective)
     se = w_se1 is not None
     b, h, w_in, c_in = x.shape
     k_h, k_w, c_mid = w_dw.shape
-    assert w_exp.shape == (c_in, c_mid), (w_exp.shape, c_in, c_mid)
+    if w_exp is None:
+        assert exp_act is None and c_in == c_mid, (exp_act, c_in, c_mid)
+    else:
+        assert w_exp.shape == (c_in, c_mid), (w_exp.shape, c_in, c_mid)
     c_out = w_proj.shape[1]
     assert w_proj.shape[0] == c_mid, (w_proj.shape, c_mid)
     assert mode in ("retain", "recompute"), mode
@@ -477,7 +537,8 @@ def _mbconv_impl(x, w_exp, w_dw, w_se1, b_se1, w_se2, b_se2, w_proj, stride,
     co_pad = _round_up(c_out, co_block)
 
     xp = jnp.pad(x, (*geo.pads, (0, ci_pad - c_in)))
-    wexp_p = jnp.pad(w_exp, ((0, ci_pad - c_in), (0, cm_pad - c_mid)))
+    wexp_p = (None if w_exp is None else
+              jnp.pad(w_exp, ((0, ci_pad - c_in), (0, cm_pad - c_mid))))
     wdw_p = jnp.pad(w_dw, ((0, 0), (0, 0), (0, cm_pad - c_mid)))
     wproj_p = jnp.pad(w_proj, ((0, cm_pad - c_mid), (0, co_pad - c_out)))
 
@@ -568,8 +629,9 @@ def _mbconv_bwd(stride, padding, tile_h, mode, exp_act, dw_act, interpret,
     # Backward through the mathematically identical reference composition —
     # the two-pass kernel computes the same MBConv block, so the VJP is
     # exact (same pattern as convdk_fused's VJP).  mbconv_ref skips the SE
-    # stage for w_se1=None, matching the se=off kernel path; the SE-param
-    # cotangents come back as None there, as custom_vjp expects.
+    # stage for w_se1=None and the expand for w_exp=None, matching the
+    # kernel paths; those params' cotangents come back as None there, as
+    # custom_vjp expects.
     _, vjp = jax.vjp(
         lambda *p: mbconv_ref(*p, stride=stride, padding=padding,
                               exp_act=exp_act, dw_act=dw_act,
@@ -590,7 +652,7 @@ _mbconv_op.defvjp(_mbconv_fwd, _mbconv_bwd)
 )
 def convdk_mbconv_fused(
     x: jax.Array,
-    w_exp: jax.Array,
+    w_exp: Optional[jax.Array],
     w_dw: jax.Array,
     w_se1: Optional[jax.Array],
     b_se1: Optional[jax.Array],
@@ -613,8 +675,9 @@ def convdk_mbconv_fused(
     (differentiable).  No residual add — the model layer owns that.
 
     x      : (B, H, W, C_in) NHWC
-    w_exp  : (C_in, C_mid) expand PW (identity + ``exp_act=None`` for
-             expansion ratio 1)
+    w_exp  : (C_in, C_mid) expand PW, or ``None`` with ``exp_act=None``
+             for expansion ratio 1 (no expand convolution: the depthwise
+             taps act on ``x`` itself, ``C_mid == C_in``)
     w_dw   : (k_h, k_w, C_mid) depthwise taps
     w_se1/b_se1, w_se2/b_se2 : SE squeeze/excite FCs — pass ALL FOUR as
              ``None`` for a no-SE block (MobileNet-V3's early/middle
@@ -646,7 +709,7 @@ def convdk_mbconv_fused(
 )
 def convdk_mbconv_staged(
     x: jax.Array,
-    w_exp: jax.Array,
+    w_exp: Optional[jax.Array],
     w_dw: jax.Array,
     w_se1: Optional[jax.Array],
     b_se1: Optional[jax.Array],
@@ -675,8 +738,9 @@ def convdk_mbconv_staged(
 
     if interpret is None:
         interpret = default_interpret()
-    e = jnp.einsum("bhwc,cd->bhwd", x.astype(jnp.float32),
-                   w_exp.astype(jnp.float32))
+    e = x.astype(jnp.float32)
+    if w_exp is not None:
+        e = jnp.einsum("bhwc,cd->bhwd", e, w_exp.astype(jnp.float32))
     e = _act_ref(e, exp_act)
     d = convdk_depthwise2d(e, w_dw.astype(jnp.float32), stride=stride,
                            padding=padding, tile_h=tile_h,

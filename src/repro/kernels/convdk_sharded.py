@@ -331,26 +331,22 @@ def _mbconv_sharded_impl(x, w_exp, w_dw, w_se1, b_se1, w_se2, b_se2, w_proj,
         raise ValueError(
             f"model_sharded in_layout needs c_in % {MODEL_AXIS} == 0, got "
             f"c_in={c_in} over {MODEL_AXIS}={mp}")
-    # identity-expand blocks (the model layer's expand_ratio == 1 form:
-    # w_exp == I, exp_act None) consume a c_in-sharded arrival FREE — the
-    # arriving slice IS the c_mid slice.  A real expand is dense over ALL
-    # of c_in, so a sharded arrival must be gathered back at the entry
-    # (priced as perfmodel's transition_words; the ISSUE's row-sharded
-    # expand alternative would need a psum BEFORE the nonlinear exp_act
+    # identity-expand blocks (expansion ratio 1: w_exp None, no expand
+    # convolution) take their input channels sharded like c_mid — the
+    # input's c_in slice IS the shard's c_mid slice, so a c_in-sharded
+    # arrival enters FREE and a replicated one is sliced locally.  A real
+    # expand is dense over ALL of c_in, so a sharded arrival must be
+    # gathered back at the entry (priced as perfmodel's transition_words;
+    # a row-sharded expand would need a psum BEFORE the nonlinear exp_act
     # inside pass 1 — not expressible at this level — and prices e>=1x
     # worse than the gather anyway).
-    identity_expand = c_in == w_dw.shape[-1] and exp_act is None
+    identity_expand = w_exp is None
     TRACE_COUNTS["mbconv"] += 1
 
     def local(xl, wel, wdl, s1l, b1l, s2l, b2l, wpl):
-        if sharded_in:
-            if identity_expand:
-                # free entry: the c_in slice is the c_mid slice; the
-                # identity expand restates itself at the local width
-                wel = jnp.eye(xl.shape[-1], dtype=wel.dtype)
-            else:
-                # gather entry: the dense expand needs all of c_in
-                xl = jax.lax.all_gather(xl, MODEL_AXIS, axis=3, tiled=True)
+        if sharded_in and not identity_expand:
+            # gather entry: the dense expand needs all of c_in
+            xl = jax.lax.all_gather(xl, MODEL_AXIS, axis=3, tiled=True)
         return _mbconv_impl(xl, wel, wdl, s1l, b1l, s2l, b2l, wpl, stride,
                             padding, tile_h, mode, exp_act, dw_act,
                             interpret, residency, se_act=se_act,
@@ -358,11 +354,8 @@ def _mbconv_sharded_impl(x, w_exp, w_dw, w_se1, b_se1, w_se2, b_se2, w_proj,
                             collective=collective, scatter_width=cw)
 
     batch = _batch_axes(mesh)
-    x_spec = P(batch, None, None, MODEL_AXIS if sharded_in else None)
-    # free entry: the local identity expand replaces the (sharded-column)
-    # w_exp slice, so its spec only has to partition consistently
-    exp_spec = (P(MODEL_AXIS, None) if (sharded_in and identity_expand)
-                else P(None, MODEL_AXIS))
+    x_spec = P(batch, None, None,
+               MODEL_AXIS if (sharded_in or identity_expand) else None)
     # the layout-aware exit: under psum_scatter each shard keeps only its
     # c_out slice, so the output leaves sharded on "model" — a following
     # PW/block that consumes c_out-sharded activations needs no regather
@@ -373,7 +366,7 @@ def _mbconv_sharded_impl(x, w_exp, w_dw, w_se1, b_se1, w_se2, b_se2, w_proj,
         local, mesh,
         in_specs=(x_spec,                           # batch slice (+ C_in
                                                     #   slice when sharded-in)
-                  exp_spec,                         # expand columns
+                  P(None, MODEL_AXIS),              # expand columns
                   P(None, None, MODEL_AXIS),        # DW taps per channel
                   P(MODEL_AXIS, None),              # squeeze FC rows
                   P(None),                          # squeeze bias (replicated:
@@ -462,7 +455,7 @@ def _mbconv_sharded_entry(mesh, stride, padding, tile_h, mode, exp_act,
 
 def convdk_mbconv_fused_sharded(
     x: jax.Array,
-    w_exp: jax.Array,
+    w_exp: Optional[jax.Array],
     w_dw: jax.Array,
     w_se1: Optional[jax.Array],
     b_se1: Optional[jax.Array],
@@ -507,10 +500,9 @@ def convdk_mbconv_fused_sharded(
     ``"replicated"`` (default) streams the full c_in per device;
     ``"model_sharded"`` (requires ``c_in % model == 0``) takes a
     c_in-sharded ``x`` — collective-free for identity-expand blocks
-    (``exp_act is None`` and ``c_in == c_mid``; the model layer's
-    expand_ratio == 1 form, whose ``w_exp`` is the identity), via an
-    entry ``all_gather`` otherwise (a real expand is dense over all of
-    c_in).
+    (``w_exp=None`` with ``exp_act=None``: expansion ratio 1, no expand
+    convolution, ``c_in == c_mid``), via an entry ``all_gather``
+    otherwise (a real expand is dense over all of c_in).
 
     Collective + transition bytes are priced by
     ``core.perfmodel.sharded_mbconv_traffic`` under the same axes.

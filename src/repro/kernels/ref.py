@@ -73,7 +73,7 @@ def separable_ref(
 
 def mbconv_ref(
     x: jax.Array,
-    w_exp: jax.Array,
+    w_exp: Optional[jax.Array],
     w_dw: jax.Array,
     w_se1: Optional[jax.Array],
     b_se1: Optional[jax.Array],
@@ -97,19 +97,24 @@ def mbconv_ref(
 
     x: (B, H, W, C_in); w_exp: (C_in, C_mid); w_dw: (k, k, C_mid);
     w_se1/b_se1: (C_mid, C_se)/(C_se,); w_se2/b_se2: (C_se, C_mid)/(C_mid,);
-    w_proj: (C_mid, C_out).  For expand_ratio == 1 blocks pass the identity
-    as ``w_exp`` with ``exp_act=None`` (the kernel does the same).  For
+    w_proj: (C_mid, C_out).  For expand_ratio == 1 blocks pass
+    ``w_exp=None`` with ``exp_act=None``: there is no expand, the depthwise
+    taps act on ``x`` itself (the kernel does the same).  For
     no-SE blocks (MobileNet-V3's early/middle stages) pass ``w_se1=None``
     — the pool, both FCs and the gate multiply disappear, exactly like the
     se=off kernel path.  EfficientNet keeps the (silu, sigmoid) defaults;
     MobileNet-V3's SE uses ``se_act="relu"``/``gate_act="hard_sigmoid"``.
     All contractions run in f32, matching the fused kernel's accumulators.
     """
-    e = jax.lax.dot_general(
-        x.astype(jnp.float32), w_exp.astype(jnp.float32),
-        dimension_numbers=(((3,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )
+    e = x.astype(jnp.float32)
+    if w_exp is None:
+        assert exp_act is None, exp_act
+    else:
+        e = jax.lax.dot_general(
+            e, w_exp.astype(jnp.float32),
+            dimension_numbers=(((3,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )
     e = _act_ref(e, exp_act)
     d = depthwise2d_ref(e, w_dw.astype(jnp.float32), stride=stride,
                         padding=padding)

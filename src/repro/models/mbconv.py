@@ -331,10 +331,10 @@ def mbconv_block(
         w_exp = params["exp"].astype(x.dtype)
         eff_exp_act = exp_act
     else:
-        # expansion ratio 1 (MBConv1): identity expand, no expand activation
+        # expansion ratio 1 (MBConv1): no expand convolution and no expand
+        # activation — the kernels' taps read the block input directly
         assert c_mid == c_in, (c_mid, c_in)
-        w_exp = jnp.eye(c_mid, dtype=x.dtype)
-        eff_exp_act = None
+        w_exp, eff_exp_act = None, None
 
     sharded = (mesh is not None and eff.shard and eff.fused
                and can_shard_fused(mesh, x.shape[0], c_mid))

@@ -12,6 +12,7 @@ process may load the TPU library, and every test worker imports this file.
 """
 
 import functools
+import re
 
 import jax
 import jax.numpy as jnp
@@ -71,14 +72,32 @@ def _assert_kernel(fn, *args):
     assert "tpu_custom_call" in hlo
 
 
-def _mbconv_args(sharding, hw, c_in, c_mid, c_out, k, se=True):
+def _mbconv_args(sharding, hw, c_in, c_mid, c_out, k, se=True,
+                 expand=True, batch=BATCH):
+    """Abstract kernel arguments; ``expand=False`` passes ``w_exp=None``
+    (a block with no expand convolution)."""
     c_se = max(1, c_in // 4)
     se_args = ((_spec(sharding, c_mid, c_se), _spec(sharding, c_se),
                 _spec(sharding, c_se, c_mid), _spec(sharding, c_mid))
                if se else (None,) * 4)
-    return (_spec(sharding, BATCH, hw, hw, c_in),
-            _spec(sharding, c_in, c_mid), _spec(sharding, k, k, c_mid),
+    return (_spec(sharding, batch, hw, hw, c_in),
+            _spec(sharding, c_in, c_mid) if expand else None,
+            _spec(sharding, k, k, c_mid),
             *se_args, _spec(sharding, c_mid, c_out))
+
+
+def _kernel_operands(hlo: str) -> dict:
+    """{kernel name: operand count} of each Pallas launch in compiled HLO
+    text (the instruction name less its ``.<n>`` suffix)."""
+    found = {}
+    for line in hlo.splitlines():
+        if 'custom_call_target="tpu_custom_call"' not in line:
+            continue
+        m = re.search(r"%([A-Za-z_0-9]+?)(?:\.\d+)? = .*?custom-call\(([^)]*)\)",
+                      line)
+        assert m is not None, line
+        found[m.group(1)] = m.group(2).count("%")
+    return found
 
 
 @pytest.mark.parametrize("row", [2, 3], ids=["s1_56x144", "s2_56x144"])
@@ -97,7 +116,7 @@ def test_fused_separable_compiles(one_chip, row):
 @pytest.mark.parametrize("mode", ["retain", "recompute"])
 @pytest.mark.parametrize("block", [0, 3], ids=["b0_block0", "b0_block3"])
 def test_mbconv_passes_compile(one_chip, block, mode):
-    """B0 block 0 (112 x 112, identity expand) and block 3 (144 channels,
+    """B0 block 0 (112 x 112, no expand) and block 3 (144 channels,
     5 x 5, stride 2), each pass-2 mode under its own solved schedule."""
     c_in, c_out, e, k, s, hw = EFFICIENTNET_B0_MBCONV[block]
     c_mid = c_in * e
@@ -107,7 +126,44 @@ def test_mbconv_passes_compile(one_chip, block, mode):
         convdk_mbconv_fused, stride=s, tile_h=sch.tile_h, mode=mode,
         exp_act="silu" if e > 1 else None, interpret=False,
         residency=sch.residency)
-    _assert_kernel(fn, *_mbconv_args(one_chip, hw, c_in, c_mid, c_out, k))
+    _assert_kernel(fn, *_mbconv_args(one_chip, hw, c_in, c_mid, c_out, k,
+                                     expand=e > 1))
+
+
+@pytest.mark.parametrize("model", ["b0", "v3l"])
+def test_identity_expand_drops_the_expand_operand(one_chip, model):
+    """Block 0 of B0 (32 -> 32 -> 16, SE) and of V3-L (16 -> 16 -> 16, no
+    SE) at the offline cells' batch 256 and 112 x 112, under the solved
+    schedule: with ``w_exp=None`` every launch that recomputes the DW
+    strip takes one operand fewer than with an explicit square ``w_exp``
+    (the expand weight and its matmul are gone from the kernel)."""
+    batch, hw = 256, 112
+    if model == "b0":
+        c_in, c_out, _, k, s, _ = EFFICIENTNET_B0_MBCONV[0]
+        se, act, se_ratio = True, "silu", 0.25
+        se_act, gate_act = "silu", "sigmoid"
+    else:
+        c_mid, c_out, k, s, se, act = MOBILENET_V3_LARGE_BLOCKS[0]
+        c_in, se_ratio, se_act, gate_act = c_mid, 0.0, "relu", \
+            "hard_sigmoid"
+    sch = get_mbconv_schedule(batch, hw, hw, c_in, c_in, c_out, k, s,
+                              se_ratio=se_ratio, act=act)
+    assert sch.mode == "recompute", sch
+    fn = functools.partial(
+        convdk_mbconv_fused, stride=s, tile_h=sch.tile_h, mode=sch.mode,
+        exp_act=None, dw_act=act, se_act=se_act, gate_act=gate_act,
+        interpret=False, residency=sch.residency)
+    ops = {}
+    for expand in (True, False):
+        args = _mbconv_args(one_chip, hw, c_in, c_in, c_out, k, se=se,
+                            expand=expand, batch=batch)
+        ops[expand] = _kernel_operands(
+            jax.jit(fn).lower(*args).compile().as_text())
+    launches = ({"mbconv_pass1", "mbconv_pass2_recompute"} if se
+                else {"mbconv_pass2_recompute"})
+    assert set(ops[False]) == set(ops[True]) == launches, ops
+    for name in launches:
+        assert ops[False][name] == ops[True][name] - 1, (name, ops)
 
 
 def test_mobilenet_v3_block_compiles(one_chip):

@@ -56,7 +56,7 @@ _PREAMBLE = textwrap.dedent("""
         c_mid = c_in * expand
         c_se = max(1, int(c_in * se_ratio))
         if expand == 1:
-            w_exp, exp_act = jnp.eye(c_mid, dtype=jnp.float32), None
+            w_exp, exp_act = None, None
         else:
             w_exp, exp_act = rand(rng, (c_in, c_mid)), "silu"
         return (w_exp, rand(rng, (k, k, c_mid), 0.3),
@@ -158,8 +158,8 @@ def test_sharded_mbconv_parity(mesh):
 
 
 def test_sharded_mbconv_expand_ratio_one():
-    """MBConv1 (identity expand) shards c_mid == c_in on "model": the
-    identity column slice selects each shard's input channels."""
+    """MBConv1 (no expand, ``w_exp=None``) shards c_mid == c_in on
+    "model": each shard takes its own slice of the input channels."""
     run_case("""
     mesh = parse_mesh("2x4")
     rng = np.random.default_rng(2)

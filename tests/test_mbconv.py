@@ -25,6 +25,7 @@ from repro.core.workloads import EFFICIENTNET_B0, EFFICIENTNET_B0_MBCONV
 from repro.kernels import convdk_mbconv_fused, convdk_mbconv_staged
 
 TOL = dict(rtol=1e-4, atol=1e-4)
+IDENTITY_EXPAND = "kernels.mbconv.identity_expand"
 
 
 def _rand(rng, shape, scale=1.0):
@@ -32,10 +33,11 @@ def _rand(rng, shape, scale=1.0):
 
 
 def _mbconv_params(rng, c_in, expand, c_out, k, se_ratio=0.25):
+    """Weights of one block; expansion ratio 1 has no expand weight."""
     c_mid = c_in * expand
     c_se = max(1, int(c_in * se_ratio))
     if expand == 1:
-        w_exp, exp_act = jnp.eye(c_mid, dtype=jnp.float32), None
+        w_exp, exp_act = None, None
     else:
         w_exp, exp_act = _rand(rng, (c_in, c_mid)), "silu"
     return (w_exp, _rand(rng, (k, k, c_mid), 0.3),
@@ -47,8 +49,8 @@ def _mbconv_params(rng, c_in, expand, c_out, k, se_ratio=0.25):
 def _oracle(x, w_exp, w_dw, w_se1, b_se1, w_se2, b_se2, w_proj, stride,
             exp_act="silu"):
     """Independent oracle: explicit lax convs + explicit SE (NOT the repo's
-    mbconv_ref)."""
-    e = x @ w_exp
+    mbconv_ref).  ``w_exp=None`` skips the expand, ``w_se1=None`` the SE."""
+    e = x if w_exp is None else x @ w_exp
     if exp_act == "silu":
         e = jax.nn.silu(e)
     k_h, k_w, c_mid = w_dw.shape
@@ -58,9 +60,11 @@ def _oracle(x, w_exp, w_dw, w_se1, b_se1, w_se2, b_se2, w_proj, stride,
         feature_group_count=c_mid,
         dimension_numbers=("NHWC", "OIHW", "NHWC"))
     d = jax.nn.silu(d)
-    gate = jax.nn.sigmoid(
-        jax.nn.silu(d.mean(axis=(1, 2)) @ w_se1 + b_se1) @ w_se2 + b_se2)
-    return (d * gate[:, None, None, :]) @ w_proj
+    if w_se1 is not None:
+        gate = jax.nn.sigmoid(
+            jax.nn.silu(d.mean(axis=(1, 2)) @ w_se1 + b_se1) @ w_se2 + b_se2)
+        d = d * gate[:, None, None, :]
+    return d @ w_proj
 
 
 # ---------------------------------------------------------------------------
@@ -82,19 +86,44 @@ def test_mbconv_fused_matches_lax_oracle(k, stride, mode):
     np.testing.assert_allclose(got, want, **TOL)
 
 
-def test_mbconv_expand_ratio_one():
-    """MBConv1 (no expansion conv): identity expand + exp_act=None is the
-    exact same math as running DW directly on the input."""
+@pytest.mark.parametrize("c", [16, 136], ids=["c16", "c136"])
+@pytest.mark.parametrize("se", [True, False], ids=["se", "nose"])
+@pytest.mark.parametrize("mode", MBCONV_MODES)
+@pytest.mark.parametrize("stride", [1, 2])
+def test_mbconv_expand_ratio_one(stride, mode, se, c):
+    """MBConv1 (no expansion conv): ``w_exp=None`` runs the depthwise taps
+    straight on the staged input window.  136 channels give two channel
+    blocks, so the window's block must follow the c_mid grid dim."""
     rng = np.random.default_rng(5)
-    ci = co = 16
-    x = _rand(rng, (1, 9, 9, ci))
-    weights, exp_act = _mbconv_params(rng, ci, 1, co, 3)
-    assert exp_act is None
-    for mode in MBCONV_MODES:
-        got = convdk_mbconv_fused(x, *weights, stride=1, mode=mode,
-                                  exp_act=None, interpret=True)
-        want = _oracle(x, *weights, 1, exp_act=None)
-        np.testing.assert_allclose(got, want, **TOL)
+    x = _rand(rng, (1, 9, 9, c))
+    weights, exp_act = _mbconv_params(rng, c, 1, c, 3)
+    assert weights[0] is None and exp_act is None
+    if not se:
+        weights = (*weights[:2], None, None, None, None, weights[-1])
+    got = convdk_mbconv_fused(x, *weights, stride=stride, mode=mode,
+                              tile_h=4, exp_act=None, interpret=True)
+    want = _oracle(x, *weights, stride, exp_act=None)
+    assert got.shape == want.shape
+    np.testing.assert_allclose(got, want, **TOL)
+
+
+def test_mbconv_square_w_exp_still_expands():
+    """A square ``w_exp`` a caller passes runs as a real expand (not the
+    no-expand path), and the identity there gives the no-expand result."""
+    from repro.core import telemetry
+
+    rng = np.random.default_rng(6)
+    c = 16
+    x = _rand(rng, (1, 9, 9, c))
+    weights, _ = _mbconv_params(rng, c, 1, c, 3)
+    square = (2.0 * jnp.eye(c, dtype=jnp.float32), *weights[1:])
+    t = telemetry.get_telemetry()
+    before = t.get(IDENTITY_EXPAND)
+    got = convdk_mbconv_fused(x, *square, stride=1, mode="recompute",
+                              tile_h=4, exp_act=None, interpret=True)
+    assert t.get(IDENTITY_EXPAND) == before
+    np.testing.assert_allclose(got, _oracle(x, *square, 1, exp_act=None),
+                               **TOL)
 
 
 def test_mbconv_retain_recompute_agree():
@@ -148,22 +177,29 @@ def test_mbconv_b0_layer_shapes_parity():
 
 
 def test_mbconv_grad_matches_reference():
+    """Gradients through the kernels' VJP match the reference's, with an
+    expand and without one (``w_exp=None``, whose cotangent is None)."""
     from repro.kernels import mbconv_ref
 
     rng = np.random.default_rng(3)
     x = _rand(rng, (1, 10, 9, 8))
-    weights, _ = _mbconv_params(rng, 8, 3, 12, 3)
 
     def loss(fn):
         return lambda *p: (fn(*p) ** 2).sum()
 
-    f = loss(lambda *p: convdk_mbconv_fused(*p, stride=2, mode="retain",
-                                            interpret=True))
-    r = loss(lambda *p: mbconv_ref(*p, stride=2))
-    g = jax.grad(f, argnums=tuple(range(8)))(x, *weights)
-    g_ref = jax.grad(r, argnums=tuple(range(8)))(x, *weights)
-    for got, want in zip(g, g_ref):
-        np.testing.assert_allclose(got, want, rtol=2e-3, atol=2e-3)
+    for expand, exp_act in ((3, "silu"), (1, None)):
+        weights, _ = _mbconv_params(rng, 8, expand, 12, 3)
+        f = loss(lambda *p: convdk_mbconv_fused(
+            *p, stride=2, mode="retain", exp_act=exp_act, interpret=True))
+        r = loss(lambda *p: mbconv_ref(*p, stride=2, exp_act=exp_act))
+        g = jax.grad(f, argnums=tuple(range(8)))(x, *weights)
+        g_ref = jax.grad(r, argnums=tuple(range(8)))(x, *weights)
+        assert (g[1] is None) == (expand == 1)
+        for got, want in zip(g, g_ref):
+            if want is None:
+                assert got is None
+                continue
+            np.testing.assert_allclose(got, want, rtol=2e-3, atol=2e-3)
 
 
 # ---------------------------------------------------------------------------
@@ -335,6 +371,66 @@ def test_efficientnet_b0_forward_backward():
     flat = jax.tree.leaves(grads)
     assert all(bool(jnp.isfinite(g).all()) for g in flat)
     assert any(float(jnp.abs(g).max()) > 0 for g in flat)
+
+
+def _trace_count(fn, *args):
+    """``kernels.mbconv.identity_expand`` ticks while ``fn`` is traced
+    (abstractly: no kernel runs).  Jit caches are cleared first so every
+    launch traces anew."""
+    from repro.core import telemetry
+
+    jax.clear_caches()
+    t = telemetry.get_telemetry()
+    before = t.get(IDENTITY_EXPAND)
+    jax.eval_shape(fn, *args)
+    return t.get(IDENTITY_EXPAND) - before
+
+
+@pytest.mark.parametrize("model,width,batch,hw,count", [
+    ("efficientnet_b0", 0.25, 1, 32, 2),
+    ("efficientnet_b0", 1.0, 256, 224, 2),
+    ("mobilenet_v3_large", 0.25, 1, 32, 1),
+    ("mobilenet_v3_large", 1.0, 256, 224, 1),
+    ("efficientnet_v2_s", 0.25, 1, 32, 0),
+])
+def test_identity_expand_counter_per_trace(model, width, batch, hw, count):
+    """One tick per launch with no expand stage: B0's block 0 runs pass 1
+    (SE pool) and pass 2 recompute, V3-L's block 0 (no SE) pass 2 only;
+    every V2-S MBConv block expands.  The full-size rows are the
+    benchmark's offline cells."""
+    from repro.models import mbconv as M
+    from repro.models.param import abstract
+
+    config, define, apply = {
+        "efficientnet_b0": (M.EffNetConfig, M.efficientnet_b0_def,
+                            M.efficientnet_b0_apply),
+        "mobilenet_v3_large": (M.MobileNetV3Config, M.mobilenet_v3_def,
+                               M.mobilenet_v3_apply),
+        "efficientnet_v2_s": (M.EffNetV2Config, M.efficientnet_v2_s_def,
+                              M.efficientnet_v2_s_apply),
+    }[model]
+    cfg = config(width_mult=width, num_classes=10)
+    images = jax.ShapeDtypeStruct((batch, hw, hw, 3), jnp.float32)
+    assert _trace_count(lambda p, x: apply(p, x, cfg),
+                        abstract(define(cfg)), images) == count
+
+
+def test_identity_expand_counter_silent_on_expanding_block():
+    """A block whose params carry ``"exp"`` takes the expand path; the same
+    block at expansion ratio 1 takes the no-expand path."""
+    from repro.configs.base import ConvKernelConfig
+    from repro.models.mbconv import mbconv_block, mbconv_def
+    from repro.models.param import abstract
+
+    kcfg = ConvKernelConfig(fused_mbconv=True, interpret=True)
+    x = jax.ShapeDtypeStruct((2, 14, 14, 16), jnp.float32)
+    for expand in (4, 1):
+        params = abstract(mbconv_def(16, 16, k=3, expand_ratio=expand))
+        assert ("exp" in params) == (expand > 1)
+        count = _trace_count(
+            lambda p, x: mbconv_block(x, p, stride=1, cfg=kcfg)[0],
+            params, x)
+        assert (count > 0) == (expand == 1), (expand, count)
 
 
 def test_vision_stem_arch_validated():
