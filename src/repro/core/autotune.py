@@ -1177,9 +1177,10 @@ def fusedmb_vmem_footprint_bytes(shape: MBConvShape, tile_h: int,
     every array at its tile padding: the input staging, the f32 dense-conv
     and projection scratch accumulators (both live the whole cell — the
     conv output feeds the projection without leaving VMEM), the tap
-    loop's live f32 values (tap slice, running partial and its update,
-    the projection partial) and the double-buffered weight and output
-    blocks."""
+    loop's live f32 values (tap slice, its product and the accumulator
+    update, the projection partial) and the double-buffered weight and
+    output blocks.  A block with no projection (expansion ratio 1) is
+    priced as if it had one: an over-count of its VMEM and bytes."""
     ci = pick_channel_block(shape.c_in, tpu.c_block)
     cm = pick_channel_block(shape.c_mid, tpu.c_block)
     co = _blocks(shape.c_out, tpu.c_block)

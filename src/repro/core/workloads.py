@@ -245,14 +245,14 @@ def effnet_v2_chain_rows(h: int = 112, w: int = 112,
     stem-output spatial dims ``h`` x ``w`` — a MIXED-FAMILY chain: the
     fused head's rows carry ``family="fusedmb"`` (always-replicated
     entries, zero pass-2 traffic), the tail ``family="mbconv"`` with SE.
-    The expansion-1 fused stage widens c_mid to c_out so the single-pass
-    projection stays well-formed (matching the model builder)."""
+    At expansion 1 a fused block's conv goes straight to c_out (it has no
+    projection), so its c_mid is c_out (matching the model builder)."""
     from .autotune import BlockRow
     rows, c_in, hh, ww = [], stem_c, h, w
     for family, expand, k, s, c_out, repeats in EFFICIENTNET_V2_S_STAGES:
         for i in range(repeats):
             si = s if i == 0 else 1
-            c_mid = max(c_in * expand, c_out) if family == "fusedmb" \
+            c_mid = c_out if (family, expand) == ("fusedmb", 1) \
                 else c_in * expand
             rows.append(BlockRow(
                 hh, ww, c_in, c_mid, c_out, k, si, family=family,

@@ -5,6 +5,12 @@ with ONE dense k x k convolution (``Fused-MBConv``):
 
     dense k x k / s conv (C_in -> C_mid) -> act -> project 1x1 (+ residual)
 
+At expansion ratio 1 the published block has no projection: the k x k
+conv goes straight to C_out and the block is act(conv) (+ residual).
+``w_proj=None`` launches that form: no c_mid grid dimension, no
+projection weight and no projection accumulator; a square ``w_proj`` a
+caller does pass still runs as a projection.
+
 There is no SE stage, and therefore no global pool coupling distant strips:
 the projection of a strip depends only on that strip's conv output.  That
 is exactly the locality the single-strip VMEM residency of
@@ -20,7 +26,8 @@ row_strip, c_mid_block, c_in_block)`` with c_in innermost (the dense-conv
 reduction) and c_mid next (the projection reduction).  The input stream
 stages through the shared strip engine (``kernels.staging``) under the
 schedule's **residency** axis — identical windows to an MBConv pass-1
-stream, re-read once per (c_out, c_mid) block pair.
+stream, re-read once per (c_out, c_mid) block pair.  Without a
+projection the grid is ``(batch, c_mid_block, row_strip, c_in_block)``.
 
 Because the whole block is one pass, its schedule has NO mode axis (there
 is no DW tensor to retain or recompute) and its **pass-2 figures are
@@ -45,6 +52,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from ..core import telemetry
 from ..core.perfmodel import (
     DEFAULT_COLLECTIVE,
     DEFAULT_RESIDENCY,
@@ -61,54 +69,68 @@ from .ref import _act_ref, fusedmb_ref
 from .staging import StripPlan, StripStream, strip_plan
 
 
-def _fusedmb_kernel(x_ref, wconv_ref, wproj_ref, o_ref, *scratch,
-                    plan: StripPlan, k_h, k_w, stride, tile_h, out_w,
-                    act: Optional[str]):
-    """One (batch, c_out-block, row-strip, c_mid-block, c_in-block) cell.
+def _fusedmb_kernel(x_ref, wconv_ref, *refs, plan: StripPlan, project: bool,
+                    k_h, k_w, stride, tile_h, out_w, act: Optional[str]):
+    """One (batch, c_out-block, row-strip[, c_mid-block], c_in-block) cell
+    (the c_mid dim only when ``project``).
 
     x_ref     : unstaged input (engine-staged per ``plan``)
     wconv_ref : (k_h, k_w, CI, CM)    dense conv block
-    wproj_ref : (CM, CO)              projection block
-    o_ref     : (1, tile_h, out_w, CO)
-    scratch   : conv accumulator (tile_h, out_w, CM) f32 carrying partial
-                dense-conv sums across the c_in grid dim, projection
-                accumulator (tile_h, out_w, CO) f32 carrying partial sums
-                across the c_mid grid dim, then the staging engine's refs.
+    then      : wproj_ref (CM, CO), the projection block, only when
+                ``project``; o_ref (1, tile_h, out_w, CO); the conv
+                accumulator (tile_h, out_w, CM) f32 carrying partial
+                dense-conv sums across the c_in grid dim; when
+                ``project``, the projection accumulator (tile_h, out_w,
+                CO) f32 carrying partial sums across the c_mid grid dim;
+                then the staging engine's refs.
+
+    Without ``project`` (expansion ratio 1) the conv's c_mid block IS the
+    output block: the cell writes act(conv).
     """
     s = stride
-    stage_refs, (conv_ref, proj_ref) = plan.take_scratch(scratch)
-    cm = pl.program_id(3)
-    ci = pl.program_id(4)
-    n_cm = pl.num_programs(3)
-    n_ci = pl.num_programs(4)
+    if project:
+        wproj_ref, o_ref, *scratch = refs
+    else:
+        wproj_ref, (o_ref, *scratch) = None, refs
+    stage_refs, (conv_ref, *proj_refs) = plan.take_scratch(tuple(scratch))
+    ci_dim = 4 if project else 3
+    ci = pl.program_id(ci_dim)
+    n_ci = pl.num_programs(ci_dim)
+    if project:
+        cm = pl.program_id(3)
+        n_cm = pl.num_programs(3)
     win = StripStream(plan, x_ref, stage_refs).get()
 
     # Dense-conv tap loop: each tap contracts the strided window slice
     # (tile_h, out_w, CI) with its (CI, CM) weight plane — the expand-PW
     # and DW of a classic MBConv, collapsed into one MXU contraction per
-    # tap.  Summed over taps here, over c_in blocks via conv_ref.
-    part = jnp.zeros((tile_h, out_w, wconv_ref.shape[-1]), jnp.float32)
+    # tap.  Each tap's product goes straight into conv_ref (summed over
+    # taps and c_in blocks), so one tap's slice is live at a time: summed
+    # as a value, all k*k strided slices stay live, each split into three
+    # bf16 parts under "highest" matmul precision (V2-S block 2 at tile_h
+    # 16 then needs 20.0 MiB of VMEM, against 13.0 MiB this way; compiled
+    # for a described v5e).
+    @pl.when(ci == 0)
+    def _init():
+        conv_ref[...] = jnp.zeros(conv_ref.shape, jnp.float32)
+
     for j in range(k_h):
         for i in range(k_w):
             xs = win.read(j, i, tile_h, out_w, s)
-            part = part + jax.lax.dot_general(
+            conv_ref[...] += jax.lax.dot_general(
                 xs.reshape(tile_h * out_w, xs.shape[-1]).astype(jnp.float32),
                 wconv_ref[j, i].astype(jnp.float32),
                 dimension_numbers=(((1,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32,
             ).reshape(tile_h, out_w, -1)
 
-    @pl.when(ci == 0)
-    def _init():
-        conv_ref[...] = part
-
-    @pl.when(ci > 0)
-    def _accumulate():
-        conv_ref[...] = conv_ref[...] + part
-
     @pl.when(ci == n_ci - 1)
-    def _project():
+    def _finish():
         e = _act_ref(conv_ref[...], act)
+        if not project:
+            o_ref[0] = e.astype(o_ref.dtype)
+            return
+        (proj_ref,) = proj_refs
         partial = jax.lax.dot_general(
             e.reshape(tile_h * out_w, e.shape[-1]),
             wproj_ref[:, :].astype(jnp.float32),
@@ -136,46 +158,59 @@ def fusedmb_pallas(x_pad, w_conv, w_proj, *, stride, out_w, tile_h, n_th,
 
     x_pad  : (B, H_tot, W_pad, CI_pad)
     w_conv : (k_h, k_w, CI_pad, CM_pad) HWIO
-    w_proj : (CM_pad, CO_pad)
+    w_proj : (CM_pad, CO_pad), or None: no projection (expansion ratio
+             1), the grid has no c_mid dim and the output is act(conv)
+             over CO_pad = CM_pad in blocks of ``cm_block``
     returns (B, n_th*tile_h, out_w, CO_pad)
     """
     b, h_tot, w_pad, ci_pad = x_pad.shape
     k_h, k_w, _, cm_pad = w_conv.shape
-    co_pad = w_proj.shape[1]
-    grid = (b, co_pad // co_block, n_th, cm_pad // cm_block,
-            ci_pad // ci_block)
+    project = w_proj is not None
+    if project:
+        co_pad = w_proj.shape[1]
+        grid = (b, co_pad // co_block, n_th, cm_pad // cm_block,
+                ci_pad // ci_block)
+    else:
+        co_pad, co_block = cm_pad, cm_block
+        grid = (b, cm_pad // cm_block, n_th, ci_pad // ci_block)
+    ci_dim = len(grid) - 1
 
     plan = strip_plan(
         h_tot=h_tot, w_tot=w_pad, c_block=ci_block, tile_h=tile_h,
-        grid=grid, window_dims=(0, 2, 4), stride=stride, k_h=k_h,
+        grid=grid, window_dims=(0, 2, ci_dim), stride=stride, k_h=k_h,
         residency=residency)
     kernel = functools.partial(
-        _fusedmb_kernel, plan=plan, k_h=k_h, k_w=k_w, stride=stride,
-        tile_h=tile_h, out_w=out_w, act=act)
+        _fusedmb_kernel, plan=plan, project=project, k_h=k_h, k_w=k_w,
+        stride=stride, tile_h=tile_h, out_w=out_w, act=act)
+    # the conv's c_mid block: the last-but-one dim with a projection, the
+    # output's channel block without one
+    cm_dim = 3 if project else 1
+    in_specs = [
+        plan.in_spec(lambda *g: (g[0], 0, 0, g[ci_dim])),
+        pl.BlockSpec((k_h, k_w, ci_block, cm_block),
+                     lambda *g: (0, 0, g[ci_dim], g[cm_dim])),
+    ]
+    operands = [x_pad, w_conv]
+    scratch = [pltpu.VMEM((tile_h, out_w, cm_block), jnp.float32)]
+    if project:
+        in_specs.append(pl.BlockSpec((cm_block, co_block),
+                                     lambda bi, co, ti, cm, ci: (cm, co)))
+        operands.append(w_proj)
+        scratch.append(pltpu.VMEM((tile_h, out_w, co_block), jnp.float32))
     return pl.pallas_call(
         kernel,
         grid=grid,
-        in_specs=[
-            plan.in_spec(lambda bi, co, ti, cm, ci: (bi, 0, 0, ci)),
-            pl.BlockSpec((k_h, k_w, ci_block, cm_block),
-                         lambda bi, co, ti, cm, ci: (0, 0, ci, cm)),
-            pl.BlockSpec((cm_block, co_block),
-                         lambda bi, co, ti, cm, ci: (cm, co)),
-        ],
+        in_specs=in_specs,
         out_specs=pl.BlockSpec(
             (1, tile_h, out_w, co_block),
-            lambda bi, co, ti, cm, ci: (bi, ti, 0, co)),
+            lambda bi, co, ti, *_: (bi, ti, 0, co)),
         out_shape=jax.ShapeDtypeStruct(
             (b, n_th * tile_h, out_w, co_pad), x_pad.dtype),
-        scratch_shapes=[
-            pltpu.VMEM((tile_h, out_w, cm_block), jnp.float32),
-            pltpu.VMEM((tile_h, out_w, co_block), jnp.float32),
-            *plan.scratch_shapes(x_pad.dtype),
-        ],
+        scratch_shapes=[*scratch, *plan.scratch_shapes(x_pad.dtype)],
         compiler_params=compiler_params(),
         interpret=interpret,
         name="fusedmb",
-    )(x_pad, w_conv, w_proj)
+    )(*operands)
 
 
 def _fusedmb_impl(x, w_conv, w_proj, stride, padding, tile_h, act, interpret,
@@ -192,13 +227,20 @@ def _fusedmb_impl(x, w_conv, w_proj, stride, padding, tile_h, act, interpret,
     ``collective`` exactly like MBConv's pass 2: ``psum`` replicates the
     output, ``psum_scatter`` leaves it c_out-sharded at half the wire
     words.  There is no SE stage, hence no squeeze collective at all.
+
+    ``w_proj is None`` (expansion ratio 1) is a block with no projection:
+    the output is act(conv) over c_out = c_mid, and under c_mid sharding
+    each device's output is its own channel slice, with no collective.
     """
     validate_collective(collective)
     b, h, w_in, c_in = x.shape
     k_h, k_w, ci_w, c_mid = w_conv.shape
     assert ci_w == c_in, (w_conv.shape, c_in)
-    c_out = w_proj.shape[1]
-    assert w_proj.shape[0] == c_mid, (w_proj.shape, c_mid)
+    if w_proj is None:
+        c_out = c_mid
+    else:
+        c_out = w_proj.shape[1]
+        assert w_proj.shape[0] == c_mid, (w_proj.shape, c_mid)
     s = stride
     geo = launch_geometry(h, w_in, k_h, k_w, s, padding, tile_h)
     out_h, out_w = geo.out_h, geo.out_w
@@ -213,12 +255,15 @@ def _fusedmb_impl(x, w_conv, w_proj, stride, padding, tile_h, act, interpret,
     xp = jnp.pad(x, (*geo.pads, (0, ci_pad - c_in)))
     wconv_p = jnp.pad(w_conv, ((0, 0), (0, 0), (0, ci_pad - c_in),
                                (0, cm_pad - c_mid)))
-    wproj_p = jnp.pad(w_proj, ((0, cm_pad - c_mid), (0, co_pad - c_out)))
+    wproj_p = None if w_proj is None else jnp.pad(
+        w_proj, ((0, cm_pad - c_mid), (0, co_pad - c_out)))
 
     out = fusedmb_pallas(
         xp, wconv_p, wproj_p, stride=s, out_w=geo.out_wk, tile_h=geo.tile_h,
         n_th=geo.n_th, ci_block=ci_block, cm_block=cm_block,
         co_block=co_block, act=act, interpret=interpret, residency=residency)
+    if w_proj is None:
+        return out[:, :out_h, :out_w, :c_out]
     if axis_name is not None and collective == "psum_scatter":
         # layout-aware exit, same contract as MBConv pass 2: zero w_proj
         # columns pad a non-dividing c_out to ``scatter_width`` (their
@@ -274,10 +319,16 @@ _fusedmb_op.defvjp(_fusedmb_fwd, _fusedmb_bwd)
     static_argnames=("stride", "padding", "tile_h", "act", "interpret",
                      "residency"),
 )
+def _fusedmb_fused(x, w_conv, w_proj, *, stride, padding, tile_h, act,
+                   interpret, residency):
+    return _fusedmb_op(x, w_conv, w_proj, stride, padding, tile_h, act,
+                       interpret, residency)
+
+
 def convdk_fusedmb_fused(
     x: jax.Array,
     w_conv: jax.Array,
-    w_proj: jax.Array,
+    w_proj: Optional[jax.Array],
     *,
     stride: int = 1,
     padding: str = "SAME",
@@ -292,19 +343,29 @@ def convdk_fusedmb_fused(
     x      : (B, H, W, C_in) NHWC
     w_conv : (k_h, k_w, C_in, C_mid) HWIO dense conv (the collapsed
              expand+DW of EfficientNet-V2's fused stages)
-    w_proj : (C_mid, C_out) projection PW (linear)
+    w_proj : (C_mid, C_out) projection PW (linear), or None: no
+             projection (expansion ratio 1), the block is act(conv) and
+             C_out = C_mid
     act    : conv activation (EfficientNet-V2 uses silu)
     residency : "resident" | "strip_dma" | "strip_dma_db" (default) — how
              the input stream is staged (``kernels.staging``).
     Returns (B, H', W', C_out).  The expanded (C_mid) tensor never touches
     HBM; there is no SE stage and no second pass.
+
+    A call with ``w_proj=None`` ticks ``kernels.fusedmb.no_project`` once:
+    once per launch site a jitted caller traces (two blocks of one shape
+    share a compiled kernel but are two launches), once per launch when
+    called eagerly.
     """
     if interpret is None:
         interpret = default_interpret()
     if residency is None:
         residency = DEFAULT_RESIDENCY
-    return _fusedmb_op(x, w_conv, w_proj, stride, padding, tile_h, act,
-                       interpret, residency)
+    if w_proj is None:
+        telemetry.counter("kernels.fusedmb.no_project")
+    return _fusedmb_fused(x, w_conv, w_proj, stride=stride, padding=padding,
+                          tile_h=tile_h, act=act, interpret=interpret,
+                          residency=residency)
 
 
 @functools.partial(
@@ -314,7 +375,7 @@ def convdk_fusedmb_fused(
 def convdk_fusedmb_staged(
     x: jax.Array,
     w_conv: jax.Array,
-    w_proj: jax.Array,
+    w_proj: Optional[jax.Array],
     *,
     stride: int = 1,
     padding: str = "SAME",
@@ -328,7 +389,8 @@ def convdk_fusedmb_staged(
     as the weight-stationary baseline, which is what
     ``convdk_fusedmb_fused`` eliminates.  ``tile_h`` is accepted for
     call-site symmetry with the fused entry; the staged rendering has no
-    strip structure.
+    strip structure.  ``w_proj=None``: no projection, the activation is
+    the output.
     """
     del tile_h
     if interpret is None:
@@ -339,6 +401,7 @@ def convdk_fusedmb_staged(
         padding=padding,
         dimension_numbers=("NHWC", "HWIO", "NHWC"),
     )
-    e = _act_ref(e, act)
-    out = jnp.einsum("bhwc,cd->bhwd", e, w_proj.astype(jnp.float32))
+    out = _act_ref(e, act)
+    if w_proj is not None:
+        out = jnp.einsum("bhwc,cd->bhwd", out, w_proj.astype(jnp.float32))
     return out.astype(x.dtype)

@@ -556,7 +556,11 @@ def _fusedmb_sharded_impl(x, w_conv, w_proj, mesh, stride, padding, tile_h,
         raise ValueError(
             f"fusedmb consumes replicated arrivals only, got {in_layout!r}")
     _dp, mp = conv_mesh_shape(mesh)
-    c_out = w_proj.shape[1]
+    # no projection (expansion ratio 1): each shard's conv channels are
+    # its output channels, which leave sharded on "model" with no
+    # collective
+    project = w_proj is not None
+    c_out = w_proj.shape[1] if project else w_conv.shape[-1]
     cw = scatter_c_out(c_out, mp) if collective == "psum_scatter" else c_out
     TRACE_COUNTS["fusedmb"] += 1
 
@@ -567,7 +571,8 @@ def _fusedmb_sharded_impl(x, w_conv, w_proj, mesh, stride, padding, tile_h,
 
     batch = _batch_axes(mesh)
     out_spec = P(batch, None, None,
-                 MODEL_AXIS if collective == "psum_scatter" else None)
+                 MODEL_AXIS if collective == "psum_scatter" or not project
+                 else None)
     out = shard_map_compat(
         local, mesh,
         in_specs=(P(batch, None, None, None),       # batch slice, full C_in
@@ -575,7 +580,7 @@ def _fusedmb_sharded_impl(x, w_conv, w_proj, mesh, stride, padding, tile_h,
                   P(MODEL_AXIS, None)),             # projection rows
         out_specs=out_spec,
     )(x, w_conv, w_proj)
-    if cw != c_out:
+    if project and cw != c_out:
         out = out[..., :c_out]
     return out
 
@@ -631,7 +636,7 @@ def _fusedmb_sharded_entry(mesh, stride, padding, tile_h, act, interpret,
 def convdk_fusedmb_fused_sharded(
     x: jax.Array,
     w_conv: jax.Array,
-    w_proj: jax.Array,
+    w_proj: Optional[jax.Array],
     *,
     mesh,
     stride: int = 1,
@@ -652,10 +657,12 @@ def convdk_fusedmb_fused_sharded(
     projection's c_mid reduction crosses devices per ``collective``
     (``psum`` replicated output, ``psum_scatter`` c_out-sharded exit at
     half the wire words; non-dividing c_out zero-pads and slices back,
-    exact).  There is NO SE stage, so the block's only collective is the
-    projection reduction — and no pass 2 at all: a pipelined consumer
-    cannot hide behind this block (``core.autotune`` prices that
-    honestly).
+    exact).  ``w_proj=None`` (expansion ratio 1, no projection) has no
+    reduction: each device's output is its c_mid slice, and the block
+    leaves sharded on "model" with no collective.  There is NO SE stage,
+    so the block's only collective is the projection reduction — and no
+    pass 2 at all: a pipelined consumer cannot hide behind this block
+    (``core.autotune`` prices that honestly).
 
     ``in_layout`` must be ``"replicated"``: a dense conv consumes every
     input channel, so there is no channel-local entry for a sharded
@@ -675,7 +682,11 @@ def convdk_fusedmb_fused_sharded(
         in_layout = DEFAULT_LAYOUT
     residual_barrier_needed()
     telemetry.counter("sharded.dispatch.fusedmb")
-    telemetry.counter(f"sharded.collective.{collective}")
+    if w_proj is None:
+        # no projection, no reduction: the collective is never issued
+        telemetry.counter("kernels.fusedmb.no_project")
+    else:
+        telemetry.counter(f"sharded.collective.{collective}")
     return _fusedmb_sharded_entry(mesh, stride, padding, tile_h, act,
                                   interpret, residency, collective,
                                   in_layout)(x, w_conv, w_proj)

@@ -137,7 +137,7 @@ def mbconv_ref(
 def fusedmb_ref(
     x: jax.Array,
     w_conv: jax.Array,
-    w_proj: jax.Array,
+    w_proj: Optional[jax.Array],
     stride: int = 1,
     padding: str = "SAME",
     act: Optional[str] = "silu",
@@ -149,8 +149,9 @@ def fusedmb_ref(
     The expand-PW and the depthwise conv of a classic MBConv collapse into
     ONE dense convolution; there is no SE stage (V2's fused stages run
     without it).  x: (B, H, W, C_in); w_conv: (k, k, C_in, C_mid) HWIO;
-    w_proj: (C_mid, C_out).  All contractions run in f32, matching the
-    single-pass fused kernel's accumulators.
+    w_proj: (C_mid, C_out), or None for the expansion-1 block, which has
+    no projection: act(conv) is the output.  All contractions run in f32,
+    matching the single-pass fused kernel's accumulators.
     """
     e = jax.lax.conv_general_dilated(
         x.astype(jnp.float32), w_conv.astype(jnp.float32),
@@ -158,12 +159,13 @@ def fusedmb_ref(
         padding=padding,
         dimension_numbers=("NHWC", "HWIO", "NHWC"),
     )
-    e = _act_ref(e, act)
-    out = jax.lax.dot_general(
-        e, w_proj.astype(jnp.float32),
-        dimension_numbers=(((3,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )
+    out = _act_ref(e, act)
+    if w_proj is not None:
+        out = jax.lax.dot_general(
+            out, w_proj.astype(jnp.float32),
+            dimension_numbers=(((3,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )
     return out.astype(x.dtype)
 
 

@@ -211,19 +211,26 @@ def mbconv_def(c_in: int, c_out: int, k: int = 3, expand_ratio: int = 6,
     return p
 
 
-def fusedmb_def(c_in: int, c_out: int, c_mid: int, k: int = 3) -> dict:
+def fusedmb_def(c_in: int, c_out: int, c_mid: int, k: int = 3,
+                expand_ratio: Optional[int] = None) -> dict:
     """Params of one Fused-MBConv block: the dense k x k conv that
-    collapses expand+DW (HWIO), plus the 1x1 projection."""
-    return {
-        "conv": P((k, k, c_in, c_mid), (None,) * 4),
-        "proj": P((c_mid, c_out), (None, None), scale=2.0),
-    }
+    collapses expand+DW (HWIO), plus the 1x1 projection — except at
+    expansion ratio 1, where the published block is the k x k conv
+    alone, straight to ``c_out`` (``c_mid`` must equal it): no ``proj``.
+    """
+    p: Dict[str, Any] = {"conv": P((k, k, c_in, c_mid), (None,) * 4)}
+    if expand_ratio == 1:
+        assert c_mid == c_out, (c_mid, c_out)
+    else:
+        p["proj"] = P((c_mid, c_out), (None, None), scale=2.0)
+    return p
 
 
 def block_def(sp: MBConvSpec) -> dict:
     """Family dispatch: the param tree of one spec'd block."""
     if sp.family == "fusedmb":
-        return fusedmb_def(sp.c_in, sp.c_out, sp.c_mid, k=sp.k)
+        return fusedmb_def(sp.c_in, sp.c_out, sp.c_mid, k=sp.k,
+                           expand_ratio=sp.expand_ratio)
     return mbconv_def(sp.c_in, sp.c_out, k=sp.k,
                       expand_ratio=sp.expand_ratio, se_ratio=sp.se_ratio,
                       c_mid=sp.c_mid_override)
@@ -444,8 +451,12 @@ def fusedmb_block(
     Under a mesh the expanded c_mid grid shards on "model" and the
     projection reduction crosses devices per the solved collective; a
     ``psum_scatter`` exit on a dividing c_out reports
-    ``out_layout="model_sharded"``.  The identity residual is added when
-    the shapes allow (s == 1, C_in == C_out).
+    ``out_layout="model_sharded"``, as does a sharded block with no
+    projection.  The identity residual is added when the shapes allow
+    (s == 1, C_in == C_out).
+
+    Params without ``"proj"`` are the expansion-1 block, which has no
+    projection: the kernels get ``w_proj=None`` and compute act(conv).
 
     x: (B, H, W, C_in) NHWC -> (B, H', W', C_out).
     """
@@ -465,10 +476,10 @@ def fusedmb_block(
             f"{in_layout!r}")
     eff = resolve_pin(cfg, pin, family="fusedmb")
     w_conv = params["conv"].astype(x.dtype)
-    w_proj = params["proj"].astype(x.dtype)
+    w_proj = params["proj"].astype(x.dtype) if "proj" in params else None
     c_in = x.shape[-1]
     c_mid = w_conv.shape[-1]
-    c_out = w_proj.shape[-1]
+    c_out = c_mid if w_proj is None else w_proj.shape[-1]
     k = w_conv.shape[0]
 
     sharded = (mesh is not None and eff.shard and eff.fused
@@ -500,8 +511,8 @@ def fusedmb_block(
             residency=residency, collective=collective,
             in_layout="replicated")
         out_layout = ("model_sharded"
-                      if (collective == "psum_scatter"
-                          and c_out % mesh_shape[1] == 0)
+                      if w_proj is None or (collective == "psum_scatter"
+                                            and c_out % mesh_shape[1] == 0)
                       else "replicated")
     elif eff.fused:
         out = convdk_fusedmb_fused(
@@ -801,16 +812,17 @@ class EffNetV2Config:
 def effnet_v2_block_specs(cfg: EffNetV2Config) -> List[MBConvSpec]:
     """The per-block spec table of one EfficientNet-V2 config — a
     mixed-family chain: ``fusedmb`` specs for the fused stages (silu
-    dense conv, never SE; the expansion-1 stage widens c_mid to c_out so
-    the single-pass kernel's projection stays well-formed), ``mbconv``
-    specs for the tail (silu, SE 0.25)."""
+    dense conv, never SE; at expansion 1 the conv goes straight to c_out
+    and the block has no projection, so c_mid = c_out), ``mbconv`` specs
+    for the tail (silu, SE 0.25)."""
     specs: List[MBConvSpec] = []
     c_in = round_filters(cfg.stem_c, cfg.width_mult)
     for family, expand, k, s, c_out, repeats in cfg.stages:
         c_out = round_filters(c_out, cfg.width_mult)
         for i in range(repeats):
-            c_mid = max(c_in * expand, c_out) if family == "fusedmb" \
-                else None
+            c_mid = None
+            if family == "fusedmb":
+                c_mid = c_out if expand == 1 else c_in * expand
             specs.append(MBConvSpec(
                 c_in=c_in, c_out=c_out, expand_ratio=expand, k=k,
                 s=s if i == 0 else 1,

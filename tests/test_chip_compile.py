@@ -197,6 +197,50 @@ def test_fusedmb_compiles(one_chip):
                    _spec(one_chip, sp.c_mid, sp.c_out))
 
 
+def _v2s_fused_launch(sharding, block, project=None):
+    """(kernel fn, abstract args) of V2-S block ``block`` at the offline
+    cell's batch 64 and its input side, under the solved schedule.
+    ``project`` overrides the spec: True passes a (square, for block 0)
+    ``w_proj``, False passes None."""
+    sp = effnet_v2_block_specs(EffNetV2Config())[block]
+    batch, hw = 64, 192
+    sch = get_fusedmb_schedule(batch, hw, hw, sp.c_in, sp.c_mid, sp.c_out,
+                               sp.k, sp.s)
+    fn = functools.partial(
+        convdk_fusedmb_fused, stride=sp.s, tile_h=sch.tile_h, act="silu",
+        interpret=False, residency=sch.residency)
+    if project is None:
+        project = sp.expand_ratio != 1
+    return fn, (_spec(sharding, batch, hw, hw, sp.c_in),
+                _spec(sharding, sp.k, sp.k, sp.c_in, sp.c_mid),
+                _spec(sharding, sp.c_mid, sp.c_out) if project else None)
+
+
+@pytest.mark.parametrize("block", [0, 2], ids=["block0_e1", "block2_s2"])
+def test_v2s_fused_blocks_compile_at_the_cell_size(one_chip, block):
+    """V2-S block 0 (expansion 1: 24 -> 24, no projection) and block 2
+    (the first stride 2: 24 -> 96 -> 48) at batch 64, 192 x 192, at the
+    cell's "highest" matmul precision, where a float32 contraction's
+    operands split into bfloat16 parts in VMEM (block 2's solved tile_h
+    16 once needed 20 MiB of the 16 MiB limit there)."""
+    fn, args = _v2s_fused_launch(one_chip, block)
+    assert (args[2] is None) == (block == 0)
+    with jax.default_matmul_precision("highest"):
+        _assert_kernel(fn, *args)
+
+
+def test_no_projection_launch_drops_the_projection_operand(one_chip):
+    """V2-S block 0 at the cell's size: with ``w_proj=None`` the launch
+    takes one operand fewer than with an explicit square ``w_proj``."""
+    ops = {}
+    for project in (True, False):
+        fn, args = _v2s_fused_launch(one_chip, 0, project)
+        ops[project] = _kernel_operands(
+            jax.jit(fn).lower(*args).compile().as_text())
+    assert set(ops[False]) == set(ops[True]) == {"fusedmb"}, ops
+    assert ops[False]["fusedmb"] == ops[True]["fusedmb"] - 1, ops
+
+
 def test_sharded_mbconv_compiles(mesh):
     """The sharded MBConv wrapper on a (2, 2) mesh of described chips:
     batch on "data", B0 block 3's 144 expanded channels on "model"."""
@@ -216,6 +260,27 @@ def test_sharded_mbconv_compiles(mesh):
         *_mbconv_args(rep, hw, c_in, c_mid, c_out, k)).compile().as_text()
     assert "tpu_custom_call" in hlo
     assert "all-reduce" in hlo or "reduce-scatter" in hlo
+
+
+def test_efficientnet_v2_s_apply_compiles_at_the_cell_size(one_chip):
+    """The whole served V2-S program as its benchmark cell runs it:
+    published widths, batch 64 at 384, "highest" matmul precision."""
+    from repro.configs.base import ConvKernelConfig
+    from repro.models.mbconv import (
+        efficientnet_v2_s_apply,
+        efficientnet_v2_s_def,
+    )
+    from repro.models.param import abstract
+
+    cfg = EffNetV2Config()
+    params = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        abstract(efficientnet_v2_s_def(cfg)))
+    kcfg = ConvKernelConfig(interpret=False)
+    with jax.default_matmul_precision("highest"):
+        _assert_kernel(
+            lambda p, x: efficientnet_v2_s_apply(p, x, cfg, kcfg),
+            params, _spec(one_chip, 64, 384, 384, 3))
 
 
 def test_efficientnet_b0_apply_compiles(one_chip):

@@ -73,10 +73,14 @@ def _mbconv_oracle(x, w_exp, w_dw, w_se1, b_se1, w_se2, b_se2, w_proj,
 
 def _fusedmb_oracle(x, w_conv, w_proj, stride, act="silu"):
     """Independent Fused-MBConv oracle: ONE dense lax conv, act,
-    projection einsum (NOT the repo's fusedmb_ref)."""
+    projection einsum (NOT the repo's fusedmb_ref).  ``w_proj=None`` is
+    the published expansion-1 block: the conv goes straight to the output
+    channels and act(conv) is the block."""
     e = jax.lax.conv_general_dilated(
         x, w_conv, window_strides=(stride, stride), padding="SAME",
         dimension_numbers=("NHWC", "HWIO", "NHWC"))
+    if w_proj is None:
+        return _act(e, act)
     return jnp.einsum("bhwc,cd->bhwd", _act(e, act), w_proj)
 
 
@@ -133,38 +137,167 @@ def test_mbconv_v3_flavor_matches_oracle(k, stride, mode):
 @pytest.mark.parametrize("stride", [1, 2])
 def test_fusedmb_matches_dense_conv_oracle(k, stride):
     """The single-pass Fused-MBConv kernel == dense conv -> act ->
-    projection, and the staged baseline computes the identical block."""
+    projection, and the staged baseline computes the identical block;
+    with ``w_proj=None`` (expansion 1) both == dense conv -> act."""
     rng = np.random.default_rng(k + stride)
     b, h, w_in, ci, cm, co = 2, 13, 11, 8, 24, 16
     x = _rand(rng, (b, h, w_in, ci))
     w_conv = _rand(rng, (k, k, ci, cm), 0.3)
-    w_proj = _rand(rng, (cm, co))
-    want = _fusedmb_oracle(x, w_conv, w_proj, stride)
-    for tile_h in (1, 4):
-        got = convdk_fusedmb_fused(x, w_conv, w_proj, stride=stride,
-                                   tile_h=tile_h, interpret=True)
-        assert got.shape == want.shape
-        np.testing.assert_allclose(got, want, **TOL)
-    staged = convdk_fusedmb_staged(x, w_conv, w_proj, stride=stride,
-                                   interpret=True)
-    np.testing.assert_allclose(staged, want, **TOL)
+    for w_proj in (_rand(rng, (cm, co)), None):
+        want = _fusedmb_oracle(x, w_conv, w_proj, stride)
+        assert want.shape[-1] == (cm if w_proj is None else co)
+        for tile_h in (1, 4):
+            got = convdk_fusedmb_fused(x, w_conv, w_proj, stride=stride,
+                                       tile_h=tile_h, interpret=True)
+            assert got.shape == want.shape
+            np.testing.assert_allclose(got, want, **TOL)
+        staged = convdk_fusedmb_staged(x, w_conv, w_proj, stride=stride,
+                                       interpret=True)
+        np.testing.assert_allclose(staged, want, **TOL)
 
 
 def test_fusedmb_grad_matches_oracle():
+    """Gradients with a projection and without one (``w_proj=None``: its
+    cotangent is None)."""
     rng = np.random.default_rng(17)
     x = _rand(rng, (1, 10, 9, 8))
     w_conv = _rand(rng, (3, 3, 8, 16), 0.3)
-    w_proj = _rand(rng, (16, 12))
 
     def loss(fn):
         return lambda *p: (fn(*p) ** 2).sum()
 
-    f = loss(lambda *p: convdk_fusedmb_fused(*p, stride=2, interpret=True))
-    r = loss(lambda *p: _fusedmb_oracle(*p, 2))
-    g = jax.grad(f, argnums=(0, 1, 2))(x, w_conv, w_proj)
-    g_ref = jax.grad(r, argnums=(0, 1, 2))(x, w_conv, w_proj)
-    for got, want in zip(g, g_ref):
-        np.testing.assert_allclose(got, want, rtol=2e-3, atol=2e-3)
+    for w_proj in (_rand(rng, (16, 12)), None):
+        f = loss(lambda x_, wc, wp: convdk_fusedmb_fused(
+            x_, wc, wp, stride=2, interpret=True))
+        r = loss(lambda x_, wc, wp: _fusedmb_oracle(x_, wc, wp, 2))
+        g = jax.grad(f, argnums=(0, 1, 2))(x, w_conv, w_proj)
+        g_ref = jax.grad(r, argnums=(0, 1, 2))(x, w_conv, w_proj)
+        assert (g[2] is None) == (w_proj is None)
+        for got, want in zip(jax.tree.leaves(g), jax.tree.leaves(g_ref)):
+            np.testing.assert_allclose(got, want, rtol=2e-3, atol=2e-3)
+
+
+NO_PROJECT = "kernels.fusedmb.no_project"
+
+
+def _no_project_ticks(fn, *args):
+    """``kernels.fusedmb.no_project`` ticks while ``fn`` is traced
+    (abstractly: no kernel runs), jit caches cleared first."""
+    from repro.core import telemetry
+
+    jax.clear_caches()
+    t = telemetry.get_telemetry()
+    before = t.get(NO_PROJECT)
+    jax.eval_shape(fn, *args)
+    return t.get(NO_PROJECT) - before
+
+
+def test_square_w_proj_still_projects():
+    """A square ``w_proj`` passed explicitly runs as a projection (and
+    does not tick the no-projection counter); only ``None`` drops it."""
+    rng = np.random.default_rng(23)
+    x = _rand(rng, (2, 12, 10, 16))
+    w_conv = _rand(rng, (3, 3, 16, 16), 0.3)
+    w_proj = _rand(rng, (16, 16))
+    proj = convdk_fusedmb_fused(x, w_conv, w_proj, tile_h=4, interpret=True)
+    bare = convdk_fusedmb_fused(x, w_conv, None, tile_h=4, interpret=True)
+    np.testing.assert_allclose(proj, _fusedmb_oracle(x, w_conv, w_proj, 1),
+                               **TOL)
+    np.testing.assert_allclose(bare, _fusedmb_oracle(x, w_conv, None, 1),
+                               **TOL)
+    assert float(jnp.abs(proj - bare).max()) > 1e-1
+    f = convdk_fusedmb_fused
+    assert _no_project_ticks(lambda *a: f(*a, interpret=True),
+                             x, w_conv, w_proj) == 0
+    assert _no_project_ticks(lambda x_, wc: f(x_, wc, None, interpret=True),
+                             x, w_conv) == 1
+
+
+# EfficientNet-V2-S, arXiv:2104.00298 Table 2: (family, expansion, k,
+# first stride, c_in, c_out, repeats) after the 3 x 3 / 2 stem to 24
+V2S_TABLE = [
+    ("fusedmb", 1, 3, 1, 24, 24, 2),
+    ("fusedmb", 4, 3, 2, 24, 48, 4),
+    ("fusedmb", 4, 3, 2, 48, 64, 4),
+    ("mbconv", 4, 3, 2, 64, 128, 6),
+    ("mbconv", 6, 3, 1, 128, 160, 9),
+    ("mbconv", 6, 3, 2, 160, 256, 15),
+]
+
+
+def test_v2s_spec_table_and_param_tree_are_published():
+    """Full-width V2-S: the spec table is the paper's row for row, the
+    expansion-1 blocks (0-1) have no ``proj`` (the conv goes straight to
+    24 channels), every other fused block has one, and the tree holds
+    21.3 M parameters (the paper's 22 M counts batch norm too)."""
+    from repro.models.mbconv import (
+        EffNetV2Config, effnet_v2_block_specs, efficientnet_v2_s_def)
+    from repro.models.param import abstract
+
+    cfg = EffNetV2Config()
+    want = []
+    for family, e, k, s, c_in, c_out, reps in V2S_TABLE:
+        for i in range(reps):
+            ci = c_in if i == 0 else c_out
+            cm = c_out if e == 1 else ci * e
+            want.append((family, ci, cm, c_out, k, s if i == 0 else 1,
+                         max(1, int(ci * 0.25)) if family == "mbconv" else 0))
+    specs = effnet_v2_block_specs(cfg)
+    assert [(sp.family, sp.c_in, sp.c_mid, sp.c_out, sp.k, sp.s, sp.c_se)
+            for sp in specs] == want
+    tree = abstract(efficientnet_v2_s_def(cfg))
+    assert sorted(tree["block0"]) == sorted(tree["block1"]) == ["conv"]
+    assert tree["block0"]["conv"].shape == (3, 3, 24, 24)
+    for i in range(2, 10):
+        assert sorted(tree[f"block{i}"]) == ["conv", "proj"], i
+    n = sum(int(np.prod(a.shape)) for a in jax.tree.leaves(tree))
+    assert n == 21_304_616
+
+
+@pytest.mark.parametrize("model,width,batch,hw,count", [
+    ("efficientnet_v2_s", 1.0, 64, 384, 2),
+    ("efficientnet_v2_s", 0.25, 1, 32, 2),
+    ("efficientnet_b0", 1.0, 256, 224, 0),
+    ("mobilenet_v3_large", 1.0, 256, 224, 0),
+])
+def test_no_project_counter_per_trace(model, width, batch, hw, count):
+    """One tick per launch with no projection: V2-S's blocks 0 and 1
+    (the full-size row is the benchmark's cell), none in the all-MBConv
+    families."""
+    from repro.models import mbconv as M
+    from repro.models.param import abstract
+
+    config, define, apply = {
+        "efficientnet_b0": (M.EffNetConfig, M.efficientnet_b0_def,
+                            M.efficientnet_b0_apply),
+        "mobilenet_v3_large": (M.MobileNetV3Config, M.mobilenet_v3_def,
+                               M.mobilenet_v3_apply),
+        "efficientnet_v2_s": (M.EffNetV2Config, M.efficientnet_v2_s_def,
+                              M.efficientnet_v2_s_apply),
+    }[model]
+    cfg = config(width_mult=width, num_classes=10)
+    images = jax.ShapeDtypeStruct((batch, hw, hw, 3), jnp.float32)
+    assert _no_project_ticks(lambda p, x: apply(p, x, cfg),
+                             abstract(define(cfg)), images) == count
+
+
+def test_no_project_counter_silent_on_expanding_fused_block():
+    """A fused block at expansion 4 carries ``proj`` and projects; the same
+    block at expansion 1 carries none and takes the no-projection path."""
+    from repro.configs.base import ConvKernelConfig
+    from repro.models.mbconv import fusedmb_block, fusedmb_def
+    from repro.models.param import abstract
+
+    kcfg = ConvKernelConfig(interpret=True)
+    x = jax.ShapeDtypeStruct((2, 14, 14, 16), jnp.float32)
+    for expand in (4, 1):
+        params = abstract(fusedmb_def(16, 16, 16 * expand, k=3,
+                                      expand_ratio=expand))
+        assert ("proj" in params) == (expand > 1)
+        count = _no_project_ticks(
+            lambda p, x: fusedmb_block(x, p, stride=1, cfg=kcfg)[0],
+            params, x)
+        assert count == (expand == 1), (expand, count)
 
 
 # ---------------------------------------------------------------------------
@@ -245,7 +378,7 @@ def _sequential_blocks(x, specs, params):
     for i, sp in enumerate(specs):
         p = params[f"block{i}"]
         if sp.family == "fusedmb":
-            y = fusedmb_ref(x, p["conv"], p["proj"], stride=sp.s,
+            y = fusedmb_ref(x, p["conv"], p.get("proj"), stride=sp.s,
                             act=sp.act)
         else:
             if "exp" in p:

@@ -18,15 +18,18 @@ SCOPE = re.compile(r"(stem|head|mbconv\d+|fusedmb\d+)")
 
 MODELS = {
     "efficientnet_b0": (M.EffNetConfig, M.efficientnet_b0_def,
-                        M.efficientnet_b0_apply, 16),
+                        M.efficientnet_b0_apply, ["mbconv"] * 16),
     "mobilenet_v3_large": (M.MobileNetV3Config, M.mobilenet_v3_def,
-                           M.mobilenet_v3_apply, 15),
+                           M.mobilenet_v3_apply, ["mbconv"] * 15),
+    "efficientnet_v2_s": (M.EffNetV2Config, M.efficientnet_v2_s_def,
+                          M.efficientnet_v2_s_apply,
+                          ["fusedmb"] * 10 + ["mbconv"] * 30),
 }
 
 
 @pytest.mark.parametrize("model", sorted(MODELS))
 def test_every_program_op_sits_under_a_scope(model):
-    config, define, apply, n_blocks = MODELS[model]
+    config, define, apply, families = MODELS[model]
     cfg = config(width_mult=0.25, num_classes=10)
     params = materialize(define(cfg), jax.random.key(0))
     x = jnp.zeros((1, 32, 32, 3), jnp.float32)
@@ -40,8 +43,8 @@ def test_every_program_op_sits_under_a_scope(model):
                      None)
         assert scope is not None, path
         seen.add(scope)
-    assert seen == {"stem", "head"} | {f"mbconv{i}"
-                                       for i in range(n_blocks)}
+    assert seen == {"stem", "head"} | {f"{family}{i}"
+                                       for i, family in enumerate(families)}
 
 
 def _f32(*shape):
