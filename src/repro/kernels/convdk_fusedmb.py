@@ -29,6 +29,16 @@ schedule's **residency** axis — identical windows to an MBConv pass-1
 stream, re-read once per (c_out, c_mid) block pair.  Without a
 projection the grid is ``(batch, c_mid_block, row_strip, c_in_block)``.
 
+A narrow input packs its taps into the contraction.  The channel block
+is 128 lanes, so a dense conv over c_in = 24 contracts 104 zero lanes per
+tap.  When c_in fits at least twice in the block, ``taps_per_pass``
+gives T = floor(128 / c_in): the kernel rolls T taps' window slices into
+disjoint lane ranges of one (rows, 128) patch (the staged window's lanes
+from c_in up are zero, so a roll by t * c_in and an add place tap t
+exactly) and contracts the patch once against a packed weight plane —
+ceil(k*k / T) MXU passes per (c_mid block, strip) instead of k*k.  T = 1
+(c_in over 64, or over several blocks) is the plain per-tap loop.
+
 Because the whole block is one pass, its schedule has NO mode axis (there
 is no DW tensor to retain or recompute) and its **pass-2 figures are
 exactly zero** by convention: ``core.perfmodel.fusedmb_pass_traffic``
@@ -56,6 +66,7 @@ from ..core import telemetry
 from ..core.perfmodel import (
     DEFAULT_COLLECTIVE,
     DEFAULT_RESIDENCY,
+    LANES,
     pick_channel_block,
     validate_collective,
 )
@@ -69,20 +80,45 @@ from .ref import _act_ref, fusedmb_ref
 from .staging import StripPlan, StripStream, strip_plan
 
 
+def taps_per_pass(c_in: int, n_taps: int) -> int:
+    """Conv taps one MXU contraction packs side by side in a 128-lane
+    channel block: floor(128 / c_in), at most all ``n_taps``; 1 when c_in
+    is over 64 (it fits the block once, or spans several blocks)."""
+    return max(1, min(n_taps, LANES // c_in))
+
+
+def pack_conv_taps(w_conv, ci_pad: int, cm_pad: int):
+    """(k_h, k_w, c_in, c_mid) HWIO -> (n_groups, ci_pad, cm_pad): the
+    weight planes of the ``taps_per_pass`` groups.  Row t * c_in + c of
+    group g holds channel c of tap g * T + t (taps in row-major order);
+    every other row is zero.  At T = 1 this is the taps' planes, padded."""
+    k_h, k_w, c_in, c_mid = w_conv.shape
+    n_taps = k_h * k_w
+    t = taps_per_pass(c_in, n_taps)
+    n_groups = -(-n_taps // t)
+    w = jnp.pad(w_conv.reshape(n_taps, c_in, c_mid),
+                ((0, n_groups * t - n_taps), (0, 0), (0, cm_pad - c_mid)))
+    w = w.reshape(n_groups, t * c_in, cm_pad)
+    return jnp.pad(w, ((0, 0), (0, ci_pad - t * c_in), (0, 0)))
+
+
 def _fusedmb_kernel(x_ref, wconv_ref, *refs, plan: StripPlan, project: bool,
-                    k_h, k_w, stride, tile_h, out_w, act: Optional[str]):
+                    k_h, k_w, c_in, stride, tile_h, out_w,
+                    act: Optional[str]):
     """One (batch, c_out-block, row-strip[, c_mid-block], c_in-block) cell
     (the c_mid dim only when ``project``).
 
     x_ref     : unstaged input (engine-staged per ``plan``)
-    wconv_ref : (k_h, k_w, CI, CM)    dense conv block
+    wconv_ref : (n_groups, CI, CM)    the dense conv's packed tap planes
+                (``pack_conv_taps``); ``c_in`` is the real input width
     then      : wproj_ref (CM, CO), the projection block, only when
                 ``project``; o_ref (1, tile_h, out_w, CO); the conv
                 accumulator (tile_h, out_w, CM) f32 carrying partial
                 dense-conv sums across the c_in grid dim; when
                 ``project``, the projection accumulator (tile_h, out_w,
                 CO) f32 carrying partial sums across the c_mid grid dim;
-                then the staging engine's refs.
+                when taps pack, the patches (n_groups, tile_h, out_w, CI)
+                f32; then the staging engine's refs.
 
     Without ``project`` (expansion ratio 1) the conv's c_mid block IS the
     output block: the cell writes act(conv).
@@ -92,7 +128,10 @@ def _fusedmb_kernel(x_ref, wconv_ref, *refs, plan: StripPlan, project: bool,
         wproj_ref, o_ref, *scratch = refs
     else:
         wproj_ref, (o_ref, *scratch) = None, refs
-    stage_refs, (conv_ref, *proj_refs) = plan.take_scratch(tuple(scratch))
+    stage_refs, (conv_ref, *rest) = plan.take_scratch(tuple(scratch))
+    per_pass = taps_per_pass(c_in, k_h * k_w)
+    proj_refs = rest[:1] if project else ()
+    patch_ref = rest[-1] if per_pass > 1 else None
     ci_dim = 4 if project else 3
     ci = pl.program_id(ci_dim)
     n_ci = pl.num_programs(ci_dim)
@@ -101,28 +140,64 @@ def _fusedmb_kernel(x_ref, wconv_ref, *refs, plan: StripPlan, project: bool,
         n_cm = pl.num_programs(3)
     win = StripStream(plan, x_ref, stage_refs).get()
 
-    # Dense-conv tap loop: each tap contracts the strided window slice
-    # (tile_h, out_w, CI) with its (CI, CM) weight plane — the expand-PW
-    # and DW of a classic MBConv, collapsed into one MXU contraction per
-    # tap.  Each tap's product goes straight into conv_ref (summed over
-    # taps and c_in blocks), so one tap's slice is live at a time: summed
-    # as a value, all k*k strided slices stay live, each split into three
-    # bf16 parts under "highest" matmul precision (V2-S block 2 at tile_h
-    # 16 then needs 20.0 MiB of VMEM, against 13.0 MiB this way; compiled
-    # for a described v5e).
+    # Dense-conv tap loop: each group of ``taps_per_pass`` taps is one
+    # (tile_h * out_w, CI) x (CI, CM) MXU contraction against its packed
+    # weight plane — the expand-PW and DW of a classic MBConv in
+    # ceil(k*k / T) passes.  patch_ref[g] holds group g's patch: its
+    # first tap's strided window slice plus each tap t's rolled t * c_in
+    # lanes up (lanes from c_in up are the window's zero padding).  A
+    # strip's patches serve all its c_mid blocks, which the grid visits
+    # in order inside the strip, so the first packs them, about 256 patch
+    # rows a loop step.  Rolled whole, each tap's slice keeps VMEM of its
+    # own (V2-S block 0 at tile_h 16 then needs 18.0 MiB, against 13.6 MiB
+    # this way).  Each product goes straight into conv_ref: summed as
+    # values, all k*k slices stay live, each split into three bf16 parts
+    # under "highest" matmul precision (V2-S block 2 at tile_h 16 then
+    # needs 20.0 MiB, against 13.6 MiB).  All compiled for a described
+    # v5e.
     @pl.when(ci == 0)
     def _init():
         conv_ref[...] = jnp.zeros(conv_ref.shape, jnp.float32)
 
-    for j in range(k_h):
-        for i in range(k_w):
-            xs = win.read(j, i, tile_h, out_w, s)
-            conv_ref[...] += jax.lax.dot_general(
-                xs.reshape(tile_h * out_w, xs.shape[-1]).astype(jnp.float32),
-                wconv_ref[j, i].astype(jnp.float32),
-                dimension_numbers=(((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32,
-            ).reshape(tile_h, out_w, -1)
+    step_rows = max(d for d in range(1, tile_h + 1)
+                    if tile_h % d == 0 and d * out_w <= max(256, out_w))
+
+    def pack_rows(g, group):
+        def rows(r, carry):
+            r0 = r * step_rows
+            patch = None
+            for t, (j, i) in enumerate(group):
+                xs = win.read(j + r0 * s, i, step_rows, out_w, s).astype(
+                    jnp.float32).reshape(step_rows * out_w, -1)
+                patch = xs if t == 0 else patch + pltpu.roll(xs, t * c_in, 1)
+            patch_ref[g, pl.ds(r0, step_rows)] = patch.reshape(
+                step_rows, out_w, -1)
+            return carry
+        jax.lax.fori_loop(0, tile_h // step_rows, rows, 0)
+
+    taps = [(j, i) for j in range(k_h) for i in range(k_w)]
+    groups = [taps[g * per_pass:(g + 1) * per_pass]
+              for g in range(wconv_ref.shape[0])]
+    if per_pass > 1:
+        def pack():
+            for g, group in enumerate(groups):
+                pack_rows(g, group)
+        if project:
+            pl.when(cm == 0)(pack)
+        else:
+            pack()
+    for g, group in enumerate(groups):
+        if per_pass > 1:
+            patch = patch_ref[g]
+        else:
+            (j, i), = group
+            patch = win.read(j, i, tile_h, out_w, s).astype(jnp.float32)
+        conv_ref[...] += jax.lax.dot_general(
+            patch.reshape(tile_h * out_w, patch.shape[-1]),
+            wconv_ref[g].astype(jnp.float32),
+            dimension_numbers=(((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        ).reshape(tile_h, out_w, -1)
 
     @pl.when(ci == n_ci - 1)
     def _finish():
@@ -151,20 +226,22 @@ def _fusedmb_kernel(x_ref, wconv_ref, *refs, plan: StripPlan, project: bool,
             o_ref[0] = proj_ref[...].astype(o_ref.dtype)
 
 
-def fusedmb_pallas(x_pad, w_conv, w_proj, *, stride, out_w, tile_h, n_th,
-                   ci_block, cm_block, co_block, act, interpret,
+def fusedmb_pallas(x_pad, w_conv, w_proj, *, k, c_in, stride, out_w, tile_h,
+                   n_th, ci_block, cm_block, co_block, act, interpret,
                    residency=DEFAULT_RESIDENCY):
     """Raw single-pass launch over a pre-padded input.
 
-    x_pad  : (B, H_tot, W_pad, CI_pad)
-    w_conv : (k_h, k_w, CI_pad, CM_pad) HWIO
+    x_pad  : (B, H_tot, W_pad, CI_pad), zero from lane ``c_in`` up
+    w_conv : (n_groups, CI_pad, CM_pad), the ``k`` = (k_h, k_w) conv's
+             tap planes packed by ``pack_conv_taps``
     w_proj : (CM_pad, CO_pad), or None: no projection (expansion ratio
              1), the grid has no c_mid dim and the output is act(conv)
              over CO_pad = CM_pad in blocks of ``cm_block``
     returns (B, n_th*tile_h, out_w, CO_pad)
     """
     b, h_tot, w_pad, ci_pad = x_pad.shape
-    k_h, k_w, _, cm_pad = w_conv.shape
+    k_h, k_w = k
+    n_groups, _, cm_pad = w_conv.shape
     project = w_proj is not None
     if project:
         co_pad = w_proj.shape[1]
@@ -181,14 +258,14 @@ def fusedmb_pallas(x_pad, w_conv, w_proj, *, stride, out_w, tile_h, n_th,
         residency=residency)
     kernel = functools.partial(
         _fusedmb_kernel, plan=plan, project=project, k_h=k_h, k_w=k_w,
-        stride=stride, tile_h=tile_h, out_w=out_w, act=act)
+        c_in=c_in, stride=stride, tile_h=tile_h, out_w=out_w, act=act)
     # the conv's c_mid block: the last-but-one dim with a projection, the
     # output's channel block without one
     cm_dim = 3 if project else 1
     in_specs = [
         plan.in_spec(lambda *g: (g[0], 0, 0, g[ci_dim])),
-        pl.BlockSpec((k_h, k_w, ci_block, cm_block),
-                     lambda *g: (0, 0, g[ci_dim], g[cm_dim])),
+        pl.BlockSpec((n_groups, ci_block, cm_block),
+                     lambda *g: (0, g[ci_dim], g[cm_dim])),
     ]
     operands = [x_pad, w_conv]
     scratch = [pltpu.VMEM((tile_h, out_w, cm_block), jnp.float32)]
@@ -197,6 +274,9 @@ def fusedmb_pallas(x_pad, w_conv, w_proj, *, stride, out_w, tile_h, n_th,
                                      lambda bi, co, ti, cm, ci: (cm, co)))
         operands.append(w_proj)
         scratch.append(pltpu.VMEM((tile_h, out_w, co_block), jnp.float32))
+    if taps_per_pass(c_in, k_h * k_w) > 1:
+        scratch.append(pltpu.VMEM((n_groups, tile_h, out_w, ci_block),
+                                  jnp.float32))
     return pl.pallas_call(
         kernel,
         grid=grid,
@@ -253,15 +333,15 @@ def _fusedmb_impl(x, w_conv, w_proj, stride, padding, tile_h, act, interpret,
     co_pad = _round_up(c_out, co_block)
 
     xp = jnp.pad(x, (*geo.pads, (0, ci_pad - c_in)))
-    wconv_p = jnp.pad(w_conv, ((0, 0), (0, 0), (0, ci_pad - c_in),
-                               (0, cm_pad - c_mid)))
+    wconv_p = pack_conv_taps(w_conv, ci_pad, cm_pad)
     wproj_p = None if w_proj is None else jnp.pad(
         w_proj, ((0, cm_pad - c_mid), (0, co_pad - c_out)))
 
     out = fusedmb_pallas(
-        xp, wconv_p, wproj_p, stride=s, out_w=geo.out_wk, tile_h=geo.tile_h,
-        n_th=geo.n_th, ci_block=ci_block, cm_block=cm_block,
-        co_block=co_block, act=act, interpret=interpret, residency=residency)
+        xp, wconv_p, wproj_p, k=(k_h, k_w), c_in=c_in, stride=s,
+        out_w=geo.out_wk, tile_h=geo.tile_h, n_th=geo.n_th,
+        ci_block=ci_block, cm_block=cm_block, co_block=co_block, act=act,
+        interpret=interpret, residency=residency)
     if w_proj is None:
         return out[:, :out_h, :out_w, :c_out]
     if axis_name is not None and collective == "psum_scatter":
@@ -355,7 +435,8 @@ def convdk_fusedmb_fused(
     A call with ``w_proj=None`` ticks ``kernels.fusedmb.no_project`` once:
     once per launch site a jitted caller traces (two blocks of one shape
     share a compiled kernel but are two launches), once per launch when
-    called eagerly.
+    called eagerly.  A call whose kernel packs taps (``taps_per_pass`` of
+    C_in over 1) ticks ``kernels.fusedmb.tap_packed`` the same way.
     """
     if interpret is None:
         interpret = default_interpret()
@@ -363,6 +444,8 @@ def convdk_fusedmb_fused(
         residency = DEFAULT_RESIDENCY
     if w_proj is None:
         telemetry.counter("kernels.fusedmb.no_project")
+    if taps_per_pass(x.shape[-1], w_conv.shape[0] * w_conv.shape[1]) > 1:
+        telemetry.counter("kernels.fusedmb.tap_packed")
     return _fusedmb_fused(x, w_conv, w_proj, stride=stride, padding=padding,
                           tile_h=tile_h, act=act, interpret=interpret,
                           residency=residency)

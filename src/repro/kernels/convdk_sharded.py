@@ -71,7 +71,7 @@ from ..core.perfmodel import (
 )
 from .common import default_interpret
 from .convdk_fused import _fused_impl
-from .convdk_fusedmb import _fusedmb_impl
+from .convdk_fusedmb import _fusedmb_impl, taps_per_pass
 from .convdk_mbconv import _mbconv_impl
 from .ref import _act_ref, fusedmb_ref, mbconv_ref, separable_ref
 
@@ -687,6 +687,8 @@ def convdk_fusedmb_fused_sharded(
         telemetry.counter("kernels.fusedmb.no_project")
     else:
         telemetry.counter(f"sharded.collective.{collective}")
+    if taps_per_pass(x.shape[-1], w_conv.shape[0] * w_conv.shape[1]) > 1:
+        telemetry.counter("kernels.fusedmb.tap_packed")
     return _fusedmb_sharded_entry(mesh, stride, padding, tile_h, act,
                                   interpret, residency, collective,
                                   in_layout)(x, w_conv, w_proj)

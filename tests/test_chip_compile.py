@@ -199,11 +199,12 @@ def test_fusedmb_compiles(one_chip):
 
 def _v2s_fused_launch(sharding, block, project=None):
     """(kernel fn, abstract args) of V2-S block ``block`` at the offline
-    cell's batch 64 and its input side, under the solved schedule.
+    cell's batch 64 and its own input side (the stem halves 384 to 192,
+    blocks 2 and 6 halve it again), under the solved schedule.
     ``project`` overrides the spec: True passes a (square, for block 0)
     ``w_proj``, False passes None."""
     sp = effnet_v2_block_specs(EffNetV2Config())[block]
-    batch, hw = 64, 192
+    batch, hw = 64, 192 if block <= 2 else 96 if block <= 6 else 48
     sch = get_fusedmb_schedule(batch, hw, hw, sp.c_in, sp.c_mid, sp.c_out,
                                sp.k, sp.s)
     fn = functools.partial(
@@ -216,13 +217,17 @@ def _v2s_fused_launch(sharding, block, project=None):
                 _spec(sharding, sp.c_mid, sp.c_out) if project else None)
 
 
-@pytest.mark.parametrize("block", [0, 2], ids=["block0_e1", "block2_s2"])
+@pytest.mark.parametrize("block", [0, 2, 3, 6, 7], ids=[
+    "block0_e1", "block2_s2", "block3_c48", "block6_s2_c48", "block7_c64"])
 def test_v2s_fused_blocks_compile_at_the_cell_size(one_chip, block):
-    """V2-S block 0 (expansion 1: 24 -> 24, no projection) and block 2
-    (the first stride 2: 24 -> 96 -> 48) at batch 64, 192 x 192, at the
+    """V2-S fused blocks at batch 64 and their own input sides, at the
     cell's "highest" matmul precision, where a float32 contraction's
     operands split into bfloat16 parts in VMEM (block 2's solved tile_h
-    16 once needed 20 MiB of the 16 MiB limit there)."""
+    16 once needed 20 MiB of the 16 MiB limit there): block 0 (expansion
+    1: 24 -> 24, no projection, 192 x 192), block 2 (the first stride 2:
+    24 -> 96 -> 48), block 3 (48 -> 192 -> 48 at 96 x 96), block 6 (48 ->
+    192 -> 64, stride 2) and block 7 (64 -> 256 -> 64 at 48 x 48).  Every
+    one packs taps: 5 per contraction at c_in 24, 2 at 48 and 64."""
     fn, args = _v2s_fused_launch(one_chip, block)
     assert (args[2] is None) == (block == 0)
     with jax.default_matmul_precision("highest"):

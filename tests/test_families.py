@@ -156,6 +156,31 @@ def test_fusedmb_matches_dense_conv_oracle(k, stride):
         np.testing.assert_allclose(staged, want, **TOL)
 
 
+@pytest.mark.parametrize("project", [True, False], ids=["proj", "no_proj"])
+@pytest.mark.parametrize("stride", [1, 2])
+@pytest.mark.parametrize("c_in,per_pass", [
+    (24, 5), (48, 2), (64, 2),     # V2-S's fused widths: taps pack
+    (72, 1), (136, 1),             # one tap per pass: over 64, two blocks
+])
+def test_fusedmb_tap_packing_matches_oracle(c_in, per_pass, stride, project):
+    """Tap packing: floor(128 / c_in) taps share one 128-lane contraction
+    (c_in 24 -> 5, 48 and 64 -> 2) and the kernel still equals the dense
+    conv oracle; c_in 72 (one tap fills the block) and 136 (two channel
+    blocks) keep one tap per contraction and match too."""
+    from repro.kernels.convdk_fusedmb import taps_per_pass
+
+    assert taps_per_pass(c_in, 9) == per_pass
+    rng = np.random.default_rng(c_in + stride)
+    cm, co = 136, 24              # two c_mid blocks share a packing
+    x = _rand(rng, (1, 9, 10, c_in))
+    w_conv = _rand(rng, (3, 3, c_in, cm), 1 / np.sqrt(9 * c_in))
+    w_proj = _rand(rng, (cm, co)) if project else None
+    got = convdk_fusedmb_fused(x, w_conv, w_proj, stride=stride, tile_h=4,
+                               interpret=True)
+    np.testing.assert_allclose(
+        got, _fusedmb_oracle(x, w_conv, w_proj, stride), **TOL)
+
+
 def test_fusedmb_grad_matches_oracle():
     """Gradients with a projection and without one (``w_proj=None``: its
     cotangent is None)."""
@@ -178,18 +203,19 @@ def test_fusedmb_grad_matches_oracle():
 
 
 NO_PROJECT = "kernels.fusedmb.no_project"
+TAP_PACKED = "kernels.fusedmb.tap_packed"
 
 
-def _no_project_ticks(fn, *args):
-    """``kernels.fusedmb.no_project`` ticks while ``fn`` is traced
-    (abstractly: no kernel runs), jit caches cleared first."""
+def _ticks(name, fn, *args):
+    """Ticks of counter ``name`` while ``fn`` is traced (abstractly: no
+    kernel runs), jit caches cleared first."""
     from repro.core import telemetry
 
     jax.clear_caches()
     t = telemetry.get_telemetry()
-    before = t.get(NO_PROJECT)
+    before = t.get(name)
     jax.eval_shape(fn, *args)
-    return t.get(NO_PROJECT) - before
+    return t.get(name) - before
 
 
 def test_square_w_proj_still_projects():
@@ -207,10 +233,11 @@ def test_square_w_proj_still_projects():
                                **TOL)
     assert float(jnp.abs(proj - bare).max()) > 1e-1
     f = convdk_fusedmb_fused
-    assert _no_project_ticks(lambda *a: f(*a, interpret=True),
-                             x, w_conv, w_proj) == 0
-    assert _no_project_ticks(lambda x_, wc: f(x_, wc, None, interpret=True),
-                             x, w_conv) == 1
+    assert _ticks(NO_PROJECT, lambda *a: f(*a, interpret=True),
+                  x, w_conv, w_proj) == 0
+    assert _ticks(NO_PROJECT,
+                  lambda x_, wc: f(x_, wc, None, interpret=True),
+                  x, w_conv) == 1
 
 
 # EfficientNet-V2-S, arXiv:2104.00298 Table 2: (family, expansion, k,
@@ -254,16 +281,9 @@ def test_v2s_spec_table_and_param_tree_are_published():
     assert n == 21_304_616
 
 
-@pytest.mark.parametrize("model,width,batch,hw,count", [
-    ("efficientnet_v2_s", 1.0, 64, 384, 2),
-    ("efficientnet_v2_s", 0.25, 1, 32, 2),
-    ("efficientnet_b0", 1.0, 256, 224, 0),
-    ("mobilenet_v3_large", 1.0, 256, 224, 0),
-])
-def test_no_project_counter_per_trace(model, width, batch, hw, count):
-    """One tick per launch with no projection: V2-S's blocks 0 and 1
-    (the full-size row is the benchmark's cell), none in the all-MBConv
-    families."""
+def _model_ticks(name, model, width, batch, hw):
+    """Ticks of counter ``name`` while ``model``'s served apply traces at
+    ``width`` on a (batch, hw, hw, 3) batch of images."""
     from repro.models import mbconv as M
     from repro.models.param import abstract
 
@@ -277,8 +297,41 @@ def test_no_project_counter_per_trace(model, width, batch, hw, count):
     }[model]
     cfg = config(width_mult=width, num_classes=10)
     images = jax.ShapeDtypeStruct((batch, hw, hw, 3), jnp.float32)
-    assert _no_project_ticks(lambda p, x: apply(p, x, cfg),
-                             abstract(define(cfg)), images) == count
+    return _ticks(name, lambda p, x: apply(p, x, cfg),
+                  abstract(define(cfg)), images)
+
+
+@pytest.mark.parametrize("model,width,batch,hw,count", [
+    ("efficientnet_v2_s", 1.0, 64, 384, 2),
+    ("efficientnet_v2_s", 0.25, 1, 32, 2),
+    ("efficientnet_b0", 1.0, 256, 224, 0),
+    ("mobilenet_v3_large", 1.0, 256, 224, 0),
+])
+def test_no_project_counter_per_trace(model, width, batch, hw, count):
+    """One tick per launch with no projection: V2-S's blocks 0 and 1
+    (the full-size row is the benchmark's cell), none in the all-MBConv
+    families."""
+    assert _model_ticks(NO_PROJECT, model, width, batch, hw) == count
+
+
+@pytest.mark.parametrize("model,width,batch,hw,count", [
+    ("efficientnet_v2_s", 1.0, 64, 384, 10),
+    ("efficientnet_v2_s", 0.25, 1, 32, 10),
+    ("efficientnet_b0", 1.0, 256, 224, 0),
+    ("mobilenet_v3_large", 1.0, 256, 224, 0),
+])
+def test_tap_packed_counter_per_trace(model, width, batch, hw, count):
+    """One tick per launch that packs taps: all ten V2-S fused blocks, at
+    full width (c_in 24, 48, 64; the benchmark's cell) and at width 0.25
+    (c_in 8 and 16), none in the all-MBConv families."""
+    from repro.models.mbconv import EffNetV2Config, effnet_v2_block_specs
+
+    if model == "efficientnet_v2_s":
+        specs = effnet_v2_block_specs(
+            EffNetV2Config(width_mult=width, num_classes=10))
+        assert count == sum(sp.family == "fusedmb" and sp.c_in <= 64
+                            for sp in specs)
+    assert _model_ticks(TAP_PACKED, model, width, batch, hw) == count
 
 
 def test_no_project_counter_silent_on_expanding_fused_block():
@@ -294,7 +347,8 @@ def test_no_project_counter_silent_on_expanding_fused_block():
         params = abstract(fusedmb_def(16, 16, 16 * expand, k=3,
                                       expand_ratio=expand))
         assert ("proj" in params) == (expand > 1)
-        count = _no_project_ticks(
+        count = _ticks(
+            NO_PROJECT,
             lambda p, x: fusedmb_block(x, p, stride=1, cfg=kcfg)[0],
             params, x)
         assert count == (expand == 1), (expand, count)
