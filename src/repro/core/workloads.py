@@ -207,7 +207,7 @@ def mobilenet_v3_chain_rows(variant: str = "large", se_ratio: float = 0.25
                             ) -> tuple:
     """Family-generic ``core.autotune.BlockRow`` chain of MobileNet-V3
     for the network-level layout solver — the analogue of
-    ``models.mbconv.effnet_chain_rows`` built from the canonical workload
+    ``models.mbconv.block_chain_rows`` built from the canonical workload
     tables: each row carries its DW stage (expanded width, hw, k, s)
     plus the per-row projection width, SE flag and act from the META
     tables above."""

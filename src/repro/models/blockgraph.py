@@ -1,8 +1,8 @@
-"""Dataflow-graph form of an MBConv block chain.
+"""Dataflow-graph form of a network's block chain.
 
-``efficientnet_b0_apply`` used to call its 16 blocks in a bare Python
-loop, which leaves the chain's buffer structure implicit: each two-pass
-fused block (``kernels.convdk_mbconv_fused``) writes a set of
+A bare Python loop over a network's blocks would leave the chain's
+buffer structure implicit: each two-pass fused block
+(``kernels.convdk_mbconv_fused``) writes a set of
 intermediate buffers in pass 1 (the retained DW tensor, the SE pool and
 gate scale) that only pass 2 of the SAME block reads — so pass 2 of
 block *i* and pass 1 of block *i+1* touch disjoint buffers except for
@@ -20,7 +20,7 @@ producer's pass 2 into the consumer's pass 1 is the boundary activation
 ``kernels/staging.py`` double-buffering), with no write-after-write or
 write-after-read conflicts on the side buffers.  ``lower(x)`` then
 executes the chain in node order, calling each node's closure exactly as
-the old loop did — forward and grad stay bit-exact because each closure
+the loop would — forward and grad stay bit-exact because each closure
 wraps the whole-block ``custom_vjp`` kernel unchanged.
 
 Buffer naming convention (canonical, used by the builders and tests):
@@ -244,7 +244,7 @@ class BlockGraph:
             if node.apply is None:
                 raise GraphValidationError(
                     f"{node.name}: no apply closure bound; build the "
-                    "graph through build_mbconv_graph to lower it")
+                    "graph through build_block_graph to lower it")
             with jax.named_scope(node.name):
                 x = node.apply(x)
         return x
@@ -252,14 +252,14 @@ class BlockGraph:
 
 def build_block_graph(specs, params, *, kcfg=None, mesh=None,
                       plan=None) -> BlockGraph:
-    """The ``BlockGraph`` of a block chain (stem and head stay in the
-    caller).  Family-generic: each spec's ``family`` picks the node form
-    — two-pass ``mbconv`` nodes (per-pass buffer sets reflecting the
+    """The ``BlockGraph`` of a block chain (stem and head stay in
+    ``mbconv.network_apply``).  Each spec's ``family`` picks the node
+    form — two-pass ``mbconv`` nodes (per-pass buffer sets reflecting the
     solved mode and the spec's SE presence) or one-pass ``fusedmb``
     nodes (empty pass 2, categorically serial exits).  Each node's apply
-    closure performs the exact block call the sequential loop used to
-    make — same ``SchedulePin``, same ``in_layout``, the spec's own
-    act/SE routing — so ``graph.lower(x)`` is bit-exact with the loop;
+    closure performs the exact block call a sequential loop would make
+    — same ``SchedulePin``, same ``in_layout``, the spec's own act/SE
+    routing — so ``graph.lower(x)`` is bit-exact with the loop;
     with a ``plan``, each node additionally inherits the plan's solved
     ``entry_overlap``.
 
@@ -276,7 +276,6 @@ def build_block_graph(specs, params, *, kcfg=None, mesh=None,
             f"{len(specs)}")
     nodes = []
     for i, sp in enumerate(specs):
-        family = getattr(sp, "family", "mbconv")
         if plan is not None:
             bp = plan.blocks[i]
             # FusedMBSchedule has no mode axis (single pass)
@@ -290,39 +289,25 @@ def build_block_graph(specs, params, *, kcfg=None, mesh=None,
             mode, overlap = "retain", DEFAULT_OVERLAP
             pin, in_layout = None, "replicated"
 
-        if family == "fusedmb":
-            def apply(x, _p=params[f"block{i}"], _sp=sp, _pin=pin,
-                      _ov=overlap if plan is not None else None):
-                y, _ = fusedmb_block(x, _p, stride=_sp.s, act=_sp.act,
-                                     cfg=kcfg, mesh=mesh, pin=_pin,
-                                     overlap=_ov)
-                return y
-
+        if sp.family == "fusedmb":
+            block, kw = fusedmb_block, dict(act=sp.act)
             p1, p2 = fusedmb_stage_io(i)
-            name = f"fusedmb{i}"
         else:
-            def apply(x, _p=params[f"block{i}"], _sp=sp, _pin=pin,
-                      _lay=in_layout,
-                      _ov=overlap if plan is not None else None):
-                y, _ = mbconv_block(
-                    x, _p, stride=_sp.s, cfg=kcfg, mesh=mesh, pin=_pin,
-                    in_layout=_lay, overlap=_ov,
-                    exp_act=getattr(_sp, "act", "silu"),
-                    dw_act=getattr(_sp, "act", "silu"),
-                    se_act=getattr(_sp, "se_act", "silu"),
-                    gate_act=getattr(_sp, "gate_act", "sigmoid"))
-                return y
+            block = mbconv_block
+            kw = dict(in_layout=in_layout, exp_act=sp.act, dw_act=sp.act,
+                      se_act=sp.se_act, gate_act=sp.gate_act)
+            p1, p2 = mbconv_stage_io(i, mode=mode, residual=sp.has_residual,
+                                     se=sp.has_se)
 
-            p1, p2 = mbconv_stage_io(
-                i, mode=mode, residual=sp.has_residual,
-                se=getattr(sp, "has_se", True))
-            name = f"mbconv{i}"
-        nodes.append(BlockNode(index=i, name=name, pass1=p1,
-                               pass2=p2, entry_overlap=overlap,
-                               apply=apply))
+        nodes.append(BlockNode(
+            index=i, name=f"{sp.family}{i}", pass1=p1, pass2=p2,
+            entry_overlap=overlap,
+            apply=_block_apply(block, params[f"block{i}"], stride=sp.s,
+                               cfg=kcfg, mesh=mesh, pin=pin,
+                               overlap=overlap, **kw)))
     return BlockGraph(nodes=tuple(nodes))
 
 
-# legacy name — the builder grew family dispatch and kept its behavior
-# for all-MBConv chains bit-for-bit
-build_mbconv_graph = build_block_graph
+def _block_apply(block, params, **kw):
+    """``x -> y`` of one block call (its out_layout dropped)."""
+    return lambda x: block(x, params, **kw)[0]

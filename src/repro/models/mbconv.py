@@ -1,4 +1,4 @@
-"""MBConv (EfficientNet) blocks and the EfficientNet-B0 builder.
+"""MBConv / Fused-MBConv blocks and the networks built from them.
 
 ``mbconv_block`` is the model-level entry point for one mobile inverted
 bottleneck with squeeze-and-excitation:
@@ -13,18 +13,22 @@ schedule — tile_h AND the pass-2 retain/recompute mode — solved by
 ``core.autotune.get_mbconv_schedule`` from the HBM traffic model.
 Otherwise the staged baseline (``kernels.convdk_mbconv_staged``) runs: the
 DW tensor round-trips through HBM around the SE stage.
+``fusedmb_block`` is the single-pass Fused-MBConv counterpart.
 
-``efficientnet_b0_def`` / ``efficientnet_b0_apply`` assemble the full
-EfficientNet-B0 (stem conv -> 16 MBConv blocks -> head conv -> pool ->
-classifier), every MBConv routed through the two-pass fused kernel.  The
-stage table reproduces ``core.workloads.EFFICIENTNET_B0`` exactly (a test
-asserts the consistency).
+``network_def`` / ``network_apply`` assemble a whole network of any
+family (stem conv -> block chain -> head conv -> pool [-> fc] ->
+classifier) from its config: the block specs, the stem/head activation
+and the optional fc layer are data, so adding a family needs only its
+spec table and config class.  ``efficientnet_b0_*``, ``mobilenet_v3_*``
+and ``efficientnet_v2_s_*`` bind them to each family's default config.
+The B0 stage table reproduces ``core.workloads.EFFICIENTNET_B0`` exactly
+(a test asserts the consistency).
 """
 
 from __future__ import annotations
 
 import dataclasses
-from typing import Any, Dict, List, Optional, Tuple
+from typing import Any, ClassVar, Dict, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -61,6 +65,11 @@ class EffNetConfig:
     head_c: int = 1280
     stages: Tuple[Tuple[int, int, int, int, int], ...] = EFFNET_B0_STAGES
     dtype: str = "float32"
+    # stem and head activation, a ``jax.nn`` name (class data, not a field)
+    stem_head_act: ClassVar[str] = "silu"
+
+    def block_specs(self) -> List["MBConvSpec"]:
+        return effnet_block_specs(self)
 
 
 def round_filters(c: int, width_mult: float, divisor: int = 8) -> int:
@@ -137,41 +146,38 @@ class MBConvSpec:
 
 def effnet_block_specs(cfg: EffNetConfig) -> List[MBConvSpec]:
     """The per-block MBConv table of one EfficientNet config."""
+    return _stage_specs(cfg, [("mbconv",) + st for st in cfg.stages])
+
+
+def _stage_specs(cfg, stages) -> List[MBConvSpec]:
+    """Specs of EfficientNet (family, expand_ratio, k, s, c_out, repeats)
+    stages: the first block of a stage carries the stride; ``fusedmb``
+    blocks never carry SE, and at expansion 1 their conv goes straight
+    to c_out (no projection, so c_mid = c_out); ``mbconv`` blocks
+    expand c_in by the ratio and carry SE at ``cfg.se_ratio``."""
     specs: List[MBConvSpec] = []
     c_in = round_filters(cfg.stem_c, cfg.width_mult)
-    for expand, k, s, c_out, repeats in cfg.stages:
+    for family, expand, k, s, c_out, repeats in stages:
         c_out = round_filters(c_out, cfg.width_mult)
         for i in range(repeats):
-            specs.append(MBConvSpec(c_in=c_in, c_out=c_out,
-                                    expand_ratio=expand, k=k,
-                                    s=s if i == 0 else 1,
-                                    se_ratio=cfg.se_ratio))
+            c_mid = None
+            if family == "fusedmb":
+                c_mid = c_out if expand == 1 else c_in * expand
+            specs.append(MBConvSpec(
+                c_in=c_in, c_out=c_out, expand_ratio=expand, k=k,
+                s=s if i == 0 else 1,
+                se_ratio=0.0 if family == "fusedmb" else cfg.se_ratio,
+                c_mid_override=c_mid, family=family))
             c_in = c_out
     return specs
 
 
-def effnet_chain_rows(specs: List[MBConvSpec], h: int, w: int
-                      ) -> Tuple[Tuple[int, int, int, int, int, int, int],
-                                 ...]:
-    """(h, w, c_in, c_mid, c_out, k, s) chain rows for the network-level
-    layout solver (``core.autotune.get_network_plan``), threading the
-    spatial dims through each block's stride.  ``h``/``w`` are the
-    STEM-OUTPUT dims (the first block's input) — callers with image dims
-    divide by the stem stride first.  Shared by ``efficientnet_b0_apply``
-    and the vision serving engine, so both price the same chain."""
-    rows, hh, ww = [], h, w
-    for sp in specs:
-        rows.append((hh, ww, sp.c_in, sp.c_mid, sp.c_out, sp.k, sp.s))
-        hh, ww = -(-hh // sp.s), -(-ww // sp.s)
-    return tuple(rows)
-
-
 def block_chain_rows(specs: List[MBConvSpec], h: int, w: int) -> tuple:
-    """Family-generic chain rows (``core.autotune.BlockRow``) for the
-    network-level layout solver — like ``effnet_chain_rows`` but carrying
-    each spec's family, act and SE ratio, so mixed-family chains
-    (EfficientNet-V2) and per-block act/SE variants (MobileNet-V3) solve
-    through the same DP."""
+    """``core.autotune.BlockRow`` chain rows for the network-level layout
+    solver, threading the spatial dims through each block's stride from
+    the STEM-OUTPUT dims ``h`` x ``w``; each row carries its spec's
+    family, act and SE ratio.  Shared by ``network_apply`` and the vision
+    serving engine, so both price the same chain."""
     from ..core.autotune import BlockRow
     rows, hh, ww = [], h, w
     for sp in specs:
@@ -531,110 +537,6 @@ def fusedmb_block(
 
 
 # ---------------------------------------------------------------------------
-# EfficientNet-B0
-# ---------------------------------------------------------------------------
-
-def efficientnet_b0_def(cfg: EffNetConfig = EffNetConfig()) -> dict:
-    """Param tree: stem conv -> MBConv blocks -> head conv -> classifier."""
-    specs = effnet_block_specs(cfg)
-    stem_c = round_filters(cfg.stem_c, cfg.width_mult)
-    head_c = round_filters(cfg.head_c, cfg.width_mult)
-    p: Dict[str, Any] = {
-        "stem": P((3, 3, 3, stem_c), (None,) * 4),
-        "head": P((specs[-1].c_out, head_c), (None, None), scale=2.0),
-        "cls_w": P((head_c, cfg.num_classes), (None, None)),
-        "cls_b": P((cfg.num_classes,), (None,), init="zeros"),
-    }
-    for i, sp in enumerate(specs):
-        p[f"block{i}"] = mbconv_def(sp.c_in, sp.c_out, k=sp.k,
-                                    expand_ratio=sp.expand_ratio,
-                                    se_ratio=sp.se_ratio)
-    return p
-
-
-def efficientnet_b0_apply(params: dict, images: jax.Array,
-                          cfg: EffNetConfig = EffNetConfig(),
-                          kcfg=None, mesh=None, plan=None) -> jax.Array:
-    """(B, H, W, 3) images -> (B, num_classes) logits.
-
-    Every MBConv block runs the two-pass fused ConvDK pipeline (or the
-    staged baseline, per ``kcfg``) — EfficientNet-B0 end to end through the
-    paper's dataflow.  With ``mesh``, every shardable block runs the
-    mesh-sharded fused pipeline (see ``mbconv_block``), and the per-block
-    schedules come from the NETWORK-level layout solve
-    (``core.autotune.get_network_plan``): the DP picks each block's
-    (residency, mode, collective, in/out layout) jointly over the whole
-    chain — the stem output materializes model-sharded when the plan says
-    so (a ``with_sharding_constraint``; block0's identity expand then
-    consumes it collective-free), and every block call threads the solved
-    layout chain via ``pin=`` / ``in_layout=``.
-
-    ``plan`` passes a pre-solved ``core.autotune.NetworkPlan`` explicitly
-    (it must match this call's chain shapes): the vision serving engine
-    solves one plan per resolution bucket and threads it here, so the
-    bytes its telemetry counters charge are — by construction — the
-    schedules the blocks actually run.
-
-    The block chain itself lowers through ``models.blockgraph``: the
-    specs (and plan, when present) build a ``BlockGraph`` whose nodes
-    carry explicit per-pass buffer sets and the plan's solved
-    ``entry_overlap``, ``validate()`` proves every pipelined boundary
-    hazard-free, and ``lower()`` runs the chain — bit-exact with the
-    former sequential loop."""
-    specs = effnet_block_specs(cfg)
-    dt = jnp.dtype(cfg.dtype)
-    with jax.named_scope("stem"):
-        x = jax.lax.conv_general_dilated(
-            images.astype(dt), params["stem"].astype(dt), (2, 2), "SAME",
-            dimension_numbers=("NHWC", "HWIO", "NHWC"))
-        x = jax.nn.silu(x)
-
-    if kcfg is None:
-        from ..configs.base import kernel_config
-        kcfg = kernel_config()
-    if plan is None and (mesh is not None and kcfg.shard_fused
-                         and kcfg.fused_mbconv and kcfg.autotune):
-        from ..core.autotune import get_network_plan
-        from ..kernels import conv_mesh_shape
-        b, h, w, _c0 = x.shape
-        plan = get_network_plan(effnet_chain_rows(specs, h, w), b,
-                                conv_mesh_shape(mesh),
-                                dtype_bytes=dt.itemsize,
-                                se_ratio=cfg.se_ratio)
-    if plan is not None:
-        if mesh is not None and plan.stem_layout == "model_sharded":
-            # materialize the stem output once per element mesh-wide: each
-            # device of a model group holds only its c0/mp channel slice,
-            # which block0's sharded-in entry consumes without a gather
-            from jax.sharding import NamedSharding
-            from jax.sharding import PartitionSpec as _P
-            from ..kernels.convdk_sharded import MODEL_AXIS, _batch_axes
-            with jax.named_scope("stem"):
-                x = jax.lax.with_sharding_constraint(
-                    x, NamedSharding(mesh, _P(_batch_axes(mesh), None,
-                                              None, MODEL_AXIS)))
-
-    # the 16-block chain lowers through its dataflow-graph form: each
-    # block is a BlockNode with explicit per-pass read/write buffer
-    # sets, validate() proves every plan-pipelined boundary hazard-free
-    # (only the boundary activation flows producer-pass-2 ->
-    # consumer-pass-1), and lower() executes the nodes in chain order —
-    # operation-for-operation what the old Python loop did, so forward
-    # and grad are bit-exact with it
-    from .blockgraph import build_mbconv_graph
-    graph = build_mbconv_graph(specs, params, kcfg=kcfg, mesh=mesh,
-                               plan=plan)
-    graph.validate()
-    x = graph.lower(x)
-    with jax.named_scope("head"):
-        x = jax.nn.silu(jnp.einsum("bhwc,cd->bhwd", x,
-                                   params["head"].astype(x.dtype)))
-        x = x.mean(axis=(1, 2))
-        return (x @ params["cls_w"].astype(x.dtype)
-                + params["cls_b"].astype(x.dtype))
-
-
-# ---------------------------------------------------------------------------
 # MobileNet-V3-Large
 # ---------------------------------------------------------------------------
 
@@ -680,6 +582,10 @@ class MobileNetV3Config:
     blocks: Tuple[Tuple[int, int, int, int, bool, str], ...] = \
         MOBILENET_V3_LARGE_BLOCKS
     dtype: str = "float32"
+    stem_head_act: ClassVar[str] = "hard_swish"
+
+    def block_specs(self) -> List[MBConvSpec]:
+        return mobilenet_v3_specs(self)
 
 
 def mobilenet_v3_specs(cfg: MobileNetV3Config) -> List[MBConvSpec]:
@@ -697,82 +603,6 @@ def mobilenet_v3_specs(cfg: MobileNetV3Config) -> List[MBConvSpec]:
             act=act, se_act="relu", gate_act="hard_sigmoid"))
         c_in = c_out
     return specs
-
-
-def mobilenet_v3_def(cfg: MobileNetV3Config = MobileNetV3Config()) -> dict:
-    """Param tree: stem conv -> V3 blocks -> head conv -> FC -> classifier."""
-    specs = mobilenet_v3_specs(cfg)
-    stem_c = round_filters(cfg.stem_c, cfg.width_mult)
-    head_c = round_filters(cfg.head_c, cfg.width_mult)
-    cls_c = round_filters(cfg.cls_c, cfg.width_mult)
-    p: Dict[str, Any] = {
-        "stem": P((3, 3, 3, stem_c), (None,) * 4),
-        "head": P((specs[-1].c_out, head_c), (None, None), scale=2.0),
-        "fc": P((head_c, cls_c), (None, None), scale=2.0),
-        "cls_w": P((cls_c, cfg.num_classes), (None, None)),
-        "cls_b": P((cfg.num_classes,), (None,), init="zeros"),
-    }
-    for i, sp in enumerate(specs):
-        p[f"block{i}"] = block_def(sp)
-    return p
-
-
-def mobilenet_v3_apply(params: dict, images: jax.Array,
-                       cfg: MobileNetV3Config = MobileNetV3Config(),
-                       kcfg=None, mesh=None, plan=None) -> jax.Array:
-    """(B, H, W, 3) images -> (B, num_classes) logits.
-
-    MobileNet-V3-Large end to end through the paper's dataflow: every
-    block runs the two-pass fused ConvDK pipeline with its OWN act and
-    SE facts — relu early stages, hard_swish late stages, SE on the
-    blocks Table 1 marks (the no-SE blocks pay zero SE bytes: no pool,
-    no gate, no squeeze collective under a mesh).  The chain lowers
-    through ``models.blockgraph`` exactly as EfficientNet-B0 does, and
-    with a mesh the per-block schedules come from the network-level
-    layout solve over family-generic ``BlockRow``s carrying the per-row
-    act/SE axes."""
-    specs = mobilenet_v3_specs(cfg)
-    dt = jnp.dtype(cfg.dtype)
-    with jax.named_scope("stem"):
-        x = jax.lax.conv_general_dilated(
-            images.astype(dt), params["stem"].astype(dt), (2, 2), "SAME",
-            dimension_numbers=("NHWC", "HWIO", "NHWC"))
-        x = jax.nn.hard_swish(x)
-
-    if kcfg is None:
-        from ..configs.base import kernel_config
-        kcfg = kernel_config()
-    if plan is None and (mesh is not None and kcfg.shard_fused
-                         and kcfg.fused_mbconv and kcfg.autotune):
-        from ..core.autotune import get_network_plan
-        from ..kernels import conv_mesh_shape
-        b, h, w, _c0 = x.shape
-        plan = get_network_plan(block_chain_rows(specs, h, w), b,
-                                conv_mesh_shape(mesh),
-                                dtype_bytes=dt.itemsize,
-                                se_ratio=cfg.se_ratio)
-    if plan is not None:
-        if mesh is not None and plan.stem_layout == "model_sharded":
-            from jax.sharding import NamedSharding
-            from jax.sharding import PartitionSpec as _P
-            from ..kernels.convdk_sharded import MODEL_AXIS, _batch_axes
-            with jax.named_scope("stem"):
-                x = jax.lax.with_sharding_constraint(
-                    x, NamedSharding(mesh, _P(_batch_axes(mesh), None,
-                                              None, MODEL_AXIS)))
-
-    from .blockgraph import build_block_graph
-    graph = build_block_graph(specs, params, kcfg=kcfg, mesh=mesh,
-                              plan=plan)
-    graph.validate()
-    x = graph.lower(x)
-    with jax.named_scope("head"):
-        x = jax.nn.hard_swish(jnp.einsum("bhwc,cd->bhwd", x,
-                                         params["head"].astype(x.dtype)))
-        x = x.mean(axis=(1, 2))
-        x = jax.nn.hard_swish(x @ params["fc"].astype(x.dtype))
-        return (x @ params["cls_w"].astype(x.dtype)
-                + params["cls_b"].astype(x.dtype))
 
 
 # ---------------------------------------------------------------------------
@@ -807,89 +637,98 @@ class EffNetV2Config:
     stages: Tuple[Tuple[str, int, int, int, int, int], ...] = \
         EFFNET_V2_S_STAGES
     dtype: str = "float32"
+    stem_head_act: ClassVar[str] = "silu"
+
+    def block_specs(self) -> List[MBConvSpec]:
+        return effnet_v2_block_specs(self)
 
 
 def effnet_v2_block_specs(cfg: EffNetV2Config) -> List[MBConvSpec]:
-    """The per-block spec table of one EfficientNet-V2 config — a
-    mixed-family chain: ``fusedmb`` specs for the fused stages (silu
-    dense conv, never SE; at expansion 1 the conv goes straight to c_out
-    and the block has no projection, so c_mid = c_out), ``mbconv`` specs
-    for the tail (silu, SE 0.25)."""
-    specs: List[MBConvSpec] = []
-    c_in = round_filters(cfg.stem_c, cfg.width_mult)
-    for family, expand, k, s, c_out, repeats in cfg.stages:
-        c_out = round_filters(c_out, cfg.width_mult)
-        for i in range(repeats):
-            c_mid = None
-            if family == "fusedmb":
-                c_mid = c_out if expand == 1 else c_in * expand
-            specs.append(MBConvSpec(
-                c_in=c_in, c_out=c_out, expand_ratio=expand, k=k,
-                s=s if i == 0 else 1,
-                se_ratio=0.0 if family == "fusedmb" else cfg.se_ratio,
-                c_mid_override=c_mid, family=family))
-            c_in = c_out
-    return specs
+    """The per-block spec table of one EfficientNet-V2 config."""
+    return _stage_specs(cfg, cfg.stages)
 
 
-def efficientnet_v2_s_def(cfg: EffNetV2Config = EffNetV2Config()) -> dict:
-    """Param tree: stem conv -> Fused-MBConv + MBConv blocks -> head conv
-    -> classifier."""
-    specs = effnet_v2_block_specs(cfg)
+# ---------------------------------------------------------------------------
+# the network: stem -> block chain -> head, for every family
+# ---------------------------------------------------------------------------
+
+STEM_STRIDE = 2      # the stem conv halves the spatial dims
+
+
+def network_def(cfg) -> dict:
+    """Param tree: stem conv -> blocks -> head conv [-> fc] -> classifier.
+
+    Every block comes from ``block_def`` of ``cfg.block_specs()``; a
+    config with a ``cls_c`` (MobileNet-V3) gets the dense ``fc`` layer
+    between the pooled head and the classifier."""
+    specs = cfg.block_specs()
     stem_c = round_filters(cfg.stem_c, cfg.width_mult)
     head_c = round_filters(cfg.head_c, cfg.width_mult)
     p: Dict[str, Any] = {
         "stem": P((3, 3, 3, stem_c), (None,) * 4),
         "head": P((specs[-1].c_out, head_c), (None, None), scale=2.0),
-        "cls_w": P((head_c, cfg.num_classes), (None, None)),
-        "cls_b": P((cfg.num_classes,), (None,), init="zeros"),
     }
+    cls_in = head_c
+    if getattr(cfg, "cls_c", None) is not None:
+        cls_in = round_filters(cfg.cls_c, cfg.width_mult)
+        p["fc"] = P((head_c, cls_in), (None, None), scale=2.0)
+    p["cls_w"] = P((cls_in, cfg.num_classes), (None, None))
+    p["cls_b"] = P((cfg.num_classes,), (None,), init="zeros")
     for i, sp in enumerate(specs):
         p[f"block{i}"] = block_def(sp)
     return p
 
 
-def efficientnet_v2_s_apply(params: dict, images: jax.Array,
-                            cfg: EffNetV2Config = EffNetV2Config(),
-                            kcfg=None, mesh=None, plan=None) -> jax.Array:
-    """(B, H, W, 3) images -> (B, num_classes) logits.
+def network_apply(params: dict, images: jax.Array, cfg, kcfg=None,
+                  mesh=None, plan=None) -> jax.Array:
+    """(B, H, W, 3) images -> (B, num_classes) logits, for any family.
 
-    EfficientNet-V2-S end to end: the fused stages run the SINGLE-PASS
-    ``kernels.convdk_fusedmb_fused`` pipeline, the tail the two-pass
-    MBConv pipeline — one mixed-family chain through
-    ``models.blockgraph`` (one-pass nodes validate with empty pass 2;
-    boundaries behind them stay serial) and, with a mesh, one
-    family-generic network-level layout solve (fusedmb entries always
-    replicated, the DP prices the boundary regathers accordingly)."""
-    specs = effnet_v2_block_specs(cfg)
+    The family is data: ``cfg.block_specs()`` is the block chain (each
+    spec's family, act and SE facts route its block), ``cfg.stem_head_act``
+    names the stem and head activation, and an ``"fc"`` entry in
+    ``params`` adds a dense layer (same activation) after the pool.
+
+    Without a ``plan`` and with a ``mesh``, the per-block schedules come
+    from the network-level layout solve (``core.autotune.get_network_plan``
+    over ``block_chain_rows``), which may materialize the stem output
+    model-sharded; ``plan`` passes a pre-solved ``NetworkPlan`` instead
+    (the vision serving engine solves one per bucket, so the bytes its
+    counters charge are the schedules that run).  The chain lowers
+    through ``models.blockgraph``: ``validate()`` proves every pipelined
+    boundary hazard-free and ``lower()`` runs each block under its
+    ``mbconv<i>`` / ``fusedmb<i>`` name scope."""
+    specs = cfg.block_specs()
+    act = getattr(jax.nn, cfg.stem_head_act)
     dt = jnp.dtype(cfg.dtype)
-    with jax.named_scope("stem"):
-        x = jax.lax.conv_general_dilated(
-            images.astype(dt), params["stem"].astype(dt), (2, 2), "SAME",
-            dimension_numbers=("NHWC", "HWIO", "NHWC"))
-        x = jax.nn.silu(x)
-
     if kcfg is None:
         from ..configs.base import kernel_config
         kcfg = kernel_config()
-    if plan is None and (mesh is not None and kcfg.shard_fused
-                         and kcfg.fused_mbconv and kcfg.autotune):
-        from ..core.autotune import get_network_plan
-        from ..kernels import conv_mesh_shape
-        b, h, w, _c0 = x.shape
-        plan = get_network_plan(block_chain_rows(specs, h, w), b,
-                                conv_mesh_shape(mesh),
-                                dtype_bytes=dt.itemsize,
-                                se_ratio=cfg.se_ratio)
-    if plan is not None:
-        if mesh is not None and plan.stem_layout == "model_sharded":
+    with jax.named_scope("stem"):
+        x = jax.lax.conv_general_dilated(
+            images.astype(dt), params["stem"].astype(dt),
+            (STEM_STRIDE, STEM_STRIDE), "SAME",
+            dimension_numbers=("NHWC", "HWIO", "NHWC"))
+        x = act(x)
+        if plan is None and (mesh is not None and kcfg.shard_fused
+                             and kcfg.fused_mbconv and kcfg.autotune):
+            from ..core.autotune import get_network_plan
+            from ..kernels import conv_mesh_shape
+            b, h, w, _c0 = x.shape
+            plan = get_network_plan(block_chain_rows(specs, h, w), b,
+                                    conv_mesh_shape(mesh),
+                                    dtype_bytes=dt.itemsize,
+                                    se_ratio=cfg.se_ratio)
+        if (plan is not None and mesh is not None
+                and plan.stem_layout == "model_sharded"):
+            # materialize the stem output once per element mesh-wide: each
+            # device of a model group holds only its c0/mp channel slice,
+            # which block0's sharded-in entry consumes without a gather
             from jax.sharding import NamedSharding
             from jax.sharding import PartitionSpec as _P
             from ..kernels.convdk_sharded import MODEL_AXIS, _batch_axes
-            with jax.named_scope("stem"):
-                x = jax.lax.with_sharding_constraint(
-                    x, NamedSharding(mesh, _P(_batch_axes(mesh), None,
-                                              None, MODEL_AXIS)))
+            x = jax.lax.with_sharding_constraint(
+                x, NamedSharding(mesh, _P(_batch_axes(mesh), None, None,
+                                          MODEL_AXIS)))
 
     from .blockgraph import build_block_graph
     graph = build_block_graph(specs, params, kcfg=kcfg, mesh=mesh,
@@ -897,8 +736,42 @@ def efficientnet_v2_s_apply(params: dict, images: jax.Array,
     graph.validate()
     x = graph.lower(x)
     with jax.named_scope("head"):
-        x = jax.nn.silu(jnp.einsum("bhwc,cd->bhwd", x,
-                                   params["head"].astype(x.dtype)))
+        x = act(jnp.einsum("bhwc,cd->bhwd", x,
+                           params["head"].astype(x.dtype)))
         x = x.mean(axis=(1, 2))
+        if "fc" in params:
+            x = act(x @ params["fc"].astype(x.dtype))
         return (x @ params["cls_w"].astype(x.dtype)
                 + params["cls_b"].astype(x.dtype))
+
+
+# the families' named entries: the network at each default config
+
+def efficientnet_b0_def(cfg: EffNetConfig = EffNetConfig()) -> dict:
+    return network_def(cfg)
+
+
+def efficientnet_b0_apply(params: dict, images: jax.Array,
+                          cfg: EffNetConfig = EffNetConfig(),
+                          kcfg=None, mesh=None, plan=None) -> jax.Array:
+    return network_apply(params, images, cfg, kcfg, mesh=mesh, plan=plan)
+
+
+def mobilenet_v3_def(cfg: MobileNetV3Config = MobileNetV3Config()) -> dict:
+    return network_def(cfg)
+
+
+def mobilenet_v3_apply(params: dict, images: jax.Array,
+                       cfg: MobileNetV3Config = MobileNetV3Config(),
+                       kcfg=None, mesh=None, plan=None) -> jax.Array:
+    return network_apply(params, images, cfg, kcfg, mesh=mesh, plan=plan)
+
+
+def efficientnet_v2_s_def(cfg: EffNetV2Config = EffNetV2Config()) -> dict:
+    return network_def(cfg)
+
+
+def efficientnet_v2_s_apply(params: dict, images: jax.Array,
+                            cfg: EffNetV2Config = EffNetV2Config(),
+                            kcfg=None, mesh=None, plan=None) -> jax.Array:
+    return network_apply(params, images, cfg, kcfg, mesh=mesh, plan=plan)

@@ -1,10 +1,10 @@
-"""Batched vision serving engine over the fused EfficientNet pipeline.
+"""Batched vision serving engine over the fused block-chain networks.
 
 The LM engine (``serve.engine``) buckets requests by prompt LENGTH; the
 vision engine generalizes the same BIG/LITTLE admission idea to image
 RESOLUTION: mixed 224/384/512 requests are admitted into per-resolution
 buckets and launched as shape-stable jitted batches (one trace per bucket,
-never per request), through ``efficientnet_b0_apply`` with the
+never per request), through ``models.mbconv.network_apply`` with the
 network-level layout plan (``core.autotune.get_network_plan``) solved ONCE
 per bucket and threaded into every launch.
 
@@ -31,13 +31,9 @@ lane (FIFO within each lane; the batch back-fills from the normal lane's
 same bucket).  Shedding is unchanged — the queue bound applies to the
 COMBINED depth, so priority requests cannot starve the shed accounting.
 
-Every bucket's plan comes from the network-level solve, which now
-includes the cross-block ``overlap`` axis: boundaries the DP proves
-pipelinable execute pass 2 of block *i* overlapped with pass 1 of block
-*i+1* (``models.blockgraph`` validates the buffer hazards at lowering),
-so serving inherits the pipelined chain latency without any engine code
-knowing about it.  ``serve.pipelined_boundaries.r<res>`` records how
-many boundaries of the bucket's plan pipeline.
+Boundaries the bucket's plan proves pipelinable run pass 2 of block *i*
+overlapped with pass 1 of block *i+1* (``models.blockgraph`` validates
+the hazards); ``serve.pipelined_boundaries.r<res>`` counts them.
 
 Counter naming (shape-class first, then layer):
 
@@ -71,12 +67,7 @@ import numpy as np
 
 from ..core import telemetry
 from ..core.autotune import NetworkPlan, get_network_plan
-from ..models.mbconv import (
-    EffNetConfig,
-    effnet_block_specs,
-    effnet_chain_rows,
-    efficientnet_b0_apply,
-)
+from ..models.mbconv import STEM_STRIDE, block_chain_rows, network_apply
 
 __all__ = [
     "VisionEngine",
@@ -85,8 +76,6 @@ __all__ = [
     "VisionServeConfig",
     "layer_names",
 ]
-
-STEM_STRIDE = 2      # the B0 stem conv halves the spatial dims
 
 
 @dataclasses.dataclass(frozen=True)
@@ -150,7 +139,8 @@ def layer_names(n_blocks: int) -> Tuple[str, ...]:
 
 
 class VisionEngine:
-    """Admission-bucketed batched inference over the fused B0 pipeline.
+    """Admission-bucketed batched inference over the fused pipeline of
+    the network ``cfg`` describes (any family ``network_apply`` runs).
 
     ``submit()`` admits (or sheds) one image; ``step()`` launches ONE
     shape-stable batch — the oldest waiter's bucket, filled FIFO from that
@@ -160,9 +150,9 @@ class VisionEngine:
     never re-traces and never re-solves.
     """
 
-    def __init__(self, params, cfg: EffNetConfig = EffNetConfig(),
-                 serve_cfg: Optional[VisionServeConfig] = None,
-                 mesh=None, kcfg=None):
+    def __init__(self, params, cfg,
+                 serve_cfg: Optional[VisionServeConfig] = None, mesh=None,
+                 kcfg=None):
         self.params = params
         self.cfg = cfg
         self.scfg = serve_cfg or VisionServeConfig()
@@ -171,7 +161,7 @@ class VisionEngine:
             from ..configs.base import kernel_config
             kcfg = kernel_config()
         self.kcfg = kcfg
-        self.specs = effnet_block_specs(cfg)
+        self.specs = cfg.block_specs()
         self._queue: Deque[VisionRequest] = deque()
         self._pqueue: Deque[VisionRequest] = deque()
         self._next_rid = 0
@@ -243,7 +233,7 @@ class VisionEngine:
         the schedules priced here are the schedules that run."""
         if res not in self._plans:
             stem_hw = -(-res // STEM_STRIDE)
-            rows = effnet_chain_rows(self.specs, stem_hw, stem_hw)
+            rows = block_chain_rows(self.specs, stem_hw, stem_hw)
             plan = get_network_plan(
                 rows, self.scfg.batch_size, self._mesh_shape(),
                 dtype_bytes=jnp.dtype(self.cfg.dtype).itemsize,
@@ -279,8 +269,8 @@ class VisionEngine:
                 # staying at 1 per bucket IS the no-per-request-retrace
                 # guarantee the admission design makes
                 telemetry.counter(f"serve.trace.r{res}")
-                return efficientnet_b0_apply(params, images, cfg, kcfg,
-                                             mesh=mesh, plan=plan)
+                return network_apply(params, images, cfg, kcfg,
+                                     mesh=mesh, plan=plan)
 
             self._applies[res] = jax.jit(apply)
         return self._applies[res]

@@ -274,24 +274,24 @@ def test_build_graph_matches_plan_annotation():
     """The built graph inherits the plan's solved overlap marks 1:1 and
     validates — the lowering path CI exercises, minus the jit."""
     from repro.configs.efficientnet_b0 import efficientnet_b0_smoke
-    from repro.models.blockgraph import build_mbconv_graph
+    from repro.models.blockgraph import build_block_graph
     from repro.models.mbconv import (
-        effnet_block_specs, effnet_chain_rows, efficientnet_b0_def,
+        block_chain_rows, effnet_block_specs, efficientnet_b0_def,
     )
     from repro.models.param import materialize
     from repro.core.autotune import get_network_plan
     cfg = efficientnet_b0_smoke(width_mult=0.125, num_classes=4)
     params = materialize(efficientnet_b0_def(cfg), jax.random.key(0))
     specs = effnet_block_specs(cfg)
-    plan = get_network_plan(effnet_chain_rows(specs, 16, 16), 8, (2, 4),
+    plan = get_network_plan(block_chain_rows(specs, 16, 16), 8, (2, 4),
                             dtype_bytes=4, se_ratio=cfg.se_ratio)
-    g = build_mbconv_graph(specs, params, plan=plan)
+    g = build_block_graph(specs, params, plan=plan)
     g.validate()
     assert g.pipelined_boundaries == plan.pipelined_boundaries
     for node, bp in zip(g.nodes, plan.blocks):
         assert node.entry_overlap == bp.entry_overlap
     # serial build: same chain, no overlap marks
-    g0 = build_mbconv_graph(specs, params)
+    g0 = build_block_graph(specs, params)
     g0.validate()
     assert g0.pipelined_boundaries == ()
 
@@ -308,8 +308,8 @@ _PREAMBLE = textwrap.dedent("""
     from repro.compat import make_mesh
     from repro.configs.base import ConvKernelConfig, SchedulePin
     from repro.models.mbconv import (
-        EffNetConfig, efficientnet_b0_apply, efficientnet_b0_def,
-        effnet_block_specs, effnet_chain_rows, mbconv_block,
+        EffNetConfig, block_chain_rows, efficientnet_b0_apply,
+        efficientnet_b0_def, effnet_block_specs, mbconv_block,
     )
     from repro.models.param import materialize
 
@@ -409,7 +409,7 @@ def test_planned_pipelined_chain_parity(mesh):
         base_us=0.0, us_per_mb=1000.0, us_per_dma_issue=1.0,
         us_per_collective_mb=1000.0, n_samples=1, rms_us=0.0))
     try:
-        plan = get_network_plan(effnet_chain_rows(specs, 12, 12), 8,
+        plan = get_network_plan(block_chain_rows(specs, 12, 12), 8,
                                 conv_mesh_shape(mesh), dtype_bytes=4,
                                 se_ratio=cfg.se_ratio)
         if conv_mesh_shape(mesh)[1] > 1:

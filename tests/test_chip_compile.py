@@ -4,13 +4,15 @@ The TPU compiler ships with the installed jaxlib and compiles for a chip
 that is described, not attached, so these tests show for free what
 interpret mode hides: tile alignment of blocks and DMA windows, strided
 loads, mask shapes and the scoped-VMEM limit.  Every case compiles one
-kernel (or the whole B0 apply) at published widths with ``interpret=False``
-and asserts that the Mosaic kernel is in the program (``tpu_custom_call``).
+kernel (or a whole network's named entry) at published widths with
+``interpret=False`` and asserts that the Mosaic kernel is in the program
+(``tpu_custom_call``).
 
 The topology is described inside a fixture, never at import: only one
 process may load the TPU library, and every test worker imports this file.
 """
 
+import contextlib
 import functools
 import re
 
@@ -34,6 +36,7 @@ from repro.kernels import (
     convdk_mbconv_fused,
     convdk_mbconv_fused_sharded,
 )
+from repro.models import mbconv as M
 from repro.models.mbconv import (
     EffNetV2Config,
     MOBILENET_V3_LARGE_BLOCKS,
@@ -267,41 +270,32 @@ def test_sharded_mbconv_compiles(mesh):
     assert "all-reduce" in hlo or "reduce-scatter" in hlo
 
 
-def test_efficientnet_v2_s_apply_compiles_at_the_cell_size(one_chip):
-    """The whole served V2-S program as its benchmark cell runs it:
-    published widths, batch 64 at 384, "highest" matmul precision."""
+# each family's named entry at (batch, resolution, matmul precision):
+# V2-S as its benchmark cell runs it, B0 and V3-L at 224, batch 8
+_NAMED_ENTRIES = {
+    "efficientnet_b0": (M.EffNetConfig, M.efficientnet_b0_def,
+                        M.efficientnet_b0_apply, BATCH, 224, None),
+    "mobilenet_v3_large": (M.MobileNetV3Config, M.mobilenet_v3_def,
+                           M.mobilenet_v3_apply, BATCH, 224, None),
+    "efficientnet_v2_s": (M.EffNetV2Config, M.efficientnet_v2_s_def,
+                          M.efficientnet_v2_s_apply, 64, 384, "highest"),
+}
+
+
+@pytest.mark.parametrize("model", sorted(_NAMED_ENTRIES))
+def test_named_entry_compiles(one_chip, model):
+    """The whole served program of each family at published widths,
+    through its named entry."""
     from repro.configs.base import ConvKernelConfig
-    from repro.models.mbconv import (
-        efficientnet_v2_s_apply,
-        efficientnet_v2_s_def,
-    )
     from repro.models.param import abstract
 
-    cfg = EffNetV2Config()
+    config, define, apply, batch, res, precision = _NAMED_ENTRIES[model]
+    cfg = config()
     params = jax.tree.map(
         lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
-        abstract(efficientnet_v2_s_def(cfg)))
+        abstract(define(cfg)))
     kcfg = ConvKernelConfig(interpret=False)
-    with jax.default_matmul_precision("highest"):
-        _assert_kernel(
-            lambda p, x: efficientnet_v2_s_apply(p, x, cfg, kcfg),
-            params, _spec(one_chip, 64, 384, 384, 3))
-
-
-def test_efficientnet_b0_apply_compiles(one_chip):
-    """The whole served program: full-width B0 at 224, batch 8."""
-    from repro.configs.base import ConvKernelConfig
-    from repro.models.mbconv import (
-        EffNetConfig,
-        efficientnet_b0_apply,
-        efficientnet_b0_def,
-    )
-    from repro.models.param import abstract
-
-    cfg = EffNetConfig()
-    params = jax.tree.map(
-        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
-        abstract(efficientnet_b0_def(cfg)))
-    kcfg = ConvKernelConfig(interpret=False)
-    _assert_kernel(lambda p, x: efficientnet_b0_apply(p, x, cfg, kcfg),
-                   params, _spec(one_chip, BATCH, 224, 224, 3))
+    with (jax.default_matmul_precision(precision) if precision
+          else contextlib.nullcontext()):
+        _assert_kernel(lambda p, x: apply(p, x, cfg, kcfg),
+                       params, _spec(one_chip, batch, res, res, 3))

@@ -528,6 +528,46 @@ def test_efficientnet_v2_s_matches_sequential_oracle():
         np.testing.assert_allclose(got, want, rtol=2e-3, atol=2e-3)
 
 
+def _named_entries():
+    """Each family's named (def, apply) entries at a CI-sized config."""
+    from repro.models import mbconv as M
+    return {
+        "efficientnet_b0": (
+            M.EffNetConfig(num_classes=4, width_mult=0.125),
+            M.efficientnet_b0_def, M.efficientnet_b0_apply),
+        "mobilenet_v3_large": (
+            M.MobileNetV3Config(num_classes=4, width_mult=0.125),
+            M.mobilenet_v3_def, M.mobilenet_v3_apply),
+        "efficientnet_v2_s": (
+            M.EffNetV2Config(num_classes=4, width_mult=0.25, head_c=128,
+                             stages=(("fusedmb", 1, 3, 1, 24, 1),
+                                     ("fusedmb", 4, 3, 2, 48, 2),
+                                     ("mbconv", 4, 3, 2, 64, 2))),
+            M.efficientnet_v2_s_def, M.efficientnet_v2_s_apply),
+    }
+
+
+@pytest.mark.parametrize("model", ["efficientnet_b0", "mobilenet_v3_large",
+                                   "efficientnet_v2_s"])
+def test_named_entry_is_network_apply(model):
+    """Each family's named entry is the one network entry at its config:
+    the same param tree as ``network_def`` and the same logits as
+    ``network_apply`` with the same config and params."""
+    from repro.configs.base import ConvKernelConfig
+    from repro.models.mbconv import network_apply, network_def
+    from repro.models.param import abstract, materialize
+
+    cfg, define, apply = _named_entries()[model]
+    assert abstract(define(cfg)) == abstract(network_def(cfg))
+    params = materialize(define(cfg), jax.random.key(0))
+    x = _rand(np.random.default_rng(0), (1, 16, 16, 3), 0.5)
+    kcfg = ConvKernelConfig(interpret=True)
+    got = apply(params, x, cfg, kcfg=kcfg)
+    want = network_apply(params, x, cfg, kcfg=kcfg)
+    assert got.shape == (1, 4)
+    assert jnp.array_equal(got, want)
+
+
 def test_one_pass_nodes_validate_and_refuse_phantom_overlap():
     """Graph contract: fusedmb nodes carry an EMPTY pass 2, validate as
     one-pass producers, and a pipelined entry directly behind one is a

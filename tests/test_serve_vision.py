@@ -4,6 +4,7 @@ load-shedding at the queue bound, and exact reconciliation of telemetry
 byte counters against the solved plans' modeled traffic."""
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
@@ -268,6 +269,64 @@ def test_pipelined_boundaries_counter(engine_parts):
     eng.plan_for(16)                                     # cached: no re-count
     assert t.get("serve.pipelined_boundaries.r16") \
         == len(plan.pipelined_boundaries)
+
+
+def test_engine_serves_mobilenet_v3():
+    """The engine is family-generic: a CI-width MobileNet-V3 serves
+    through it, and its logits equal ``mobilenet_v3_apply``'s under the
+    engine's own plan on the same zero-padded batch."""
+    from repro.models.mbconv import (
+        MobileNetV3Config,
+        mobilenet_v3_apply,
+        mobilenet_v3_def,
+    )
+
+    telemetry.reset()
+    cfg = MobileNetV3Config(width_mult=0.25, num_classes=4)
+    params = materialize(mobilenet_v3_def(cfg), jax.random.key(0))
+    eng = VisionEngine(params, cfg, VisionServeConfig(
+        resolutions=(16,), batch_size=2, max_queue=4))
+    img = _img(np.random.default_rng(3), 12)
+    rid = eng.submit(img)
+    (res,) = eng.drain()
+    assert (res.rid, res.bucket, res.logits.shape) == (rid, 16, (4,))
+    assert telemetry.get_telemetry().get("serve.trace.r16") == 1
+
+    batch = np.zeros((2, 16, 16, 3), np.float32)
+    batch[0, :12, :12] = img
+    plan = eng.plan_for(16)
+    want = jax.jit(lambda p, x: mobilenet_v3_apply(
+        p, x, cfg, eng.kcfg, plan=plan))(params, jnp.asarray(batch))
+    np.testing.assert_array_equal(res.logits, np.asarray(want)[0])
+
+
+def test_b0_block_rows_plan_matches_tuple_rows():
+    """B0 on a (2, 4) mesh: the engine's plan, solved over
+    ``block_chain_rows``, equals the plan over the tuple rows
+    ``network_rows_from_table`` builds from B0's workload table, so the
+    modeled bytes the engine charges per launch stay as they were."""
+    from jax.sharding import AbstractMesh
+
+    from repro.core.autotune import get_network_plan, network_rows_from_table
+    from repro.core.workloads import EFFICIENTNET_B0_MBCONV
+    from repro.models.mbconv import EffNetConfig
+    from repro.models.param import abstract
+
+    cfg = EffNetConfig()
+    eng = VisionEngine(abstract(efficientnet_b0_def(cfg)), cfg,
+                       VisionServeConfig(resolutions=(224,), batch_size=8),
+                       mesh=AbstractMesh((2, 4), ("data", "model")))
+    want = get_network_plan(network_rows_from_table(EFFICIENTNET_B0_MBCONV),
+                            8, (2, 4), dtype_bytes=4,
+                            se_ratio=cfg.se_ratio)
+    got = eng.plan_for(224)
+    assert got == want
+    layers = eng.modeled_layer_bytes(224)
+    assert layers["stem"] == (want.stem_bytes, 0)
+    assert [layers[f"block{i:02d}"] for i in range(16)] == [
+        (bp.schedule.total_bytes, bp.schedule.collective_bytes)
+        for bp in want.blocks]
+    assert layers["boundaries"] == (want.boundary_words * 4, 0)
 
 
 def test_serve_config_validation():
