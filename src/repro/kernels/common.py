@@ -10,9 +10,10 @@ from __future__ import annotations
 from typing import NamedTuple, Tuple
 
 import jax
+import jax.numpy as jnp
 from jax.experimental.pallas import tpu as pltpu
 
-from ..core.perfmodel import launch_width
+from ..core.perfmodel import LANES, SUBLANES, launch_width
 
 
 def default_interpret() -> bool:
@@ -28,6 +29,13 @@ def compiler_params() -> pltpu.CompilerParams:
     schedule the solver admits is one the compiler accepts."""
     from ..core.autotune import TPUConfig
     return pltpu.CompilerParams(vmem_limit_bytes=TPUConfig().vmem_bytes)
+
+
+def store_out(o_ref, val):
+    """Write a computed ``(tile_h, out_wk, C)`` output tile into a
+    ``(1, tile_h, out_w, C)`` output block, which holds only the image's
+    ``out_w`` columns (the cover columns are computed, never stored)."""
+    o_ref[0] = val[:, :o_ref.shape[2]].astype(o_ref.dtype)
 
 
 def round_up(x: int, m: int) -> int:
@@ -58,18 +66,26 @@ def spatial_pads(
 class LaunchGeometry(NamedTuple):
     """How one strip-tiled conv launch covers its input.
 
-    ``out_wk`` is the kernel's output width — ``out_w`` rounded up to whole
-    sublanes (``perfmodel.launch_width``); its extra columns are sliced
-    off.  ``pads`` is the jnp.pad spec of the (B, H, W) dims: SAME/VALID
-    padding plus the height cover of the last strip and the width cover of
-    ``out_wk`` taps, in whole sublanes."""
+    The kernels stage from the block's activation as it is, with no
+    padded copy: each staged window is a ``(rows, w_tot, C)`` frame whose
+    image pixels sit at row ``top`` and column ``left`` of the padded
+    input, the rest of the frame zero (built in VMEM by
+    ``kernels.staging``).  ``h_tot`` is the frame's height: SAME/VALID
+    padding plus the height cover of the last strip.  ``w_tot`` is its
+    width: the reach of ``out_wk`` taps, in whole sublanes.  ``out_wk``
+    is the kernel's computed output width — ``out_w`` rounded up to whole
+    sublanes (``perfmodel.launch_width``); only the first ``out_w``
+    columns are stored."""
 
     out_h: int
     out_w: int
     out_wk: int
     tile_h: int
     n_th: int
-    pads: Tuple[Tuple[int, int], ...]
+    top: int
+    left: int
+    h_tot: int
+    w_tot: int
 
 
 def launch_geometry(h: int, w_in: int, k_h: int, k_w: int, s: int,
@@ -79,8 +95,64 @@ def launch_geometry(h: int, w_in: int, k_h: int, k_w: int, s: int,
     tile_h = max(1, min(tile_h, out_h))
     n_th = -(-out_h // tile_h)
     out_wk, w_tot = launch_width(out_w, s, k_w, w_in + left + right)
-    need_h = (n_th * tile_h - 1) * s + k_h
-    bottom += max(0, need_h - (h + top + bottom))
-    right = w_tot - w_in - left
-    return LaunchGeometry(out_h, out_w, out_wk, tile_h, n_th,
-                          ((0, 0), (top, bottom), (left, right)))
+    h_tot = max(h + top + bottom, (n_th * tile_h - 1) * s + k_h)
+    return LaunchGeometry(out_h, out_w, out_wk, tile_h, n_th, top, left,
+                          h_tot, w_tot)
+
+
+# -- the lane layout the block chain carries ---------------------------------
+#
+# Between two blocks an activation stays in the layout the next kernel
+# stages from: channels rounded up to whole 128-lane groups, zero from the
+# real channel count up.  HBM stores a narrow float32 channel dim in whole
+# 128-lane tiles anyway, so the zero tail costs no bytes, and a strip DMA
+# can address the array only at that width.  An array whose channel dim
+# is a whole number of lane groups is taken to be in that layout; the
+# kernels read the real channel count from their weights.
+
+def in_lane_layout(x) -> bool:
+    """Whether ``x``'s channel dim is a whole number of 128-lane groups."""
+    return x.shape[-1] % LANES == 0
+
+
+def to_lanes(x):
+    """``x`` with its channels zero-padded up to whole 128-lane groups (a
+    copy only when they are not already)."""
+    c = x.shape[-1]
+    if c % LANES == 0:
+        return x
+    return jnp.pad(x, ((0, 0),) * (x.ndim - 1) + ((0, round_up(c, LANES)
+                                                    - c),))
+
+
+def as_layout(y, c: int, lanes: bool):
+    """A block output in its caller's layout: ``y`` (c real channels,
+    possibly lane-padded) lane-padded when ``lanes``, else its first
+    ``c`` channels."""
+    return to_lanes(y) if lanes else y[..., :c]
+
+
+def _needs_width_cover(x, c_block: int) -> bool:
+    return x.shape[2] % SUBLANES != 0 and x.shape[-1] > c_block
+
+
+def strip_source(x, c_block: int):
+    """The array a kernel's strip copies read: ``x`` (lane layout) itself,
+    unless its channels span several ``c_block`` blocks and its width is
+    not a whole number of sublanes.  Mosaic slices a channel block out
+    of an array only at such a width, so there ``x`` gets its width
+    zero-padded up to whole sublanes — a copy, at 7 x 7 and 12 x 12 in
+    the benchmarked networks; the zero columns fall in the right halo."""
+    if not _needs_width_cover(x, c_block):
+        return x
+    w = x.shape[2]
+    return jnp.pad(x, ((0, 0), (0, 0), (0, round_up(w, SUBLANES) - w),
+                       (0, 0)))
+
+
+def stages_in_place(x) -> bool:
+    """Whether a block's kernels stage ``x`` as it is: in the lane layout
+    and needing no width cover (``strip_source`` with the kernels'
+    128-lane channel block), so the whole conv halo is built in VMEM and
+    no XLA op copies ``x`` in front of the launch."""
+    return in_lane_layout(x) and not _needs_width_cover(x, LANES)

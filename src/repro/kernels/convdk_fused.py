@@ -52,10 +52,13 @@ from jax.experimental.pallas import tpu as pltpu
 
 from ..core.perfmodel import DEFAULT_RESIDENCY, pick_channel_block
 from .common import (
+    LaunchGeometry,
     compiler_params,
     default_interpret,
     launch_geometry,
     round_up as _round_up,
+    strip_source,
+    to_lanes,
 )
 from .ref import _act_ref, separable_ref
 from .staging import StripPlan, StripStream, strip_plan
@@ -66,8 +69,8 @@ def _fused_kernel(x_ref, wdw_ref, wpw_ref, o_ref, *scratch, plan: StripPlan,
                   dw_act: Optional[str], act: Optional[str]):
     """One (batch, row-strip, c_out-block, c_in-block) grid cell.
 
-    x_ref   : unstaged input — a full-height VMEM channel block
-              (``resident``) or the whole ``ANY``-space tensor (DMA modes)
+    x_ref   : unstaged input — the whole ``ANY``-space tensor, staged by
+              the engine with its conv halo built in VMEM
     wdw_ref : (k_h, k_w, CI)         depthwise taps (the "TM")
     wpw_ref : (CI, CO)               pointwise projection block
     o_ref   : (1, tile_h, out_w, CO)
@@ -118,14 +121,12 @@ def _fused_kernel(x_ref, wdw_ref, wpw_ref, o_ref, *scratch, plan: StripPlan,
 
 
 def fused_separable_pallas(
-    x_pad: jax.Array,
+    x: jax.Array,
     w_dw: jax.Array,
     w_pw: jax.Array,
     *,
+    geo: LaunchGeometry,
     stride: int,
-    out_w: int,
-    tile_h: int,
-    n_th: int,
     ci_block: int,
     co_block: int,
     dw_act: Optional[str] = None,
@@ -133,23 +134,27 @@ def fused_separable_pallas(
     interpret: bool = False,
     residency: str = DEFAULT_RESIDENCY,
 ) -> jax.Array:
-    """Raw fused kernel launch over a pre-padded input.
+    """Raw fused kernel launch over the block's input.
 
-    x_pad : (B, H_tot, W_pad, C_in) with H_tot >= (n_th-1)*tile_h*s + in_rows
+    x     : (B, H, W, C_in), unpadded spatially (``geo`` places its conv
+            halo, which the staging engine builds in VMEM), C_in in whole
+            128-lane groups
     w_dw  : (k_h, k_w, C_in);  w_pw : (C_in, C_out)
-    returns (B, n_th*tile_h, out_w, C_out)
+    returns (B, n_th*tile_h, out_wk, C_out)
     """
-    b, h_tot, w_pad, c_in = x_pad.shape
+    b, h, w_in, c_in = x.shape
     k_h, k_w, _ = w_dw.shape
     c_out = w_pw.shape[1]
+    tile_h, out_w = geo.tile_h, geo.out_wk
     assert c_in % ci_block == 0, (c_in, ci_block)
     assert c_out % co_block == 0, (c_out, co_block)
-    grid = (b, n_th, c_out // co_block, c_in // ci_block)
+    grid = (b, geo.n_th, c_out // co_block, c_in // ci_block)
 
     plan = strip_plan(
-        h_tot=h_tot, w_tot=w_pad, c_block=ci_block, tile_h=tile_h,
+        h_tot=geo.h_tot, w_tot=geo.w_tot, c_block=ci_block, tile_h=tile_h,
         grid=grid, window_dims=(0, 1, 3), stride=stride, k_h=k_h,
-        residency=residency)
+        residency=residency, h_src=h, w_src=w_in, top=geo.top,
+        left=geo.left)
 
     kernel = functools.partial(
         _fused_kernel, plan=plan, k_h=k_h, k_w=k_w, stride=stride,
@@ -159,7 +164,7 @@ def fused_separable_pallas(
         kernel,
         grid=grid,
         in_specs=[
-            plan.in_spec(lambda bi, ti, co, ci: (bi, 0, 0, ci)),
+            plan.in_spec(),
             pl.BlockSpec((k_h, k_w, ci_block),
                          lambda bi, ti, co, ci: (0, 0, ci)),
             pl.BlockSpec((ci_block, co_block),
@@ -170,13 +175,13 @@ def fused_separable_pallas(
             lambda bi, ti, co, ci: (bi, ti, 0, co),
         ),
         out_shape=jax.ShapeDtypeStruct(
-            (b, n_th * tile_h, out_w, c_out), x_pad.dtype),
+            (b, geo.n_th * tile_h, out_w, c_out), x.dtype),
         scratch_shapes=[pltpu.VMEM((tile_h, out_w, co_block), jnp.float32),
-                        *plan.scratch_shapes(x_pad.dtype)],
+                        *plan.scratch_shapes(x.dtype)],
         compiler_params=compiler_params(),
         interpret=interpret,
         name="fused_separable",
-    )(x_pad, w_dw, w_pw)
+    )(x, w_dw, w_pw)
 
 
 def _fused_impl(x, w_dw, w_pw, stride, padding, tile_h, dw_act, act,
@@ -196,14 +201,13 @@ def _fused_impl(x, w_dw, w_pw, stride, padding, tile_h, dw_act, act,
     ci_pad = _round_up(c, ci_block)
     co_block = min(128, _round_up(c_out, 8))
     co_pad = _round_up(c_out, co_block)
-    xp = jnp.pad(x, (*geo.pads, (0, ci_pad - c)))
     wdp = jnp.pad(w_dw, ((0, 0), (0, 0), (0, ci_pad - c)))
     wpp = jnp.pad(w_pw, ((0, ci_pad - c), (0, co_pad - c_out)))
 
     out = fused_separable_pallas(
-        xp, wdp, wpp, stride=s, out_w=geo.out_wk, tile_h=geo.tile_h,
-        n_th=geo.n_th, ci_block=ci_block, co_block=co_block, dw_act=dw_act,
-        act=act, interpret=interpret, residency=residency,
+        strip_source(to_lanes(x), ci_block), wdp, wpp, geo=geo, stride=s,
+        ci_block=ci_block, co_block=co_block, dw_act=dw_act, act=act,
+        interpret=interpret, residency=residency,
     )
     return out[:, :geo.out_h, :geo.out_w, :c_out]
 

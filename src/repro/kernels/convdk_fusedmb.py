@@ -71,10 +71,17 @@ from ..core.perfmodel import (
     validate_collective,
 )
 from .common import (
+    LaunchGeometry,
+    as_layout,
     compiler_params,
     default_interpret,
+    in_lane_layout,
     launch_geometry,
     round_up as _round_up,
+    stages_in_place,
+    store_out,
+    strip_source,
+    to_lanes,
 )
 from .ref import _act_ref, fusedmb_ref
 from .staging import StripPlan, StripStream, strip_plan
@@ -117,6 +124,8 @@ def _fusedmb_kernel(x_ref, wconv_ref, *refs, plan: StripPlan, project: bool,
                 dense-conv sums across the c_in grid dim; when
                 ``project``, the projection accumulator (tile_h, out_w,
                 CO) f32 carrying partial sums across the c_mid grid dim;
+                (o_ref's block holds the image's columns only, out_w is
+                the computed width in whole sublanes)
                 when taps pack, the patches (n_groups, tile_h, out_w, CI)
                 f32; then the staging engine's refs.
 
@@ -203,7 +212,7 @@ def _fusedmb_kernel(x_ref, wconv_ref, *refs, plan: StripPlan, project: bool,
     def _finish():
         e = _act_ref(conv_ref[...], act)
         if not project:
-            o_ref[0] = e.astype(o_ref.dtype)
+            store_out(o_ref, e)
             return
         (proj_ref,) = proj_refs
         partial = jax.lax.dot_general(
@@ -223,70 +232,76 @@ def _fusedmb_kernel(x_ref, wconv_ref, *refs, plan: StripPlan, project: bool,
 
         @pl.when(cm == n_cm - 1)
         def _finalize():
-            o_ref[0] = proj_ref[...].astype(o_ref.dtype)
+            store_out(o_ref, proj_ref[...])
 
 
-def fusedmb_pallas(x_pad, w_conv, w_proj, *, k, c_in, stride, out_w, tile_h,
-                   n_th, ci_block, cm_block, co_block, act, interpret,
+def fusedmb_pallas(x, w_conv, w_proj, *, k, c_in, geo: LaunchGeometry,
+                   stride, ci_block, cm_block, co_block, act, interpret,
                    residency=DEFAULT_RESIDENCY):
-    """Raw single-pass launch over a pre-padded input.
+    """Raw single-pass launch over the block's activation.
 
-    x_pad  : (B, H_tot, W_pad, CI_pad), zero from lane ``c_in`` up
+    x      : (B, H, W, CI_pad), unpadded spatially (``geo`` places its
+             conv halo, which the staging engine builds in VMEM), zero
+             from lane ``c_in`` up
     w_conv : (n_groups, CI_pad, CM_pad), the ``k`` = (k_h, k_w) conv's
              tap planes packed by ``pack_conv_taps``
     w_proj : (CM_pad, CO_pad), or None: no projection (expansion ratio
              1), the grid has no c_mid dim and the output is act(conv)
              over CO_pad = CM_pad in blocks of ``cm_block``
-    returns (B, n_th*tile_h, out_w, CO_pad)
+    returns (B, out_h, out_w, CO_pad)
     """
-    b, h_tot, w_pad, ci_pad = x_pad.shape
+    b, h, w_in, ci_pad = x.shape
     k_h, k_w = k
     n_groups, _, cm_pad = w_conv.shape
+    tile_h, out_wk = geo.tile_h, geo.out_wk
     project = w_proj is not None
     if project:
         co_pad = w_proj.shape[1]
-        grid = (b, co_pad // co_block, n_th, cm_pad // cm_block,
+        grid = (b, co_pad // co_block, geo.n_th, cm_pad // cm_block,
                 ci_pad // ci_block)
     else:
         co_pad, co_block = cm_pad, cm_block
-        grid = (b, cm_pad // cm_block, n_th, ci_pad // ci_block)
+        grid = (b, cm_pad // cm_block, geo.n_th, ci_pad // ci_block)
     ci_dim = len(grid) - 1
 
     plan = strip_plan(
-        h_tot=h_tot, w_tot=w_pad, c_block=ci_block, tile_h=tile_h,
+        h_tot=geo.h_tot, w_tot=geo.w_tot, c_block=ci_block, tile_h=tile_h,
         grid=grid, window_dims=(0, 2, ci_dim), stride=stride, k_h=k_h,
-        residency=residency)
+        residency=residency, h_src=h, w_src=w_in, top=geo.top,
+        left=geo.left)
     kernel = functools.partial(
         _fusedmb_kernel, plan=plan, project=project, k_h=k_h, k_w=k_w,
-        c_in=c_in, stride=stride, tile_h=tile_h, out_w=out_w, act=act)
+        c_in=c_in, stride=stride, tile_h=tile_h, out_w=out_wk, act=act)
     # the conv's c_mid block: the last-but-one dim with a projection, the
     # output's channel block without one
     cm_dim = 3 if project else 1
     in_specs = [
-        plan.in_spec(lambda *g: (g[0], 0, 0, g[ci_dim])),
+        plan.in_spec(),
         pl.BlockSpec((n_groups, ci_block, cm_block),
                      lambda *g: (0, g[ci_dim], g[cm_dim])),
     ]
-    operands = [x_pad, w_conv]
-    scratch = [pltpu.VMEM((tile_h, out_w, cm_block), jnp.float32)]
+    operands = [x, w_conv]
+    scratch = [pltpu.VMEM((tile_h, out_wk, cm_block), jnp.float32)]
     if project:
         in_specs.append(pl.BlockSpec((cm_block, co_block),
                                      lambda bi, co, ti, cm, ci: (cm, co)))
         operands.append(w_proj)
-        scratch.append(pltpu.VMEM((tile_h, out_w, co_block), jnp.float32))
+        scratch.append(pltpu.VMEM((tile_h, out_wk, co_block), jnp.float32))
     if taps_per_pass(c_in, k_h * k_w) > 1:
-        scratch.append(pltpu.VMEM((n_groups, tile_h, out_w, ci_block),
+        scratch.append(pltpu.VMEM((n_groups, tile_h, out_wk, ci_block),
                                   jnp.float32))
     return pl.pallas_call(
         kernel,
         grid=grid,
         in_specs=in_specs,
+        # the image's pixels only: the last strip's block is clipped where
+        # tile_h does not divide out_h
         out_specs=pl.BlockSpec(
-            (1, tile_h, out_w, co_block),
+            (1, tile_h, geo.out_w, co_block),
             lambda bi, co, ti, *_: (bi, ti, 0, co)),
         out_shape=jax.ShapeDtypeStruct(
-            (b, n_th * tile_h, out_w, co_pad), x_pad.dtype),
-        scratch_shapes=[*scratch, *plan.scratch_shapes(x_pad.dtype)],
+            (b, geo.out_h, geo.out_w, co_pad), x.dtype),
+        scratch_shapes=[*scratch, *plan.scratch_shapes(x.dtype)],
         compiler_params=compiler_params(),
         interpret=interpret,
         name="fusedmb",
@@ -311,11 +326,16 @@ def _fusedmb_impl(x, w_conv, w_proj, stride, padding, tile_h, act, interpret,
     ``w_proj is None`` (expansion ratio 1) is a block with no projection:
     the output is act(conv) over c_out = c_mid, and under c_mid sharding
     each device's output is its own channel slice, with no collective.
+
+    ``x`` takes either layout, as in ``convdk_mbconv._mbconv_impl``: c_in
+    channels (read from ``w_conv``) or the chain's lane layout, which the
+    one-device output keeps.
     """
     validate_collective(collective)
-    b, h, w_in, c_in = x.shape
-    k_h, k_w, ci_w, c_mid = w_conv.shape
-    assert ci_w == c_in, (w_conv.shape, c_in)
+    b, h, w_in, c_x = x.shape
+    k_h, k_w, c_in, c_mid = w_conv.shape
+    lanes = in_lane_layout(x)
+    assert c_x in (c_in, _round_up(c_in, 128)), (x.shape, w_conv.shape)
     if w_proj is None:
         c_out = c_mid
     else:
@@ -323,45 +343,40 @@ def _fusedmb_impl(x, w_conv, w_proj, stride, padding, tile_h, act, interpret,
         assert w_proj.shape[0] == c_mid, (w_proj.shape, c_mid)
     s = stride
     geo = launch_geometry(h, w_in, k_h, k_w, s, padding, tile_h)
-    out_h, out_w = geo.out_h, geo.out_w
 
     ci_block = pick_channel_block(c_in)
     ci_pad = _round_up(c_in, ci_block)
     cm_block = pick_channel_block(c_mid)
     cm_pad = _round_up(c_mid, cm_block)
-    co_block = min(128, _round_up(c_out, 8))
+    co_block = pick_channel_block(c_out)
     co_pad = _round_up(c_out, co_block)
 
-    xp = jnp.pad(x, (*geo.pads, (0, ci_pad - c_in)))
     wconv_p = pack_conv_taps(w_conv, ci_pad, cm_pad)
     wproj_p = None if w_proj is None else jnp.pad(
         w_proj, ((0, cm_pad - c_mid), (0, co_pad - c_out)))
 
     out = fusedmb_pallas(
-        xp, wconv_p, wproj_p, k=(k_h, k_w), c_in=c_in, stride=s,
-        out_w=geo.out_wk, tile_h=geo.tile_h, n_th=geo.n_th,
-        ci_block=ci_block, cm_block=cm_block, co_block=co_block, act=act,
-        interpret=interpret, residency=residency)
+        strip_source(to_lanes(x), ci_block), wconv_p, wproj_p,
+        k=(k_h, k_w), c_in=c_in, stride=s, geo=geo, ci_block=ci_block,
+        cm_block=cm_block, co_block=co_block, act=act, interpret=interpret,
+        residency=residency)
+    if axis_name is None:
+        return as_layout(out, c_out, lanes)
     if w_proj is None:
-        return out[:, :out_h, :out_w, :c_out]
-    if axis_name is not None and collective == "psum_scatter":
+        return out[..., :c_out]
+    if collective == "psum_scatter":
         # layout-aware exit, same contract as MBConv pass 2: zero w_proj
         # columns pad a non-dividing c_out to ``scatter_width`` (their
         # partials are exactly zero), the wrapper slices them back.
         cw = scatter_width if scatter_width else c_out
-        out = out[:, :out_h, :out_w, :min(cw, out.shape[-1])]
+        out = out[..., :min(cw, out.shape[-1])]
         if out.shape[-1] < cw:
             out = jnp.pad(
                 out, ((0, 0), (0, 0), (0, 0), (0, cw - out.shape[-1])))
-        out = jax.lax.psum_scatter(out, axis_name,
-                                   scatter_dimension=3, tiled=True)
-    else:
-        out = out[:, :out_h, :out_w, :c_out]
-        if axis_name is not None:
-            # projection partials: each shard contracted only its c_mid
-            # slice
-            out = jax.lax.psum(out, axis_name)
-    return out
+        return jax.lax.psum_scatter(out, axis_name,
+                                    scatter_dimension=3, tiled=True)
+    # projection partials: each shard contracted only its c_mid slice
+    return jax.lax.psum(out[..., :c_out], axis_name)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))
@@ -381,11 +396,17 @@ def _fusedmb_fwd(x, w_conv, w_proj, stride, padding, tile_h, act, interpret,
 def _fusedmb_bwd(stride, padding, tile_h, act, interpret, residency, res, g):
     # Backward through the mathematically identical reference composition —
     # the single-pass kernel computes the same Fused-MBConv block, so the
-    # VJP is exact (same pattern as convdk_fused / convdk_mbconv).
+    # VJP is exact (same pattern as convdk_fused / convdk_mbconv).  A
+    # lane-layout input and output are the reference's narrow ones with a
+    # zero tail.
     x, w_conv, w_proj = res
+    c_in, c_mid = w_conv.shape[2:]
+    c_out = c_mid if w_proj is None else w_proj.shape[1]
+    lanes = in_lane_layout(x)
     _, vjp = jax.vjp(
-        lambda x_, wc_, wp_: fusedmb_ref(
-            x_, wc_, wp_, stride=stride, padding=padding, act=act),
+        lambda x_, wc_, wp_: as_layout(fusedmb_ref(
+            x_[..., :c_in], wc_, wp_, stride=stride, padding=padding,
+            act=act), c_out, lanes),
         x, w_conv, w_proj,
     )
     return vjp(g)
@@ -420,7 +441,9 @@ def convdk_fusedmb_fused(
     """Single-pass fused Fused-MBConv block via one ConvDK Pallas kernel
     (differentiable).  No residual add — the model layer owns that.
 
-    x      : (B, H, W, C_in) NHWC
+    x      : (B, H, W, C_in) NHWC, or the block chain's lane layout: C_in
+             rounded up to whole 128-lane groups with a zero tail
+             (``common.in_lane_layout``)
     w_conv : (k_h, k_w, C_in, C_mid) HWIO dense conv (the collapsed
              expand+DW of EfficientNet-V2's fused stages)
     w_proj : (C_mid, C_out) projection PW (linear), or None: no
@@ -429,14 +452,18 @@ def convdk_fusedmb_fused(
     act    : conv activation (EfficientNet-V2 uses silu)
     residency : "resident" | "strip_dma" | "strip_dma_db" (default) — how
              the input stream is staged (``kernels.staging``).
-    Returns (B, H', W', C_out).  The expanded (C_mid) tensor never touches
-    HBM; there is no SE stage and no second pass.
+    Returns (B, H', W', C_out), or (B, H', W', round_up(C_out, 128)) with
+    a zero tail for an input in the lane layout.  The expanded (C_mid)
+    tensor never touches HBM; there is no SE stage and no second pass.
 
     A call with ``w_proj=None`` ticks ``kernels.fusedmb.no_project`` once:
     once per launch site a jitted caller traces (two blocks of one shape
     share a compiled kernel but are two launches), once per launch when
     called eagerly.  A call whose kernel packs taps (``taps_per_pass`` of
-    C_in over 1) ticks ``kernels.fusedmb.tap_packed`` the same way.
+    C_in over 1) ticks ``kernels.fusedmb.tap_packed`` the same way, and
+    a call whose kernel stages its input as it is, building the whole
+    conv halo in VMEM (``common.stages_in_place``), ticks
+    ``kernels.staging.halo_in_vmem``.
     """
     if interpret is None:
         interpret = default_interpret()
@@ -444,8 +471,11 @@ def convdk_fusedmb_fused(
         residency = DEFAULT_RESIDENCY
     if w_proj is None:
         telemetry.counter("kernels.fusedmb.no_project")
-    if taps_per_pass(x.shape[-1], w_conv.shape[0] * w_conv.shape[1]) > 1:
+    k_h, k_w, c_in, _ = w_conv.shape
+    if taps_per_pass(c_in, k_h * k_w) > 1:
         telemetry.counter("kernels.fusedmb.tap_packed")
+    if stages_in_place(x):
+        telemetry.counter("kernels.staging.halo_in_vmem")
     return _fusedmb_fused(x, w_conv, w_proj, stride=stride, padding=padding,
                           tile_h=tile_h, act=act, interpret=interpret,
                           residency=residency)
@@ -473,13 +503,15 @@ def convdk_fusedmb_staged(
     ``convdk_fusedmb_fused`` eliminates.  ``tile_h`` is accepted for
     call-site symmetry with the fused entry; the staged rendering has no
     strip structure.  ``w_proj=None``: no projection, the activation is
-    the output.
+    the output.  Takes and returns either layout, as the fused entry does.
     """
     del tile_h
     if interpret is None:
         interpret = default_interpret()
+    lanes = in_lane_layout(x)
     e = jax.lax.conv_general_dilated(
-        x.astype(jnp.float32), w_conv.astype(jnp.float32),
+        x[..., :w_conv.shape[2]].astype(jnp.float32),
+        w_conv.astype(jnp.float32),
         window_strides=(stride, stride),
         padding=padding,
         dimension_numbers=("NHWC", "HWIO", "NHWC"),
@@ -487,4 +519,4 @@ def convdk_fusedmb_staged(
     out = _act_ref(e, act)
     if w_proj is not None:
         out = jnp.einsum("bhwc,cd->bhwd", out, w_proj.astype(jnp.float32))
-    return out.astype(x.dtype)
+    return as_layout(out.astype(x.dtype), out.shape[-1], lanes)

@@ -72,10 +72,17 @@ from ..core.perfmodel import (
     validate_collective,
 )
 from .common import (
+    LaunchGeometry,
+    as_layout,
     compiler_params,
     default_interpret,
+    in_lane_layout,
     launch_geometry,
     round_up as _round_up,
+    stages_in_place,
+    store_out,
+    strip_source,
+    to_lanes,
 )
 from .ref import _act_ref, mbconv_ref
 from .staging import StripPlan, StripStream, StripWindow, strip_plan
@@ -243,7 +250,7 @@ def _mbconv_pass2_recompute_kernel(x_ref, *refs, plan: StripPlan,
 
         @pl.when(cm == n_cm - 1)
         def _finalize():
-            o_ref[0] = proj_ref[...].astype(o_ref.dtype)
+            store_out(o_ref, proj_ref[...])
 
     _strip_dw(win, wexp_ref, wdw_ref, acc_refs, ci_dim=4, exp_act=exp_act,
               on_dw=project, dw_act=dw_act, k_h=k_h, k_w=k_w, stride=stride,
@@ -285,20 +292,22 @@ def _mbconv_pass2_retain_kernel(dw_ref, *rest, plan: StripPlan, tile_h,
 
     @pl.when(cm == n_cm - 1)
     def _finalize():
-        o_ref[0] = proj_ref[...].astype(o_ref.dtype)
+        store_out(o_ref, proj_ref[...])
 
 
-def _input_stream(x_pad, w_exp, w_dw, head, cm_dim, *, ci_block, cm_block,
-                  tile_h, stride, residency):
+def _input_stream(x, w_exp, w_dw, head, cm_dim, *, geo: LaunchGeometry,
+                  stride, ci_block, cm_block, residency):
     """Grid, staged-input plan, and the (x, [w_exp,] w_dw) in_specs and
     operands that pass 1 and recompute pass 2 share.
 
-    ``head`` is the kernel's grid without the c_in dim and ``cm_dim`` its
-    c_mid dim.  A real expand appends the c_in reduction dim, which picks
-    the window's channel block.  ``w_exp=None`` (expansion ratio 1) adds no
-    dim: the window's channel block is the c_mid block the taps read.
+    ``x`` is the block's activation in the lane layout; the plan builds
+    its conv halo in VMEM.  ``head`` is the kernel's grid without the c_in
+    dim and ``cm_dim`` its c_mid dim.  A real expand appends the c_in
+    reduction dim, which picks the window's channel block.  ``w_exp=None``
+    (expansion ratio 1) adds no dim: the window's channel block is the
+    c_mid block the taps read.
     """
-    b, h_tot, w_pad, ci_pad = x_pad.shape
+    b, h, w_in, ci_pad = x.shape
     k_h, k_w, cm_pad = w_dw.shape
     if w_exp is not None:
         grid, chan_dim = (*head, ci_pad // ci_block), len(head)
@@ -308,11 +317,12 @@ def _input_stream(x_pad, w_exp, w_dw, head, cm_dim, *, ci_block, cm_block,
         telemetry.counter("kernels.mbconv.identity_expand")
         grid, chan_dim = tuple(head), cm_dim
     plan = strip_plan(
-        h_tot=h_tot, w_tot=w_pad, c_block=ci_block, tile_h=tile_h,
-        grid=grid, window_dims=(0, 2, chan_dim), stride=stride, k_h=k_h,
-        residency=residency)
-    in_specs = [plan.in_spec(lambda *g: (g[0], 0, 0, g[chan_dim]))]
-    operands = [x_pad]
+        h_tot=geo.h_tot, w_tot=geo.w_tot, c_block=ci_block,
+        tile_h=geo.tile_h, grid=grid, window_dims=(0, 2, chan_dim),
+        stride=stride, k_h=k_h, residency=residency, h_src=h, w_src=w_in,
+        top=geo.top, left=geo.left)
+    in_specs = [plan.in_spec()]
+    operands = [x]
     if w_exp is not None:
         in_specs.append(pl.BlockSpec((ci_block, cm_block),
                                      lambda *g: (g[chan_dim], g[cm_dim])))
@@ -323,37 +333,39 @@ def _input_stream(x_pad, w_exp, w_dw, head, cm_dim, *, ci_block, cm_block,
     return grid, plan, in_specs, operands
 
 
-def _expand_scratch(w_exp, plan: StripPlan, w_pad, cm_block) -> list:
+def _expand_scratch(w_exp, plan: StripPlan, cm_block) -> list:
     """The f32 expand accumulator — none without an expand."""
     if w_exp is None:
         return []
-    return [pltpu.VMEM((plan.in_rows, w_pad, cm_block), jnp.float32)]
+    return [pltpu.VMEM((plan.in_rows, plan.w_tot, cm_block), jnp.float32)]
 
 
-def mbconv_pass1_pallas(x_pad, w_exp, w_dw, *, stride, out_w, out_h, valid_w,
-                        tile_h, n_th, ci_block, cm_block, exp_act, dw_act,
-                        retain, interpret, se=True,
-                        residency=DEFAULT_RESIDENCY):
+def mbconv_pass1_pallas(x, w_exp, w_dw, *, geo: LaunchGeometry, stride,
+                        ci_block, cm_block, exp_act, dw_act, retain,
+                        interpret, se=True, residency=DEFAULT_RESIDENCY):
     """Raw pass-1 launch: (pool_sums-or-None, dw_retained-or-None).
 
-    ``out_w`` is the launched (sublane-covered) output width; the pool
-    counts only the first ``valid_w`` columns and ``out_h`` rows.
+    ``x`` is the block's activation in the lane layout, unpadded; ``geo``
+    places its conv halo.  The kernel computes ``geo.out_wk`` (whole
+    sublane) output columns; the pool counts only the first ``out_w``
+    columns and ``out_h`` rows.
 
     ``se=False`` drops the pool output (and its VMEM accumulator) from the
     launch entirely — an se=off retain pass writes only the DW tensor.
     ``w_exp=None`` launches the block with no expand stage.
     """
     assert se or retain, "se=off + recompute has no pass 1 at all"
-    b, _, w_pad, _ = x_pad.shape
+    b = x.shape[0]
     cm_pad = w_dw.shape[-1]
+    tile_h, out_wk = geo.tile_h, geo.out_wk
     grid, plan, in_specs, operands = _input_stream(
-        x_pad, w_exp, w_dw, (b, cm_pad // cm_block, n_th), 1,
-        ci_block=ci_block, cm_block=cm_block, tile_h=tile_h, stride=stride,
+        x, w_exp, w_dw, (b, cm_pad // cm_block, geo.n_th), 1, geo=geo,
+        stride=stride, ci_block=ci_block, cm_block=cm_block,
         residency=residency)
     kernel = functools.partial(
         _mbconv_pass1_kernel, plan=plan, expand=w_exp is not None,
         k_h=w_dw.shape[0], k_w=w_dw.shape[1], stride=stride, tile_h=tile_h,
-        out_w=out_w, out_h=out_h, valid_w=valid_w, exp_act=exp_act,
+        out_w=out_wk, out_h=geo.out_h, valid_w=geo.out_w, exp_act=exp_act,
         dw_act=dw_act, se=se, retain=retain)
     out_shape = []
     out_specs = []
@@ -363,9 +375,9 @@ def mbconv_pass1_pallas(x_pad, w_exp, w_dw, *, stride, out_w, out_h, valid_w,
                                       lambda bi, cm, ti, *_: (bi, 0, cm)))
     if retain:
         out_shape.append(jax.ShapeDtypeStruct(
-            (b, n_th * tile_h, out_w, cm_pad), x_pad.dtype))
+            (b, geo.n_th * tile_h, out_wk, cm_pad), x.dtype))
         out_specs.append(pl.BlockSpec(
-            (1, tile_h, out_w, cm_block),
+            (1, tile_h, out_wk, cm_block),
             lambda bi, cm, ti, *_: (bi, ti, 0, cm)))
     outs = pl.pallas_call(
         kernel,
@@ -374,8 +386,8 @@ def mbconv_pass1_pallas(x_pad, w_exp, w_dw, *, stride, out_w, out_h, valid_w,
         out_specs=out_specs,
         out_shape=out_shape,
         scratch_shapes=[
-            *_expand_scratch(w_exp, plan, w_pad, cm_block),
-            *plan.scratch_shapes(x_pad.dtype)],
+            *_expand_scratch(w_exp, plan, cm_block),
+            *plan.scratch_shapes(x.dtype)],
         compiler_params=compiler_params(),
         interpret=interpret,
         name="mbconv_pass1",
@@ -386,26 +398,37 @@ def mbconv_pass1_pallas(x_pad, w_exp, w_dw, *, stride, out_w, out_h, valid_w,
     return pool, dw_ret
 
 
-def mbconv_pass2_recompute_pallas(x_pad, w_exp, w_dw, scale, w_proj, *,
-                                  stride, out_w, tile_h, n_th, ci_block,
+def _output(geo: LaunchGeometry, b, co_pad, co_block, dtype):
+    """(out_spec, out_shape) of a pass-2 launch: the image's ``out_h`` x
+    ``out_w`` pixels in the lane layout, no cover — the last strip's
+    block is clipped where ``tile_h`` does not divide ``out_h``."""
+    return (pl.BlockSpec((1, geo.tile_h, geo.out_w, co_block),
+                         lambda bi, co, ti, *_: (bi, ti, 0, co)),
+            jax.ShapeDtypeStruct((b, geo.out_h, geo.out_w, co_pad), dtype))
+
+
+def mbconv_pass2_recompute_pallas(x, w_exp, w_dw, scale, w_proj, *,
+                                  geo: LaunchGeometry, stride, ci_block,
                                   cm_block, co_block, exp_act, dw_act,
                                   interpret, residency=DEFAULT_RESIDENCY):
     """``scale=None`` launches the se=off variant: no gate input, no gate
     multiply — the no-SE block pays zero scale bytes.  ``w_exp=None``
-    launches the block with no expand stage."""
+    launches the block with no expand stage.  Returns (B, out_h, out_w,
+    CO_pad)."""
     se = scale is not None
-    b, _, w_pad, _ = x_pad.shape
+    b = x.shape[0]
     cm_pad = w_dw.shape[-1]
     co_pad = w_proj.shape[1]
+    tile_h, out_wk = geo.tile_h, geo.out_wk
     grid, plan, in_specs, operands = _input_stream(
-        x_pad, w_exp, w_dw,
-        (b, co_pad // co_block, n_th, cm_pad // cm_block), 3,
-        ci_block=ci_block, cm_block=cm_block, tile_h=tile_h, stride=stride,
+        x, w_exp, w_dw,
+        (b, co_pad // co_block, geo.n_th, cm_pad // cm_block), 3, geo=geo,
+        stride=stride, ci_block=ci_block, cm_block=cm_block,
         residency=residency)
     kernel = functools.partial(
         _mbconv_pass2_recompute_kernel, plan=plan, expand=w_exp is not None,
         k_h=w_dw.shape[0], k_w=w_dw.shape[1], stride=stride, tile_h=tile_h,
-        out_w=out_w, exp_act=exp_act, dw_act=dw_act, se=se)
+        out_w=out_wk, exp_act=exp_act, dw_act=dw_act, se=se)
     if se:
         in_specs.append(pl.BlockSpec((1, 1, cm_block),
                                      lambda bi, co, ti, cm, *_: (bi, 0, cm)))
@@ -413,19 +436,17 @@ def mbconv_pass2_recompute_pallas(x_pad, w_exp, w_dw, scale, w_proj, *,
     in_specs.append(pl.BlockSpec((cm_block, co_block),
                                  lambda bi, co, ti, cm, *_: (cm, co)))
     operands.append(w_proj)
+    out_spec, out_shape = _output(geo, b, co_pad, co_block, x.dtype)
     return pl.pallas_call(
         kernel,
         grid=grid,
         in_specs=in_specs,
-        out_specs=pl.BlockSpec(
-            (1, tile_h, out_w, co_block),
-            lambda bi, co, ti, *_: (bi, ti, 0, co)),
-        out_shape=jax.ShapeDtypeStruct(
-            (b, n_th * tile_h, out_w, co_pad), x_pad.dtype),
+        out_specs=out_spec,
+        out_shape=out_shape,
         scratch_shapes=[
-            *_expand_scratch(w_exp, plan, w_pad, cm_block),
-            pltpu.VMEM((tile_h, out_w, co_block), jnp.float32),
-            *plan.scratch_shapes(x_pad.dtype),
+            *_expand_scratch(w_exp, plan, cm_block),
+            pltpu.VMEM((tile_h, out_wk, co_block), jnp.float32),
+            *plan.scratch_shapes(x.dtype),
         ],
         compiler_params=compiler_params(),
         interpret=interpret,
@@ -433,22 +454,24 @@ def mbconv_pass2_recompute_pallas(x_pad, w_exp, w_dw, scale, w_proj, *,
     )(*operands)
 
 
-def mbconv_pass2_retain_pallas(dw_ret, scale, w_proj, *, out_w, tile_h,
-                               n_th, cm_block, co_block, interpret,
-                               residency=DEFAULT_RESIDENCY):
+def mbconv_pass2_retain_pallas(dw_ret, scale, w_proj, *,
+                               geo: LaunchGeometry, cm_block, co_block,
+                               interpret, residency=DEFAULT_RESIDENCY):
     b = dw_ret.shape[0]
     cm_pad = dw_ret.shape[-1]
     co_pad = w_proj.shape[1]
-    grid = (b, co_pad // co_block, n_th, cm_pad // cm_block)
+    tile_h, out_wk = geo.tile_h, geo.out_wk
+    grid = (b, co_pad // co_block, geo.n_th, cm_pad // cm_block)
 
     # The retained-DW re-read: non-overlapping tile_h-row blocks (k_h=1,
     # stride=1 geometry) — the double-buffered DMA stream of the tentpole.
     se = scale is not None
     plan = strip_plan(
-        h_tot=dw_ret.shape[1], w_tot=out_w, c_block=cm_block, tile_h=tile_h,
-        grid=grid, window_dims=(0, 2, 3), residency=residency)
+        h_tot=dw_ret.shape[1], w_tot=out_wk, c_block=cm_block,
+        tile_h=tile_h, grid=grid, window_dims=(0, 2, 3),
+        residency=residency)
     kernel = functools.partial(_mbconv_pass2_retain_kernel, plan=plan,
-                               tile_h=tile_h, out_w=out_w, se=se)
+                               tile_h=tile_h, out_w=out_wk, se=se)
     in_specs = [plan.in_spec(lambda bi, co, ti, cm: (bi, ti, 0, cm))]
     operands = [dw_ret]
     if se:
@@ -458,16 +481,14 @@ def mbconv_pass2_retain_pallas(dw_ret, scale, w_proj, *, out_w, tile_h,
     in_specs.append(pl.BlockSpec((cm_block, co_block),
                                  lambda bi, co, ti, cm: (cm, co)))
     operands.append(w_proj)
+    out_spec, out_shape = _output(geo, b, co_pad, co_block, dw_ret.dtype)
     return pl.pallas_call(
         kernel,
         grid=grid,
         in_specs=in_specs,
-        out_specs=pl.BlockSpec(
-            (1, tile_h, out_w, co_block),
-            lambda bi, co, ti, cm: (bi, ti, 0, co)),
-        out_shape=jax.ShapeDtypeStruct(
-            (b, n_th * tile_h, out_w, co_pad), dw_ret.dtype),
-        scratch_shapes=[pltpu.VMEM((tile_h, out_w, co_block), jnp.float32),
+        out_specs=out_spec,
+        out_shape=out_shape,
+        scratch_shapes=[pltpu.VMEM((tile_h, out_wk, co_block), jnp.float32),
                         *plan.scratch_shapes(dw_ret.dtype)],
         compiler_params=compiler_params(),
         interpret=interpret,
@@ -512,31 +533,45 @@ def _mbconv_impl(x, w_exp, w_dw, w_se1, b_se1, w_se2, b_se2, w_proj, stride,
 
     ``w_exp is None`` (with ``exp_act=None``) is a block with no expand
     convolution: the kernels' taps read the staged input window directly.
+
+    ``x`` is unpadded spatially; the kernels stage straight from it and
+    build the conv halo in VMEM.  Its channels are either the block's
+    c_in (read from the weights) or the lane layout the block chain
+    carries (``common.in_lane_layout``: c_in rounded up to whole 128-lane
+    groups, zero from c_in up).  On one device the output keeps the
+    input's layout: c_out channels, or round_up(c_out, 128) with a zero
+    tail (the padded projection columns are zero).
     """
     validate_collective(collective)
     se = w_se1 is not None
-    b, h, w_in, c_in = x.shape
     k_h, k_w, c_mid = w_dw.shape
     if w_exp is None:
-        assert exp_act is None and c_in == c_mid, (exp_act, c_in, c_mid)
+        assert exp_act is None, exp_act
+        c_in = c_mid
     else:
+        c_in = w_exp.shape[0]
         assert w_exp.shape == (c_in, c_mid), (w_exp.shape, c_in, c_mid)
+    b, h, w_in, c_x = x.shape
+    lanes = in_lane_layout(x)
+    assert c_x in (c_in, _round_up(c_in, 128)), (x.shape, c_in)
     c_out = w_proj.shape[1]
     assert w_proj.shape[0] == c_mid, (w_proj.shape, c_mid)
     assert mode in ("retain", "recompute"), mode
     s = stride
 
     geo = launch_geometry(h, w_in, k_h, k_w, s, padding, tile_h)
-    out_h, out_w, tile_h, n_th = geo.out_h, geo.out_w, geo.tile_h, geo.n_th
+    out_h, out_w = geo.out_h, geo.out_w
 
     ci_block = pick_channel_block(c_in)
     ci_pad = _round_up(c_in, ci_block)
     cm_block = pick_channel_block(c_mid)
     cm_pad = _round_up(c_mid, cm_block)
-    co_block = min(128, _round_up(c_out, 8))
+    co_block = pick_channel_block(c_out)
     co_pad = _round_up(c_out, co_block)
 
-    xp = jnp.pad(x, (*geo.pads, (0, ci_pad - c_in)))
+    # a caller's narrow array gets its lane tail here; the block chain
+    # hands the kernels their own layout and nothing is copied
+    xl = strip_source(to_lanes(x), ci_block)
     wexp_p = (None if w_exp is None else
               jnp.pad(w_exp, ((0, ci_pad - c_in), (0, cm_pad - c_mid))))
     wdw_p = jnp.pad(w_dw, ((0, 0), (0, 0), (0, cm_pad - c_mid)))
@@ -544,8 +579,7 @@ def _mbconv_impl(x, w_exp, w_dw, w_se1, b_se1, w_se2, b_se2, w_proj, stride,
 
     if se or mode == "retain":
         pool, dw_ret = mbconv_pass1_pallas(
-            xp, wexp_p, wdw_p, stride=s, out_w=geo.out_wk, out_h=out_h,
-            valid_w=out_w, tile_h=tile_h, n_th=n_th, ci_block=ci_block,
+            xl, wexp_p, wdw_p, geo=geo, stride=s, ci_block=ci_block,
             cm_block=cm_block, exp_act=exp_act, dw_act=dw_act,
             retain=(mode == "retain"), interpret=interpret, se=se,
             residency=residency)
@@ -571,16 +605,17 @@ def _mbconv_impl(x, w_exp, w_dw, w_se1, b_se1, w_se2, b_se2, w_proj, stride,
 
     if mode == "retain":
         out = mbconv_pass2_retain_pallas(
-            dw_ret, scale, wproj_p, out_w=geo.out_wk, tile_h=tile_h,
-            n_th=n_th, cm_block=cm_block, co_block=co_block,
-            interpret=interpret, residency=residency)
+            dw_ret, scale, wproj_p, geo=geo, cm_block=cm_block,
+            co_block=co_block, interpret=interpret, residency=residency)
     else:
         out = mbconv_pass2_recompute_pallas(
-            xp, wexp_p, wdw_p, scale, wproj_p, stride=s, out_w=geo.out_wk,
-            tile_h=tile_h, n_th=n_th, ci_block=ci_block, cm_block=cm_block,
-            co_block=co_block, exp_act=exp_act, dw_act=dw_act,
-            interpret=interpret, residency=residency)
-    if axis_name is not None and collective == "psum_scatter":
+            xl, wexp_p, wdw_p, scale, wproj_p, geo=geo, stride=s,
+            ci_block=ci_block, cm_block=cm_block, co_block=co_block,
+            exp_act=exp_act, dw_act=dw_act, interpret=interpret,
+            residency=residency)
+    if axis_name is None:
+        return as_layout(out, c_out, lanes)
+    if collective == "psum_scatter":
         # reduce-scatter over the channel dim: (mp-1)/mp words per
         # reduced word instead of the ring's 2*(mp-1)/mp, and this
         # shard keeps only its channel slice — the layout-aware exit.
@@ -589,19 +624,14 @@ def _mbconv_impl(x, w_exp, w_dw, w_se1, b_se1, w_se2, b_se2, w_proj, stride,
         # columns, so their partials are exactly zero and the wrapper
         # slices them back off the gathered-global view.
         cw = scatter_width if scatter_width else c_out
-        out = out[:, :out_h, :out_w, :min(cw, out.shape[-1])]
+        out = out[..., :min(cw, out.shape[-1])]
         if out.shape[-1] < cw:
             out = jnp.pad(
                 out, ((0, 0), (0, 0), (0, 0), (0, cw - out.shape[-1])))
-        out = jax.lax.psum_scatter(out, axis_name,
-                                   scatter_dimension=3, tiled=True)
-    else:
-        out = out[:, :out_h, :out_w, :c_out]
-        if axis_name is not None:
-            # projection partials: each shard contracted only its c_mid
-            # slice
-            out = jax.lax.psum(out, axis_name)
-    return out
+        return jax.lax.psum_scatter(out, axis_name,
+                                    scatter_dimension=3, tiled=True)
+    # projection partials: each shard contracted only its c_mid slice
+    return jax.lax.psum(out[..., :c_out], axis_name)
 
 
 @functools.partial(jax.custom_vjp,
@@ -631,11 +661,16 @@ def _mbconv_bwd(stride, padding, tile_h, mode, exp_act, dw_act, interpret,
     # exact (same pattern as convdk_fused's VJP).  mbconv_ref skips the SE
     # stage for w_se1=None and the expand for w_exp=None, matching the
     # kernel paths; those params' cotangents come back as None there, as
-    # custom_vjp expects.
+    # custom_vjp expects.  A lane-layout input and output are the
+    # reference's narrow ones with a zero tail.
+    x, w_exp, w_dw, *_, w_proj = res
+    c_in = w_dw.shape[-1] if w_exp is None else w_exp.shape[0]
+    lanes = in_lane_layout(x)
     _, vjp = jax.vjp(
-        lambda *p: mbconv_ref(*p, stride=stride, padding=padding,
-                              exp_act=exp_act, dw_act=dw_act,
-                              se_act=se_act, gate_act=gate_act),
+        lambda x_, *p: as_layout(
+            mbconv_ref(x_[..., :c_in], *p, stride=stride, padding=padding,
+                       exp_act=exp_act, dw_act=dw_act, se_act=se_act,
+                       gate_act=gate_act), w_proj.shape[1], lanes),
         *res,
     )
     return vjp(g)
@@ -650,6 +685,14 @@ _mbconv_op.defvjp(_mbconv_fwd, _mbconv_bwd)
                      "dw_act", "se_act", "gate_act", "interpret",
                      "residency"),
 )
+def _mbconv_fused(x, w_exp, w_dw, w_se1, b_se1, w_se2, b_se2, w_proj, *,
+                  stride, padding, tile_h, mode, exp_act, dw_act, se_act,
+                  gate_act, interpret, residency):
+    return _mbconv_op(x, w_exp, w_dw, w_se1, b_se1, w_se2, b_se2, w_proj,
+                      stride, padding, tile_h, mode, exp_act, dw_act,
+                      interpret, residency, se_act, gate_act)
+
+
 def convdk_mbconv_fused(
     x: jax.Array,
     w_exp: Optional[jax.Array],
@@ -674,7 +717,9 @@ def convdk_mbconv_fused(
     """Two-pass fused MBConv block via the ConvDK Pallas kernels
     (differentiable).  No residual add — the model layer owns that.
 
-    x      : (B, H, W, C_in) NHWC
+    x      : (B, H, W, C_in) NHWC, or the block chain's lane layout: C_in
+             rounded up to whole 128-lane groups with a zero tail
+             (``common.in_lane_layout``)
     w_exp  : (C_in, C_mid) expand PW, or ``None`` with ``exp_act=None``
              for expansion ratio 1 (no expand convolution: the depthwise
              taps act on ``x`` itself, ``C_mid == C_in``)
@@ -691,15 +736,26 @@ def convdk_mbconv_fused(
              EfficientNet, (relu, hard_sigmoid) for MobileNet-V3.
     residency : "resident" | "strip_dma" | "strip_dma_db" (default) — how
              the input / retained-DW streams are staged (``kernels.staging``).
-    Returns (B, H', W', C_out).
+    Returns (B, H', W', C_out), or (B, H', W', round_up(C_out, 128)) with
+    a zero tail for an input in the lane layout.
+
+    A call whose kernels stage its input as it is, building the whole
+    conv halo in VMEM (``common.stages_in_place``: the lane layout, and
+    no width cover), ticks ``kernels.staging.halo_in_vmem`` once: once per
+    launch site a jitted caller traces, once per launch when called
+    eagerly.
     """
     if interpret is None:
         interpret = default_interpret()
     if residency is None:
         residency = DEFAULT_RESIDENCY
-    return _mbconv_op(x, w_exp, w_dw, w_se1, b_se1, w_se2, b_se2, w_proj,
-                      stride, padding, tile_h, mode, exp_act, dw_act,
-                      interpret, residency, se_act, gate_act)
+    if stages_in_place(x):
+        telemetry.counter("kernels.staging.halo_in_vmem")
+    return _mbconv_fused(x, w_exp, w_dw, w_se1, b_se1, w_se2, b_se2, w_proj,
+                         stride=stride, padding=padding, tile_h=tile_h,
+                         mode=mode, exp_act=exp_act, dw_act=dw_act,
+                         se_act=se_act, gate_act=gate_act,
+                         interpret=interpret, residency=residency)
 
 
 @functools.partial(
@@ -732,13 +788,16 @@ def convdk_mbconv_staged(
     gate -> HBM -> projection einsum: the DW tensor round-trips through HBM
     exactly as the paper's weight-stationary baseline, which is what
     ``convdk_mbconv_fused`` eliminates.  Kept as the reference executable
-    for fused-vs-staged numerics and traffic comparisons.
+    for fused-vs-staged numerics and traffic comparisons.  Takes and
+    returns either layout, as the fused entry does.
     """
     from .ops import convdk_depthwise2d
 
     if interpret is None:
         interpret = default_interpret()
-    e = x.astype(jnp.float32)
+    c_in = w_dw.shape[-1] if w_exp is None else w_exp.shape[0]
+    lanes = in_lane_layout(x)
+    e = x[..., :c_in].astype(jnp.float32)
     if w_exp is not None:
         e = jnp.einsum("bhwc,cd->bhwd", e, w_exp.astype(jnp.float32))
     e = _act_ref(e, exp_act)
@@ -754,4 +813,4 @@ def convdk_mbconv_staged(
                         + b_se2.astype(jnp.float32), gate_act)
         d = d * gate[:, None, None, :]
     out = jnp.einsum("bhwc,cd->bhwd", d, w_proj.astype(jnp.float32))
-    return out.astype(x.dtype)
+    return as_layout(out.astype(x.dtype), w_proj.shape[1], lanes)

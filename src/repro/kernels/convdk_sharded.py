@@ -69,7 +69,7 @@ from ..core.perfmodel import (
     scatter_c_out,
     validate_layout,
 )
-from .common import default_interpret
+from .common import as_layout, default_interpret, in_lane_layout
 from .convdk_fused import _fused_impl
 from .convdk_fusedmb import _fusedmb_impl, taps_per_pass
 from .convdk_mbconv import _mbconv_impl
@@ -536,9 +536,15 @@ def convdk_mbconv_fused_sharded(
                                   exp_act, dw_act, interpret, residency,
                                   collective, in_layout, se_act, gate_act,
                                   se)
+    # the shards take the block's narrow channels: a lane-layout arrival
+    # is sliced at the shard boundary and the output padded back
+    lanes = in_lane_layout(x)
+    x = x[..., :w_dw.shape[-1] if w_exp is None else w_exp.shape[0]]
     if se:
-        return entry(x, w_exp, w_dw, w_se1, b_se1, w_se2, b_se2, w_proj)
-    return entry(x, w_exp, w_dw, w_proj)
+        out = entry(x, w_exp, w_dw, w_se1, b_se1, w_se2, b_se2, w_proj)
+    else:
+        out = entry(x, w_exp, w_dw, w_proj)
+    return as_layout(out, w_proj.shape[1], lanes)
 
 
 # ---------------------------------------------------------------------------
@@ -687,8 +693,14 @@ def convdk_fusedmb_fused_sharded(
         telemetry.counter("kernels.fusedmb.no_project")
     else:
         telemetry.counter(f"sharded.collective.{collective}")
-    if taps_per_pass(x.shape[-1], w_conv.shape[0] * w_conv.shape[1]) > 1:
+    k_h, k_w, c_in, c_mid = w_conv.shape
+    if taps_per_pass(c_in, k_h * k_w) > 1:
         telemetry.counter("kernels.fusedmb.tap_packed")
-    return _fusedmb_sharded_entry(mesh, stride, padding, tile_h, act,
-                                  interpret, residency, collective,
-                                  in_layout)(x, w_conv, w_proj)
+    # the shards take the block's narrow channels: a lane-layout arrival
+    # is sliced at the shard boundary and the output padded back
+    lanes = in_lane_layout(x)
+    out = _fusedmb_sharded_entry(mesh, stride, padding, tile_h, act,
+                                 interpret, residency, collective,
+                                 in_layout)(x[..., :c_in], w_conv, w_proj)
+    return as_layout(out, c_mid if w_proj is None else w_proj.shape[1],
+                     lanes)

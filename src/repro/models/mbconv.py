@@ -303,7 +303,10 @@ def mbconv_block(
     blocks share VMEM while their stages overlap) and caches under its
     own ``ov=`` key segment.
 
-    x: (B, H, W, C_in) NHWC -> (B, H', W', C_out).
+    x: (B, H, W, C_in) NHWC -> (B, H', W', C_out); an ``x`` in the lane
+    layout the network's chain carries (``kernels.common.in_lane_layout``:
+    C_in rounded up to whole 128-lane groups, zero tail) gives an output
+    in it.  C_in is read from the params.
     """
     from ..configs.base import _warn_once, kernel_config, resolve_pin
     legacy_call = isinstance(x, dict)
@@ -330,8 +333,8 @@ def mbconv_block(
 
     validate_layout(in_layout)
     eff = resolve_pin(cfg, pin, family="mbconv")
-    c_in = x.shape[-1]
     c_mid = params["dw"].shape[-1]
+    c_in = params["exp"].shape[0] if "exp" in params else c_mid
     c_out = params["proj"].shape[-1]
     # the param tree IS the SE contract: absent SE FCs mean a no-SE block
     # (MobileNet-V3's early/middle stages) — the kernels then skip the
@@ -346,7 +349,6 @@ def mbconv_block(
     else:
         # expansion ratio 1 (MBConv1): no expand convolution and no expand
         # activation — the kernels' taps read the block input directly
-        assert c_mid == c_in, (c_mid, c_in)
         w_exp, eff_exp_act = None, None
 
     sharded = (mesh is not None and eff.shard and eff.fused
@@ -464,7 +466,8 @@ def fusedmb_block(
     Params without ``"proj"`` are the expansion-1 block, which has no
     projection: the kernels get ``w_proj=None`` and compute act(conv).
 
-    x: (B, H, W, C_in) NHWC -> (B, H', W', C_out).
+    x: (B, H, W, C_in) NHWC -> (B, H', W', C_out), or both in the lane
+    layout, as for ``mbconv_block``.
     """
     from ..configs.base import kernel_config, resolve_pin
     if cfg is None:
@@ -483,7 +486,7 @@ def fusedmb_block(
     eff = resolve_pin(cfg, pin, family="fusedmb")
     w_conv = params["conv"].astype(x.dtype)
     w_proj = params["proj"].astype(x.dtype) if "proj" in params else None
-    c_in = x.shape[-1]
+    c_in = w_conv.shape[2]
     c_mid = w_conv.shape[-1]
     c_out = c_mid if w_proj is None else w_proj.shape[-1]
     k = w_conv.shape[0]
@@ -696,7 +699,12 @@ def network_apply(params: dict, images: jax.Array, cfg, kcfg=None,
     counters charge are the schedules that run).  The chain lowers
     through ``models.blockgraph``: ``validate()`` proves every pipelined
     boundary hazard-free and ``lower()`` runs each block under its
-    ``mbconv<i>`` / ``fusedmb<i>`` name scope."""
+    ``mbconv<i>`` / ``fusedmb<i>`` name scope.
+
+    Between the stem and the head every activation is in the kernels'
+    lane layout (``kernels.common.in_lane_layout``): each block's kernels
+    stage it as it is, with no padded copy in front of a launch."""
+    from ..kernels.common import to_lanes
     specs = cfg.block_specs()
     act = getattr(jax.nn, cfg.stem_head_act)
     dt = jnp.dtype(cfg.dtype)
@@ -704,11 +712,13 @@ def network_apply(params: dict, images: jax.Array, cfg, kcfg=None,
         from ..configs.base import kernel_config
         kcfg = kernel_config()
     with jax.named_scope("stem"):
-        x = jax.lax.conv_general_dilated(
-            images.astype(dt), params["stem"].astype(dt),
+        # zero filters give the stem output the kernels' lane layout,
+        # which the chain carries from here to the head: act(0) = 0 for
+        # every stem activation, and no XLA op pads the activation
+        x = act(jax.lax.conv_general_dilated(
+            images.astype(dt), to_lanes(params["stem"].astype(dt)),
             (STEM_STRIDE, STEM_STRIDE), "SAME",
-            dimension_numbers=("NHWC", "HWIO", "NHWC"))
-        x = act(x)
+            dimension_numbers=("NHWC", "HWIO", "NHWC")))
         if plan is None and (mesh is not None and kcfg.shard_fused
                              and kcfg.fused_mbconv and kcfg.autotune):
             from ..core.autotune import get_network_plan
@@ -736,8 +746,11 @@ def network_apply(params: dict, images: jax.Array, cfg, kcfg=None,
     graph.validate()
     x = graph.lower(x)
     with jax.named_scope("head"):
-        x = act(jnp.einsum("bhwc,cd->bhwd", x,
-                           params["head"].astype(x.dtype)))
+        # zero rows for the lane tail the last block's output carries
+        w_head = params["head"].astype(x.dtype)
+        w_head = jnp.pad(w_head, ((0, x.shape[-1] - w_head.shape[0]),
+                                  (0, 0)))
+        x = act(jnp.einsum("bhwc,cd->bhwd", x, w_head))
         x = x.mean(axis=(1, 2))
         if "fc" in params:
             x = act(x @ params["fc"].astype(x.dtype))

@@ -327,11 +327,14 @@ _PREAMBLE = textwrap.dedent("""
 
     def loop_reference(params, imgs, kcfg, mesh, plan):
         '''The pre-graph executor: stem + explicit sequential block loop
-        + head, threading the plan pins exactly as the old code did.'''
+        + head, threading the plan pins exactly as the old code did, the
+        chain in the kernels' lane layout (zero stem filters past the
+        stem's channels, zero head rows past the last block's).'''
+        from repro.kernels.common import to_lanes
         dt = jnp.dtype(cfg.dtype)
         x = jax.lax.conv_general_dilated(
-            imgs.astype(dt), params["stem"].astype(dt), (2, 2), "SAME",
-            dimension_numbers=("NHWC", "HWIO", "NHWC"))
+            imgs.astype(dt), to_lanes(params["stem"].astype(dt)), (2, 2),
+            "SAME", dimension_numbers=("NHWC", "HWIO", "NHWC"))
         x = jax.nn.silu(x)
         if plan is not None and mesh is not None \\
                 and plan.stem_layout == "model_sharded":
@@ -354,8 +357,10 @@ _PREAMBLE = textwrap.dedent("""
             else:
                 x, _ = mbconv_block(x, params[f"block{i}"], stride=sp.s,
                                     cfg=kcfg, mesh=mesh)
-        x = jax.nn.silu(jnp.einsum("bhwc,cd->bhwd", x,
-                                   params["head"].astype(x.dtype)))
+        w_head = params["head"].astype(x.dtype)
+        w_head = jnp.pad(w_head, ((0, x.shape[-1] - w_head.shape[0]),
+                                  (0, 0)))
+        x = jax.nn.silu(jnp.einsum("bhwc,cd->bhwd", x, w_head))
         x = x.mean(axis=(1, 2))
         return x @ params["cls_w"].astype(x.dtype) \\
             + params["cls_b"].astype(x.dtype)

@@ -282,10 +282,10 @@ _NAMED_ENTRIES = {
 }
 
 
-@pytest.mark.parametrize("model", sorted(_NAMED_ENTRIES))
-def test_named_entry_compiles(one_chip, model):
-    """The whole served program of each family at published widths,
-    through its named entry."""
+@functools.lru_cache(maxsize=None)
+def _named_entry_hlo(model, one_chip) -> str:
+    """Compiled HLO text of ``model``'s named entry (``_NAMED_ENTRIES``),
+    compiled once per worker for the tests that read it."""
     from repro.configs.base import ConvKernelConfig
     from repro.models.param import abstract
 
@@ -297,5 +297,51 @@ def test_named_entry_compiles(one_chip, model):
     kcfg = ConvKernelConfig(interpret=False)
     with (jax.default_matmul_precision(precision) if precision
           else contextlib.nullcontext()):
-        _assert_kernel(lambda p, x: apply(p, x, cfg, kcfg),
-                       params, _spec(one_chip, batch, res, res, 3))
+        return jax.jit(lambda p, x: apply(p, x, cfg, kcfg)).lower(
+            params, _spec(one_chip, batch, res, res, 3)).compile().as_text()
+
+
+@pytest.mark.parametrize("model", sorted(_NAMED_ENTRIES))
+def test_named_entry_compiles(one_chip, model):
+    """The whole served program of each family at published widths,
+    through its named entry."""
+    assert "tpu_custom_call" in _named_entry_hlo(model, one_chip)
+
+
+def _entry_instructions(hlo: str) -> dict:
+    """{name: (opcode, shape, line)} of the compiled program's entry
+    computation."""
+    body = re.search(r"\nENTRY [^\n]*\{\n(.*?)\n\}", hlo, re.S).group(1)
+    found = {}
+    for line in body.splitlines():
+        m = re.match(r"\s*(?:ROOT )?%(\S+) = (.+?) ([\w\-]+)\(", line)
+        if m:
+            found[m.group(1)] = (m.group(3), m.group(2), line)
+    return found
+
+
+@pytest.mark.parametrize("model", sorted(_NAMED_ENTRIES))
+def test_named_entry_stages_without_a_copy(one_chip, model):
+    """No standalone ``pad`` or ``copy`` of an activation feeds a Pallas
+    kernel of a block at 56 x 56 or above: each kernel stages the block's
+    activation as the previous block (or the stem) wrote it, in the lane
+    layout, and builds its conv halo in VMEM.  The stem writes that layout
+    with no ``pad`` or ``copy`` of its own."""
+    ops = _entry_instructions(_named_entry_hlo(model, one_chip))
+    kernels = 0
+    for name, (opcode, _shape, line) in ops.items():
+        if 'custom_call_target="tpu_custom_call"' not in line:
+            continue
+        kernels += 1
+        args = re.search(r"custom-call\(([^)]*)\)", line).group(1)
+        for arg in re.findall(r"%(\S+?)(?:,|$)", args):
+            op, shape, _ = ops.get(arg, ("parameter", "", ""))
+            dims = re.match(r"\w+\[([\d,]*)\]", shape)
+            dims = [int(d) for d in dims.group(1).split(",")] if (
+                dims and dims.group(1)) else []
+            assert not (op in ("pad", "copy") and len(dims) == 4
+                        and dims[1] >= 56), (name, arg, op, shape)
+    assert kernels > 0
+    stem = [(name, op) for name, (op, _, line) in ops.items()
+            if "/stem/" in line and op in ("pad", "copy")]
+    assert not stem, stem

@@ -181,6 +181,81 @@ def test_fusedmb_tap_packing_matches_oracle(c_in, per_pass, stride, project):
         got, _fusedmb_oracle(x, w_conv, w_proj, stride), **TOL)
 
 
+# (side, stride, tile_h, c_in, project, residency): the fused kernel's
+# strips that differ — the only one, first, interior, last, clipped — at
+# odd and even sides and the chains' input widths (packed taps at 16, 24,
+# 40; one tap a pass at 112; the width cover at 192 on 7 x 7)
+FUSED_HALO_CASES = [
+    (7, 1, 8, 16, False, "strip_dma_db"),
+    (7, 1, 3, 192, True, "strip_dma"),
+    (14, 2, 2, 24, True, "strip_dma_db"),
+    (15, 1, 4, 40, True, "resident"),
+    (28, 2, 4, 112, True, "strip_dma_db"),
+    (56, 1, 16, 24, False, "strip_dma"),
+    (113, 2, 16, 16, True, "strip_dma_db"),
+]
+
+
+@pytest.mark.parametrize(
+    "side,stride,tile_h,c_in,project,residency", FUSED_HALO_CASES,
+    ids=[f"{c[0]}x{c[0]}_s{c[1]}_t{c[2]}_c{c[3]}"
+         f"{'_proj' if c[4] else ''}_{c[5]}" for c in FUSED_HALO_CASES])
+def test_fusedmb_halo_in_vmem_matches_reference(side, stride, tile_h, c_in,
+                                                project, residency):
+    """The single-pass kernel staging the unpadded activation, its conv
+    halo built in VMEM, matches ``fusedmb_ref`` and the dense-conv oracle;
+    the lane-layout input gives the same numbers with a zero tail."""
+    from repro.kernels.common import to_lanes
+    from repro.kernels.ref import fusedmb_ref
+
+    rng = np.random.default_rng(side * 10 + c_in)
+    c_mid = 2 * c_in if project else c_in
+    x = _rand(rng, (1, side, side, c_in))
+    w_conv = _rand(rng, (3, 3, c_in, c_mid), 0.2)
+    w_proj = _rand(rng, (c_mid, 32), 0.2) if project else None
+    c_out = 32 if project else c_mid
+    kw = dict(stride=stride, tile_h=tile_h, interpret=True,
+              residency=residency)
+    got = convdk_fusedmb_fused(x, w_conv, w_proj, **kw)
+    lanes = convdk_fusedmb_fused(to_lanes(x), w_conv, w_proj, **kw)
+    want = fusedmb_ref(x, w_conv, w_proj, stride=stride)
+    np.testing.assert_allclose(got, want, **TOL)
+    np.testing.assert_allclose(
+        got, _fusedmb_oracle(x, w_conv, w_proj, stride), **TOL)
+    assert lanes.shape == (*want.shape[:3], -(-c_out // 128) * 128)
+    np.testing.assert_array_equal(lanes[..., :c_out], got)
+    assert not bool(jnp.any(lanes[..., c_out:]))
+
+
+def test_fused_chain_carries_the_lane_layout():
+    """A fused block with no projection into an MBConv block with the
+    identity residual, on the lane layout: the narrow chain's numbers,
+    and a tail that is exactly zero after each block."""
+    from repro.configs.base import ConvKernelConfig
+    from repro.kernels.common import to_lanes
+    from repro.models.mbconv import (
+        fusedmb_block, fusedmb_def, mbconv_block, mbconv_def)
+    from repro.models.param import materialize
+
+    cfg = ConvKernelConfig(interpret=True)
+    pf = materialize(fusedmb_def(24, 24, 24, k=3, expand_ratio=1),
+                     jax.random.key(5))
+    pm = materialize(mbconv_def(24, 24, k=3, expand_ratio=4),
+                     jax.random.key(6))
+    x = _rand(np.random.default_rng(17), (2, 14, 14, 24), 0.5)
+
+    def chain(a):
+        a, _ = fusedmb_block(a, pf, stride=1, cfg=cfg)
+        mid = a
+        a, _ = mbconv_block(a, pm, stride=1, cfg=cfg)
+        return mid, a
+
+    for narrow, lanes in zip(chain(x), chain(to_lanes(x))):
+        assert lanes.shape == (2, 14, 14, 128)
+        np.testing.assert_allclose(lanes[..., :24], narrow, **TOL)
+        assert not bool(jnp.any(lanes[..., 24:]))
+
+
 def test_fusedmb_grad_matches_oracle():
     """Gradients with a projection and without one (``w_proj=None``: its
     cotangent is None)."""
@@ -204,6 +279,7 @@ def test_fusedmb_grad_matches_oracle():
 
 NO_PROJECT = "kernels.fusedmb.no_project"
 TAP_PACKED = "kernels.fusedmb.tap_packed"
+HALO_IN_VMEM = "kernels.staging.halo_in_vmem"
 
 
 def _ticks(name, fn, *args):
@@ -332,6 +408,28 @@ def test_tap_packed_counter_per_trace(model, width, batch, hw, count):
         assert count == sum(sp.family == "fusedmb" and sp.c_in <= 64
                             for sp in specs)
     assert _model_ticks(TAP_PACKED, model, width, batch, hw) == count
+
+
+@pytest.mark.parametrize("model,width,batch,hw,count", [
+    ("efficientnet_b0", 1.0, 256, 224, 12),
+    ("mobilenet_v3_large", 1.0, 256, 224, 13),
+    ("efficientnet_v2_s", 1.0, 64, 384, 26),
+    ("efficientnet_v2_s", 0.25, 1, 32, 40),
+])
+def test_halo_in_vmem_counter_per_trace(model, width, batch, hw, count):
+    """One tick per block whose kernels stage the block's activation as
+    it is: every block of the three families at their cells' sizes but
+    those taking more than 128 channels at a width that is not whole
+    sublanes, which keep a width cover (B0's blocks 12-15 at 7 x 7, V3-L's
+    13-14 at 7 x 7, V2-S's 26-39 at 12 x 12).  At width 0.25 no V2-S block
+    takes more than 128 channels."""
+    from repro.models.mbconv import EffNetV2Config, effnet_v2_block_specs
+
+    if (model, width) == ("efficientnet_v2_s", 0.25):
+        specs = effnet_v2_block_specs(
+            EffNetV2Config(width_mult=width, num_classes=10))
+        assert count == len(specs) and max(sp.c_in for sp in specs) <= 128
+    assert _model_ticks(HALO_IN_VMEM, model, width, batch, hw) == count
 
 
 def test_no_project_counter_silent_on_expanding_fused_block():

@@ -335,6 +335,66 @@ def test_mbconv_block_routes_both_paths_and_residual():
     np.testing.assert_allclose(out, x, **TOL)
 
 
+@pytest.mark.parametrize("fused", [True, False], ids=["fused", "staged"])
+def test_chained_blocks_keep_the_lane_tail_zero(fused):
+    """Two chained blocks in the lane layout the network carries — a
+    stride-2 block into one with the identity residual — give the narrow
+    chain's numbers with a tail that stays exactly zero from block to
+    block (the residual adds zero to zero), on the fused kernels and on
+    the staged baseline alike."""
+    from repro.configs.base import ConvKernelConfig
+    from repro.kernels.common import to_lanes
+    from repro.models.mbconv import mbconv_block, mbconv_def
+    from repro.models.param import materialize
+
+    cfg = ConvKernelConfig(fused_mbconv=fused, interpret=True)
+    p1 = materialize(mbconv_def(16, 24, k=3, expand_ratio=6),
+                     jax.random.key(3))
+    p2 = materialize(mbconv_def(24, 24, k=5, expand_ratio=6),
+                     jax.random.key(4))
+    x = _rand(np.random.default_rng(9), (2, 15, 15, 16))
+
+    def chain(a):
+        a, _ = mbconv_block(a, p1, stride=2, cfg=cfg)
+        mid = a
+        a, _ = mbconv_block(a, p2, stride=1, cfg=cfg)
+        return mid, a
+
+    mid_n, out_n = chain(x)
+    mid_l, out_l = chain(to_lanes(x))
+    assert out_n.shape == (2, 8, 8, 24) and out_l.shape == (2, 8, 8, 128)
+    for narrow, lanes in ((mid_n, mid_l), (out_n, out_l)):
+        np.testing.assert_allclose(lanes[..., :24], narrow, **TOL)
+        assert not bool(jnp.any(lanes[..., 24:]))
+    # the residual is live in the lane layout too
+    zeroed = dict(p2, proj=jnp.zeros_like(p2["proj"]))
+    out, _ = mbconv_block(mid_l, zeroed, stride=1, cfg=cfg)
+    np.testing.assert_array_equal(out, mid_l)
+
+
+def test_lane_layout_grad_matches_narrow():
+    """The custom VJP through a lane-layout input and output is the
+    narrow block's gradient: the zero tail takes no cotangent into the
+    weights, and the input's tail gets none."""
+    from repro.kernels.common import to_lanes
+
+    rng = np.random.default_rng(13)
+    ws, exp_act = _mbconv_params(rng, 16, 4, 24, 3)
+    x = _rand(rng, (1, 9, 9, 16))
+    kw = dict(stride=2, tile_h=2, mode="recompute", exp_act=exp_act,
+              interpret=True, residency="strip_dma_db")
+
+    def loss(fn_x, *w):
+        return (convdk_mbconv_fused(fn_x, *w, **kw)[..., :24] ** 2).sum()
+
+    g_n = jax.grad(loss, argnums=tuple(range(8)))(x, *ws)
+    g_l = jax.grad(loss, argnums=tuple(range(8)))(to_lanes(x), *ws)
+    np.testing.assert_allclose(g_l[0][..., :16], g_n[0], **TOL)
+    assert not bool(jnp.any(g_l[0][..., 16:]))
+    for a, b in zip(g_l[1:], g_n[1:]):
+        np.testing.assert_allclose(a, b, **TOL)
+
+
 def test_effnet_block_specs_match_workloads_table():
     """The model builder's stage table, the workloads MBConv table and the
     paper's DW table are three views of the same network."""

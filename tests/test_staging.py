@@ -459,3 +459,96 @@ def test_sharded_entry_point_retraces_on_new_schedule():
                                          interpret=True)
     assert TRACE_COUNTS["separable"] > base
     np.testing.assert_allclose(out, _sep_oracle(x, w_dw, w_pw, 1), **TOL)
+
+
+# ---------------------------------------------------------------------------
+# the conv halo built in VMEM: strip copies out of the unpadded activation
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("h,k,stride,tile_h", [
+    (7, 3, 1, 8), (14, 5, 2, 2), (15, 3, 1, 4), (10, 5, 1, 1),
+    (113, 3, 2, 16), (56, 5, 1, 8), (12, 3, 2, 3), (28, 5, 1, 28)])
+def test_strip_copies_tile_each_window(h, k, stride, tile_h):
+    """Each strip window is its copy plus zero halo rows: the copy lands
+    exactly the source rows the window covers, each at its own row; the
+    strips whose windows lie inside the source share one copy shape and
+    form one run between the edge strips."""
+    from repro.kernels.common import launch_geometry
+    from repro.kernels.staging import strip_plan
+
+    geo = launch_geometry(h, h, k, k, stride, "SAME", tile_h)
+    plan = strip_plan(h_tot=geo.h_tot, w_tot=geo.w_tot, c_block=128,
+                      tile_h=geo.tile_h, grid=(1, geo.n_th, 1),
+                      window_dims=(0, 1, 2), stride=stride, k_h=k,
+                      h_src=h, w_src=h, top=geo.top, left=geo.left)
+    edges, interior = plan.strip_copies()
+    by_strip = {t: (src, rows, at) for t, src, rows, at in edges}
+    if interior is not None:
+        first, last = interior
+        assert not any(first <= t <= last for t in by_strip)
+        for t in range(first, last + 1):
+            by_strip[t] = (t * geo.tile_h * stride - geo.top,
+                           plan.in_rows, 0)
+    assert sorted(by_strip) == list(range(geo.n_th))
+    for t, (src, rows, at) in by_strip.items():
+        r0 = t * geo.tile_h * stride - geo.top      # source row of row 0
+        want = [(r, r - r0) for r in range(h) if 0 <= r - r0 < plan.in_rows]
+        assert [(src + i, at + i) for i in range(rows)] == want, t
+    # the frame holds the source at (top, left) and the last strip's reach
+    assert geo.top + h <= geo.h_tot and geo.left + h <= geo.w_tot
+    assert (geo.n_th - 1) * geo.tile_h * stride + plan.in_rows <= geo.h_tot
+
+
+# (side, k, stride, tile_h, c_in, expand, se, residency, mode): the strips
+# that differ — the only one (n_th == 1), first, interior, last (also
+# clipped: tile_h not dividing out_h), several edge strips (tile_h 1, k 5)
+# — at odd and even sides, the channel counts the benchmarked chains carry
+# into a kernel (192 at 7 x 7 takes the width cover), every staging mode,
+# with and without expand and SE
+HALO_CASES = [
+    (7, 3, 1, 8, 16, 1, True, "strip_dma_db", "recompute"),
+    (7, 5, 1, 3, 192, 2, True, "strip_dma_db", "retain"),
+    (14, 5, 2, 2, 24, 3, False, "strip_dma", "retain"),
+    (14, 3, 1, 14, 112, 1, True, "resident", "recompute"),
+    (15, 3, 1, 4, 40, 2, True, "strip_dma_db", "recompute"),
+    (15, 5, 2, 3, 24, 2, False, "resident", "retain"),
+    (28, 5, 1, 8, 40, 1, False, "strip_dma", "recompute"),
+    (56, 3, 2, 8, 16, 6, True, "strip_dma_db", "recompute"),
+    (113, 3, 2, 16, 24, 2, False, "strip_dma_db", "retain"),
+    (10, 5, 1, 1, 16, 2, True, "strip_dma", "recompute"),
+]
+
+
+@pytest.mark.parametrize(
+    "side,k,stride,tile_h,c_in,expand,se,residency,mode", HALO_CASES,
+    ids=[f"{c[0]}x{c[0]}_k{c[1]}s{c[2]}_t{c[3]}_c{c[4]}_e{c[5]}"
+         f"{'_se' if c[6] else ''}_{c[7]}_{c[8]}" for c in HALO_CASES])
+def test_halo_in_vmem_parity(side, k, stride, tile_h, c_in, expand, se,
+                             residency, mode):
+    """The kernels staging the unpadded activation, its conv halo built in
+    VMEM, match ``mbconv_ref``; the lane-layout input gives the same
+    numbers, bit for bit, with a tail that is exactly zero."""
+    from repro.kernels.common import to_lanes
+    from repro.kernels.ref import mbconv_ref
+
+    rng = np.random.default_rng(side * 1000 + k * 100 + c_in)
+    c_mid, c_out = c_in * expand, 32
+    c_se = max(1, c_in // 4)
+    x = _rand(rng, (1, side, side, c_in))
+    se_w = ((_rand(rng, (c_mid, c_se), 0.3), _rand(rng, (c_se,), 0.1),
+             _rand(rng, (c_se, c_mid), 0.3), _rand(rng, (c_mid,), 0.1))
+            if se else (None,) * 4)
+    weights = (_rand(rng, (c_in, c_mid), 0.3) if expand > 1 else None,
+               _rand(rng, (k, k, c_mid), 0.3), *se_w,
+               _rand(rng, (c_mid, c_out), 0.3))
+    act = "silu" if expand > 1 else None
+    kw = dict(stride=stride, tile_h=tile_h, mode=mode, exp_act=act,
+              interpret=True, residency=residency)
+    got = convdk_mbconv_fused(x, *weights, **kw)
+    lanes = convdk_mbconv_fused(to_lanes(x), *weights, **kw)
+    want = mbconv_ref(x, *weights, stride=stride, exp_act=act)
+    assert got.shape == want.shape
+    np.testing.assert_allclose(got, want, **TOL)
+    assert lanes.shape == (*want.shape[:3], 128)
+    np.testing.assert_array_equal(lanes[..., :c_out], got)
+    assert not bool(jnp.any(lanes[..., c_out:]))
